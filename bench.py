@@ -88,6 +88,9 @@ def main() -> None:
                          "new >= 0.9 x old passes)")
     args = ap.parse_args()
 
+    from shifu_tpu import compile_cache
+    compile_cache.configure()
+
     if args.compare is not None:
         from shifu_tpu.bench import resolve_compare_paths, run_compare
         try:

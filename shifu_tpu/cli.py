@@ -295,6 +295,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 def _dispatch(argv: Optional[List[str]] = None) -> int:
+    from . import compile_cache
+    compile_cache.configure()
     argv = _split_props(list(argv if argv is not None else sys.argv[1:]))
     args = build_parser().parse_args(argv)
     from . import configure_logging

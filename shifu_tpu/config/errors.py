@@ -43,6 +43,12 @@ class ErrorCode(enum.Enum):
     # coded instead of hanging the launcher on a dead coordinator
     ERROR_DCN_CONNECT = (
         1063, "Could not connect to the distributed coordinator")
+    # rebuild-specific: a mode that starts several JAX processes was asked
+    # for on a chip host, where a chip belongs to one process at a time
+    # (parallel/mesh.refuse_children_on_chip) — refused before spawning
+    # instead of hanging the second process on the busy chip
+    ERROR_ONE_PROCESS_PER_CHIP = (
+        1064, "A chip belongs to one process at a time")
     # --- data shape (1150s)
     ERROR_EXCEED_COL = (1151, "Input data has more fields than the header")
     ERROR_LESS_COL = (1152, "Input data has fewer fields than the header")

@@ -283,8 +283,6 @@ _DECLS: Tuple[Knob, ...] = (
        "process count for the multi-controller job"),
     _k("SHIFU_PROCESS_ID", "env", "int", "",
        "this controller's process index"),
-    _k("SHIFU_MH_CACHE", "env", "str", "/tmp/shifu_tpu_mh_cache",
-       "multihost demo/bench workers' own XLA compile-cache dir"),
     _k("SHIFU_TPU_HOME", "env", "str", "",
        "home dir holding conf/shifuconfig global properties"),
     _k("SHIFU_HOME", "env", "str", "",

@@ -122,11 +122,10 @@ CURVE_POINTS = 1024     # device-sweep downsample resolution (charts/buckets)
 def _sweep_device_impl(s, t, w, points: int):
     """Whole confusion sweep ON DEVICE; one packed fetch.
 
-    The host sweep (above) argsorts fetched scores — on this rig a
-    full-set fetch costs 100-250 ms before sorting starts, putting eval
-    ~2 orders below the train plane (BENCH_r03).  Here sort, cumsums and
-    the tie-group reductions all run on device and only
-    ``5*points + 7`` floats cross the link.
+    The host sweep (above) argsorts fetched scores, so a full-set fetch
+    precedes the sort.  Here sort, cumsums and the tie-group reductions
+    all run on device and only ``5*points + 7`` floats cross to the
+    host.
 
     Deliberately scatter-free (TPU serializes scatters): tie groups are
     resolved with cummax/cummin scans + gathers —

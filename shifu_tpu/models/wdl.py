@@ -202,27 +202,6 @@ def forward_logits(params: Dict, spec: WDLModelSpec, x_num, x_cat):
     return logit
 
 
-def _ensure_barrier_batching() -> None:
-    """``optimization_barrier`` has no vmap rule in this jax — the barrier
-    is identity-shaped, so batching is bind-through (installed only when
-    missing; newer jax versions ship their own)."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p as p
-        from jax.interpreters import batching
-    except ImportError:                           # pragma: no cover
-        return
-    if p in batching.primitive_batchers:
-        return
-
-    def _batch(args, dims):
-        return p.bind(*args), dims
-
-    batching.primitive_batchers[p] = _batch
-
-
-_ensure_barrier_batching()
-
-
 @jax.custom_vjp
 def _lookup_barrier(ops):
     """Differentiable ``optimization_barrier`` (no autodiff rule upstream):

@@ -55,11 +55,12 @@ log = logging.getLogger(__name__)
 
 
 # ------------------------------------------------------------ peak table
-# Per-backend peak compute (bf16/matmul FLOP/s) and HBM bandwidth (B/s),
+# Per-device peak compute (bf16/matmul FLOP/s) and HBM bandwidth (B/s),
 # matched by substring against jax's device_kind (lowercased).  Public
-# spec-sheet numbers for the TPU generations; the CPU row is a
-# placeholder order-of-magnitude so the report renders — override with
-# SHIFU_TPU_PEAK_FLOPS / SHIFU_TPU_PEAK_BW on any rig you care about.
+# spec-sheet numbers for the TPU generations.  A device_kind that is not
+# in the table has NO peak: the report says "peak unknown" and prints no
+# percent-of-peak or MFU (set SHIFU_TPU_PEAK_FLOPS / SHIFU_TPU_PEAK_BW to
+# supply one).
 DEVICE_PEAKS: Tuple[Tuple[str, float, float], ...] = (
     ("tpu v6", 918e12, 1640e9),
     ("tpu v5p", 459e12, 2765e9),
@@ -68,39 +69,32 @@ DEVICE_PEAKS: Tuple[Tuple[str, float, float], ...] = (
     ("tpu v4", 275e12, 1228e9),
     ("tpu v3", 123e12, 900e9),
     ("tpu v2", 46e12, 700e9),
-    ("cpu", 1e11, 5e10),
 )
-GENERIC_PEAKS = (1e11, 5e10)
+PEAK_UNKNOWN = "peak unknown"
 
 
 def backend_info() -> Dict[str, str]:
     """(platform, device_kind) of local device 0 — stamped into the
     flush meta so a post-hoc report resolves the right peak row."""
-    try:
-        import jax
-        d = jax.local_devices()[0]
-        return {"platform": str(d.platform),
-                "device_kind": str(d.device_kind)}
-    except Exception:
-        return {"platform": "unknown", "device_kind": "unknown"}
+    import jax
+    d = jax.local_devices()[0]
+    return {"platform": str(d.platform), "device_kind": str(d.device_kind)}
 
 
 def resolve_peaks(backend: Optional[Dict[str, str]] = None
-                  ) -> Tuple[float, float, str]:
-    """(peak FLOP/s, peak B/s, provenance label).  Env overrides beat the
-    table: ``SHIFU_TPU_PEAK_FLOPS`` / ``SHIFU_TPU_PEAK_BW`` (floats,
-    per-device)."""
+                  ) -> Tuple[Optional[float], Optional[float], str]:
+    """(peak FLOP/s, peak B/s, provenance label) for the backend's
+    ``device_kind``; ``(None, None, PEAK_UNKNOWN)`` when the table has no
+    row for it.  Env overrides beat the table: ``SHIFU_TPU_PEAK_FLOPS`` /
+    ``SHIFU_TPU_PEAK_BW`` (floats, per-device)."""
     backend = backend or backend_info()
     kind = str(backend.get("device_kind") or "").lower()
-    platform = str(backend.get("platform") or "").lower()
     flops = bw = None
-    label = "generic fallback"
+    label = PEAK_UNKNOWN
     for sub, f, b in DEVICE_PEAKS:
-        if sub in kind or sub == platform:
+        if sub in kind:
             flops, bw, label = f, b, sub
             break
-    if flops is None:
-        flops, bw = GENERIC_PEAKS
     for env, idx in (("SHIFU_TPU_PEAK_FLOPS", 0), ("SHIFU_TPU_PEAK_BW", 1)):
         v = os.environ.get(env)
         if v:
@@ -258,11 +252,7 @@ def _leaf_sig(x: Any) -> str:
     """'f32[8,64]'-style abstract signature for one leaf (weak-typed
     python scalars keyed apart from committed arrays)."""
     import jax
-    aval = jax.core.get_aval(x)
-    try:
-        aval = jax.core.raise_to_shaped(aval)
-    except Exception:
-        pass
+    aval = jax.typeof(x)
     s = aval.str_short()
     if getattr(aval, "weak_type", False):
         s += "~"

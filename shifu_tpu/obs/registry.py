@@ -252,29 +252,24 @@ _compile_listener_installed = False
 
 def ensure_compile_listener() -> None:
     """Install (once per process) a ``jax.monitoring`` duration listener
-    that accumulates XLA compile count/time into ``xla.compile_count`` /
+    that accumulates XLA compilations into ``xla.compile_count`` and the
+    time spent tracing, lowering and compiling them into
     ``xla.compile_time_s``.  The listener itself checks ``enabled()`` so
     a later disable costs one branch per compile, nothing more."""
     global _compile_listener_installed
     if _compile_listener_installed:
         return
-    try:
-        try:
-            from jax.monitoring import \
-                register_event_duration_secs_listener as _register
-        except ImportError:
-            from jax._src.monitoring import \
-                register_event_duration_secs_listener as _register
-    except Exception:
-        return
+    from jax.monitoring import register_event_duration_secs_listener
 
     def _listener(name: str, secs: float, **kw) -> None:
-        if "compile" in name and tracer.enabled():
-            _registry.counter("xla.compile_count").inc()
+        # jax's own stages of one compilation: jaxpr trace, lowering and
+        # backend compile (a persistent-cache hit is a short backend
+        # compile).  Matching any name with "compile" in it also summed
+        # /jax/compilation_cache/compile_time_saved_sec — time NOT spent
+        if name.startswith("/jax/core/compile/") and tracer.enabled():
+            if name.endswith("/backend_compile_duration"):
+                _registry.counter("xla.compile_count").inc()
             _registry.counter("xla.compile_time_s").inc(secs)
 
-    try:
-        _register(_listener)
-        _compile_listener_installed = True
-    except Exception:
-        pass
+    register_event_duration_secs_listener(_listener)
+    _compile_listener_installed = True

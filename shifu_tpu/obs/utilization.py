@@ -27,7 +27,7 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, List, Optional, Tuple
 
-from .costs import resolve_peaks
+from .costs import PEAK_UNKNOWN, resolve_peaks
 from .report import NO_TELEMETRY_HINT, load_blocks, trace_path
 
 
@@ -67,11 +67,14 @@ def aggregate_block(block: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     return planes
 
 
-def verdict_for(flops: float, nbytes: float, peak_flops: float,
-                peak_bw: float) -> str:
-    """Roofline verdict from operational intensity vs machine balance."""
+def verdict_for(flops: float, nbytes: float, peak_flops: Optional[float],
+                peak_bw: Optional[float]) -> str:
+    """Roofline verdict from operational intensity vs machine balance;
+    no balance point without the device's peaks."""
     if flops <= 0 and nbytes <= 0:
         return "no-cost-data"
+    if not peak_flops or not peak_bw:
+        return PEAK_UNKNOWN
     if nbytes <= 0:
         return "compute-bound"
     if flops <= 0:
@@ -120,9 +123,11 @@ def render_utilization(model_set_dir: str) -> str:
     if skipped:
         out.append(f"warning: {len(skipped)} torn line(s) skipped")
     kind = (backend or {}).get("device_kind", "unknown")
-    out.append(f"device: {kind}  peaks[{label}]: "
-               f"{peak_flops:.3e} FLOP/s, {peak_bw:.3e} B/s  "
-               "(override: SHIFU_TPU_PEAK_FLOPS / SHIFU_TPU_PEAK_BW)")
+    known = bool(peak_flops and peak_bw)
+    out.append(f"device: {kind}  peaks[{label}]"
+               + (f": {peak_flops:.3e} FLOP/s, {peak_bw:.3e} B/s" if known
+                  else " — no percent-of-peak, verdict or MFU")
+               + "  (override: SHIFU_TPU_PEAK_FLOPS / SHIFU_TPU_PEAK_BW)")
     out.append("")
 
     grand_flops = grand_bytes = grand_wall = 0.0
@@ -143,8 +148,9 @@ def render_utilization(model_set_dir: str) -> str:
             fl, by = p["flops"], p["bytes"]
             fps = fl / wall if wall > 0 else None
             bps = by / wall if wall > 0 else None
-            pctf = (fps / peak_flops) if fps is not None else None
-            pctb = (bps / peak_bw) if bps is not None else None
+            pctf = (fps / peak_flops) if fps is not None and peak_flops \
+                else None
+            pctb = (bps / peak_bw) if bps is not None and peak_bw else None
             inten = (fl / by) if by > 0 else None
             v = verdict_for(fl, by, peak_flops, peak_bw)
             out.append(f"  {plane:<10}{_fmt_e(fl):>10}{_fmt_e(by):>10}"
@@ -173,8 +179,13 @@ def render_utilization(model_set_dir: str) -> str:
                    "through obs.costs.costed_jit (schema v6) and re-run "
                    "with telemetry enabled")
         return "\n".join(out)
-    mfu = grand_flops / (grand_wall * peak_flops) if grand_wall > 0 else 0.0
+    if not peak_flops:
+        mfu_txt = f"MFU not computed ({PEAK_UNKNOWN})"
+    else:
+        mfu = grand_flops / (grand_wall * peak_flops) \
+            if grand_wall > 0 else 0.0
+        mfu_txt = f"MFU {mfu:.2%}"
     out.append(f"pipeline: {_fmt_e(grand_flops).strip()} FLOPs, "
                f"{_fmt_e(grand_bytes).strip()} bytes over "
-               f"{grand_wall:.3f}s costed wall — MFU {mfu:.2%}")
+               f"{grand_wall:.3f}s costed wall — {mfu_txt}")
     return "\n".join(out)

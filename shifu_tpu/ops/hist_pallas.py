@@ -39,36 +39,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; accept
-# whichever this toolchain ships so the kernels lower on both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with the replication check off, across the
-    0.4/0.5 API split (top-level ``shard_map(check_vma=)`` vs
-    ``jax.experimental.shard_map.shard_map(check_rep=)``) — the checker
-    can't see through a pallas_call either way."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+def _bf16_trunc(a):
+    """f32 ``a`` truncated to its bf16-exact leading bits, still f32.
+    Must NOT be written as a convert round-trip (f32(bf16(a))): XLA's
+    allow-excess-precision simplification — explicitly enabled on this
+    TPU toolchain — folds ``a - f32(bf16(a))`` to zero, silently
+    degrading a hi/lo split to plain bf16.  Masking the low mantissa
+    bits via bitcast is opaque to the simplifier."""
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(a, jnp.uint32)
+        & jnp.uint32(0xFFFF0000), jnp.float32)
 
 
 def _bf16_split(a):
     """bf16 (hi, lo) halves of an f32 operand — two native-rate MXU
-    passes recover ~f32 accuracy (residual ~eps_bf16^2).  The split must
-    NOT be written as a convert round-trip (a - f32(bf16(a))): XLA's
-    allow-excess-precision simplification — explicitly enabled on this
-    TPU toolchain — folds that to zero, silently degrading the kernel to
-    plain bf16.  Masking the low mantissa bits via bitcast is opaque to
-    the simplifier."""
-    hi_f = jax.lax.bitcast_convert_type(
-        jax.lax.bitcast_convert_type(a, jnp.uint32)
-        & jnp.uint32(0xFFFF0000), jnp.float32)            # bf16-exact
+    passes recover ~f32 accuracy (residual ~eps_bf16^2)."""
+    hi_f = _bf16_trunc(a)
     return hi_f.astype(jnp.bfloat16), (a - hi_f).astype(jnp.bfloat16)
 
 
@@ -349,7 +335,7 @@ def build_histograms_pallas(bins, node_idx, stats, n_nodes: int,
                                lambda ci, r: (ci, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((c_pad, s, n_nodes, b_pad),
                                        jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bins_t, node_t, stats_t)
@@ -448,7 +434,7 @@ def build_histograms_pallas_batch(bins, node_idx_b, stats_b, n_nodes: int,
                                lambda ci, r: (ci, 0, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((c_pad, tb, s, n_nodes, b_pad),
                                        jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bins_t, node_t, stats_t)
@@ -470,10 +456,10 @@ def build_histograms_batch_sharded(bins, node_idx_b, stats_b, n_nodes: int,
                                           interpret, exact)
         return jax.lax.psum(h, "data")
 
-    return _shard_map(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P("data", None), P(None, "data"), P(None, "data", None)),
-        out_specs=P())(bins, node_idx_b, stats_b)
+        out_specs=P(), check_vma=False)(bins, node_idx_b, stats_b)
 
 
 def build_histograms_sharded(bins, node_idx, stats, n_nodes: int,
@@ -500,10 +486,10 @@ def build_histograms_sharded(bins, node_idx, stats, n_nodes: int,
                                     exact)
         return jax.lax.psum(h, "data")
 
-    return _shard_map(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P("data", None), P("data"), P("data", None)),
-        out_specs=P())(bins, node_idx, stats)
+        out_specs=P(), check_vma=False)(bins, node_idx, stats)
 
 
 def target_platform(mesh=None) -> str:
@@ -512,10 +498,7 @@ def target_platform(mesh=None) -> str:
     Mosaic lowering), the default backend otherwise."""
     if mesh is not None:
         return mesh.devices.flat[0].platform
-    try:
-        return jax.default_backend()
-    except Exception:                                      # pragma: no cover
-        return "cpu"
+    return jax.default_backend()
 
 
 def pallas_available(mesh=None) -> bool:
@@ -662,7 +645,7 @@ def stats_histograms_pallas(idx, stats, num_buckets: int,
         out_specs=pl.BlockSpec((cblk, s, hi_n, 64),
                                lambda ci, r: (ci, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((c_pad, s, hi_n, 64), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(idx_t, stats_t)
@@ -684,6 +667,6 @@ def stats_histograms_sharded(idx, stats, num_buckets: int, mesh,
         h = stats_histograms_pallas(i, st, num_buckets, interpret, exact)
         return jax.lax.psum(h, "data")
 
-    return _shard_map(
-        local, mesh, in_specs=(P("data", None), P("data", None)),
-        out_specs=P())(idx, stats)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P("data", None), P("data", None)),
+        out_specs=P(), check_vma=False)(idx, stats)

@@ -59,10 +59,7 @@ def _onehot_traversal() -> bool:
         return False
     if env in ("1", "force"):
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                                  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _use_onehot(n_nodes: int) -> bool:

@@ -22,11 +22,14 @@ Two lowerings, dispatched like the histogram kernel
   ``shifu.tree.quantKernel``): grid (row-blocks x trees), the bins block
   loaded into VMEM ONCE per row block and revisited across the whole
   forest — where the XLA lowering re-streams the [N, C] plane per
-  (tree, level), the kernel pays the HBM read once.  Selects are one-hot
-  matmuls over 0/1 operands (exact at any precision — the
-  ``ops.tree._sel_exact`` argument), so the kernel lowers through the
-  MXU without gathers.  Tests drive it in interpret mode on CPU.
-- a jnp gather fallback (CPU / kernel off) that IS the narrow twin of
+  (tree, level), the kernel pays the HBM read once.  Selects are
+  single-pass bf16 one-hot matmuls in which every output sums exactly
+  one non-zero term (f32 table values ride as three bf16-exact pieces),
+  so the kernel lowers through the MXU without gathers and stays
+  bit-identical to the walk.  Mosaic compiles it on a TPU backend;
+  tests drive it in interpret mode on CPU.
+- a jnp gather walk (CPU, kernel off, and the shapes
+  :func:`quant_lowering` names) that IS the narrow twin of
   ``ops.tree.traverse_nodes``'s gather branch — same routing, uint8
   operands.
 
@@ -73,8 +76,8 @@ def quant_scoring() -> bool:
 
 @lru_cache(maxsize=None)
 def quant_kernel() -> bool:
-    """Lower the traversal through the Pallas kernel (TPU only; the
-    fallback serves CPU and kernel-off).  ``SHIFU_TREE_QUANT=force``
+    """Lower the traversal through the Pallas kernel (a TPU backend; the
+    jnp walk serves CPU and kernel-off).  ``SHIFU_TREE_QUANT=force``
     pins the kernel on (interpret mode off-TPU — tests); ``=0/off``
     disables with the whole quant path."""
     env = _quant_knob()
@@ -82,10 +85,7 @@ def quant_kernel() -> bool:
         return False
     if env == "force":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                                  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def bins_fit_uint8(n_bins: int) -> bool:
@@ -156,54 +156,73 @@ def _predict_quant_ref(split_feats, left_u8s, leaf_values, bins,
 
 
 # --------------------------------------------------------- pallas kernel
-def _traverse_kernel(bins_ref, sf_ref, lm_ref, lv_ref, out_ref, *,
-                     depth: int, nblk: int, b_pad: int):
-    """One (row block, tree) cell: walk ``depth`` levels with level-local
-    one-hot selects (all 0/1 operands — exact), then the leaf-value dot.
+# forests past this node count keep the jnp walk: the kernel's per-level
+# select is a one-hot over the WHOLE node axis ([K_pad, nblk] in VMEM),
+# the same bound ``ops.tree.ONEHOT_MAX_NODES`` puts on the XLA one-hot
+# traversal (MaxDepth goes to 20 in config meta)
+KERNEL_MAX_NODES = 512
+# node-table rows (bf16): split feature hi/mid/lo, leaf value hi/mid/lo,
+# zero-padded to one bf16 sublane tile
+_TAB_ROWS = 16
+
+
+def _bf16_split3(a):
+    """(hi, mid, lo) bf16-exact f32 pieces with hi + mid + lo == a.  A
+    one-hot select of each piece is a single-pass bf16 MXU dot with
+    exactly one non-zero term, so the selected f32 value is reassembled
+    bit-for-bit."""
+    from .hist_pallas import _bf16_trunc
+    hi = _bf16_trunc(a)
+    mid = _bf16_trunc(a - hi)
+    return hi, mid, a - hi - mid
+
+
+def _traverse_kernel(bins_ref, tab_ref, lmt_ref, out_ref, *, depth: int,
+                     nblk: int):
+    """One (row block, tree) cell: walk ``depth`` levels with one-hot
+    selects over the tree's node axis (0/1 operands — exact), then the
+    leaf-value select.
 
     bins_ref [C_pad, nblk] int32 (features on sublanes, rows on lanes —
     the block is fetched from HBM once per row block and revisited
-    across the tree sweep); sf_ref/lv_ref [1, K_pad] f32; lm_ref
-    [1, K_pad, b_pad] f32 (0/1)."""
-    binsf = bins_ref[...].astype(jnp.float32)            # [C_pad, nblk]
-    c_pad = binsf.shape[0]
-    node = jnp.zeros((1, nblk), jnp.int32)               # global node ids
-    dims0 = (((0,), (0,)), ((), ()))                     # contract dim 0
+    across the tree sweep); tab_ref [1, 16, K_pad] bf16 node table (see
+    ``_TAB_ROWS``); lmt_ref [1, B_pad, K_pad] bf16 0/1 left masks, bins
+    on sublanes.  Every level runs the same shapes, so the walk is one
+    ``fori_loop`` body whatever the depth."""
+    bins = bins_ref[...]                                 # [C_pad, nblk]
+    tab = tab_ref[0]                                     # [16, K_pad]
+    lmt = lmt_ref[0]                                     # [B_pad, K_pad]
+    c_pad, k_pad, b_pad = bins.shape[0], tab.shape[1], lmt.shape[0]
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, (k_pad, nblk), 0)
+    c_iota = jax.lax.broadcasted_iota(jnp.int32, (c_pad, nblk), 0)
+    b_iota = jax.lax.broadcasted_iota(jnp.int32, (b_pad, nblk), 0)
     mm = (((1,), (0,)), ((), ()))                        # plain matmul
-    for level in range(depth):
-        k = 1 << level
-        base = k - 1
-        loc = node - base                                # level-local
-        k_iota = jax.lax.broadcasted_iota(jnp.int32, (k, nblk), 0)
-        oh = (k_iota == loc).astype(jnp.float32)         # [k, nblk]
-        # feature id of each row's node: [1, k] x [k, nblk] one-term dot
-        feat = jax.lax.dot_general(
-            sf_ref[0:1, base:base + k], oh, mm,
-            preferred_element_type=jnp.float32)          # [1, nblk]
+
+    def select(node):
+        """(node one-hot [K_pad, nblk] bf16, table rows at each row's
+        node [16, nblk] f32)."""
+        oh = (k_iota == node).astype(jnp.bfloat16)
+        return oh, jax.lax.dot_general(
+            tab, oh, mm, preferred_element_type=jnp.float32)
+
+    def level(_, node):
+        oh, sel = select(node)
+        feat = (sel[0:1] + sel[1:2] + sel[2:3]).astype(jnp.int32)
         # row's bin at that feature: one-hot over the feature sublanes
-        c_iota = jax.lax.broadcasted_iota(jnp.float32, (c_pad, nblk), 0)
-        featoh = (c_iota == feat).astype(jnp.float32)
-        rb = (featoh * binsf).sum(axis=0, keepdims=True)  # [1, nblk]
-        # left-mask row select + bin membership, [B, nblk] oriented so
-        # every reduction runs over sublanes (no transposes)
-        lm_lvl = lm_ref[0, base:base + k, :]             # [k, b_pad]
+        rb = jnp.where(c_iota == feat, bins, 0).sum(axis=0, keepdims=True)
+        # the node's left-mask row, then bin membership — [B_pad, nblk]
+        # oriented so every reduction runs over sublanes (no transposes)
         lrow = jax.lax.dot_general(
-            lm_lvl, oh, dims0,
-            preferred_element_type=jnp.float32)          # [b_pad, nblk]
-        b_iota = jax.lax.broadcasted_iota(jnp.float32, (b_pad, nblk), 0)
-        binoh = (b_iota == rb).astype(jnp.float32)
-        goes_left = (lrow * binoh).sum(axis=0,
-                                       keepdims=True) > 0.5  # [1, nblk]
-        in_level = loc >= 0                              # frozen earlier?
-        is_split = in_level & (feat >= 0)
+            lmt, oh, mm, preferred_element_type=jnp.float32)
+        goes_left = jnp.where(b_iota == rb, lrow, 0.0) \
+            .sum(axis=0, keepdims=True) > 0.5            # [1, nblk]
         child = 2 * node + jnp.where(goes_left, 1, 2)
-        node = jnp.where(is_split, child, node)
-    k_total = sf_ref.shape[1]
-    k_iota = jax.lax.broadcasted_iota(jnp.int32, (k_total, nblk), 0)
-    oh = (k_iota == node).astype(jnp.float32)
-    out_ref[...] = jax.lax.dot_general(
-        lv_ref[0:1, :], oh, mm,
-        preferred_element_type=jnp.float32)              # [1, nblk]
+        return jnp.where(feat >= 0, child, node)         # leaves freeze
+
+    node = jax.lax.fori_loop(0, depth, level,
+                             jnp.zeros((1, nblk), jnp.int32))
+    _, sel = select(node)
+    out_ref[0] = sel[3:4] + sel[4:5] + sel[5:6]          # [1, nblk]
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -215,7 +234,9 @@ def _predict_quant_pallas(split_feats, left_u8s, leaf_values, bins,
                           depth: int, interpret: bool = False):
     """Kernel launch wrapper: pads/transposes operands to tile shapes
     (bins widen to int32 per VMEM block, the ``hist_pallas`` convention —
-    uint8 in HBM, int32 only block-local) and trims the output."""
+    uint8 in HBM, int32 only block-local) and trims the output.  Per-tree
+    operands carry a unit middle axis so a one-tree block's last two
+    dims are whole array dims (Mosaic's block-shape rule)."""
     from jax.experimental import pallas as pl
 
     t, k = split_feats.shape
@@ -224,56 +245,70 @@ def _predict_quant_pallas(split_feats, left_u8s, leaf_values, bins,
     nblk = LANE if n <= LANE else 4 * LANE
     n_pad = _pad_to(n, nblk)
     c_pad = _pad_to(c, 8)
-    k_pad = _pad_to(k, 8)
-    b_pad = _pad_to(b, 8)
+    k_pad = _pad_to(k, LANE)
+    b_pad = _pad_to(b, 16)                               # bf16 sublanes
     binst = jnp.zeros((c_pad, n_pad), jnp.int32) \
         .at[:c, :n].set(bins.astype(jnp.int32).T)
-    # split ids pad with -1 (leaf): pad rows route nowhere
+    # split ids pad with -1 (leaf): pad nodes route nowhere
     sf = jnp.full((t, k_pad), -1.0, jnp.float32) \
         .at[:, :k].set(split_feats.astype(jnp.float32))
-    lm = jnp.zeros((t, k_pad, b_pad), jnp.float32) \
-        .at[:, :k, :b].set(left_u8s.astype(jnp.float32))
     lv = jnp.zeros((t, k_pad), jnp.float32).at[:, :k].set(leaf_values)
+    rows = _bf16_split3(sf) + _bf16_split3(lv)
+    tab = jnp.zeros((t, _TAB_ROWS, k_pad), jnp.bfloat16) \
+        .at[:, :len(rows), :].set(
+            jnp.stack(rows, axis=1).astype(jnp.bfloat16))
+    lmt = jnp.zeros((t, b_pad, k_pad), jnp.bfloat16) \
+        .at[:, :b, :k].set(left_u8s.astype(jnp.bfloat16)
+                           .transpose(0, 2, 1))
     grid = (n_pad // nblk, t)
     out = pl.pallas_call(
-        partial(_traverse_kernel, depth=depth, nblk=nblk, b_pad=b_pad),
+        partial(_traverse_kernel, depth=depth, nblk=nblk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((c_pad, nblk), lambda r, ti: (0, r)),
-            pl.BlockSpec((1, k_pad), lambda r, ti: (ti, 0)),
-            pl.BlockSpec((1, k_pad, b_pad), lambda r, ti: (ti, 0, 0)),
-            pl.BlockSpec((1, k_pad), lambda r, ti: (ti, 0)),
+            pl.BlockSpec((1, _TAB_ROWS, k_pad), lambda r, ti: (ti, 0, 0)),
+            pl.BlockSpec((1, b_pad, k_pad), lambda r, ti: (ti, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, nblk), lambda r, ti: (ti, r)),
-        out_shape=jax.ShapeDtypeStruct((t, n_pad), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, nblk), lambda r, ti: (ti, 0, r)),
+        out_shape=jax.ShapeDtypeStruct((t, 1, n_pad), jnp.float32),
         interpret=interpret,
-    )(binst, sf, lm, lv)
-    return out[:, :n]
+    )(binst, tab, lmt)
+    return out[:, 0, :n]
 
 
 # ------------------------------------------------------------- dispatch
-def _spans_devices(a) -> bool:
-    """True when ``a`` is sharded across >1 device — a pallas_call is
-    not partitionable, so such inputs must take the jnp fallback (which
-    GSPMD partitions like any other traversal)."""
-    try:
-        sh = getattr(a, "sharding", None)
-        return sh is not None and len(sh.device_set) > 1
-    except Exception:                                  # pragma: no cover
-        return False
+def quant_lowering(bins, n_nodes: int, leaf_ndim: int = 2) -> str:
+    """The lowering :func:`predict_forest_quant` picks for these operands,
+    and why: ``"pallas"`` or ``"walk:<reason>"``.  The kernel serves
+    scalar-leaf forests of at most ``KERNEL_MAX_NODES`` nodes on
+    single-device bins; a pallas_call is not partitionable, so
+    mesh-sharded bins take the jnp walk (which GSPMD partitions like any
+    other traversal), and multiclass leaf distributions ([T, K, S]) are
+    not scalar-leaf shaped."""
+    if not quant_kernel():
+        return "walk:kernel-off"
+    if leaf_ndim != 2:
+        return "walk:multiclass-leaves"
+    if n_nodes > KERNEL_MAX_NODES:
+        return "walk:nodes>%d" % KERNEL_MAX_NODES
+    sh = getattr(bins, "sharding", None)      # None for numpy and tracers
+    if sh is not None and len(sh.device_set) > 1:
+        return "walk:mesh-sharded-bins"
+    return "pallas"
 
 
 def predict_forest_quant(split_feats, left_u8s, leaf_values, bins,
-                         depth: int, use_kernel=None,
-                         interpret: bool = False):
-    """[T, N] forest predictions over the narrow plane — kernel on TPU
-    (or forced/interpret), jnp fallback elsewhere.  Multiclass leaf
-    distributions ([T, K, S]) and mesh-sharded bins always take the
-    fallback (the kernel's leaf dot is scalar-leaf shaped, and a
-    pallas_call cannot be partitioned)."""
+                         depth: int, use_kernel=None, interpret=None):
+    """[T, N] forest predictions over the narrow plane, lowered as
+    :func:`quant_lowering` says (``use_kernel`` overrides it).  Mosaic
+    compiles the kernel on a TPU backend; anywhere else a forced kernel
+    runs in interpret mode (tests, the CPU rehearsal of the chip smoke)."""
     if use_kernel is None:
-        use_kernel = quant_kernel() and not _spans_devices(bins)
+        use_kernel = quant_lowering(bins, split_feats.shape[1],
+                                    leaf_values.ndim) == "pallas"
     if use_kernel and leaf_values.ndim == 2:
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
         return _predict_quant_pallas(split_feats, left_u8s, leaf_values,
                                      bins, depth, interpret)
     return _predict_quant_ref(split_feats, left_u8s, leaf_values, bins,
@@ -286,18 +321,16 @@ def quant_traverse_cost(rows: int, n_feat: int, n_bins: int,
                         n_trees: int = 1) -> dict:
     """FLOPs / bytes of one traversal-kernel launch.
 
-    Per (tree, level k-wide): the feature dot (2*k*N), the feature
-    one-hot + bin select (~3*C*N), the mask dot (2*k*B*N) and the bin
-    membership reduce (~3*B*N); plus the terminal leaf dot (2*K*N).
-    Bytes: the uint8 bins plane read ONCE (the kernel's point — the XLA
-    lowering reads it per tree), per-tree node arrays and masks once,
-    [T, N] f32 out written once."""
-    lv_flops = 0.0
-    for level in range(depth):
-        k = 1 << level
-        lv_flops += 2.0 * k + 3.0 * n_feat + 2.0 * k * n_bins \
-            + 3.0 * n_bins
-    flops = float(rows) * n_trees * (lv_flops + 2.0 * n_nodes)
+    Per (tree, level), over the whole K-node axis: the node one-hot
+    (K*N), the node-table dot (2*16*K*N), the feature one-hot + bin
+    select (~3*C*N), the mask dot (2*K*B*N) and the bin membership
+    reduce (~3*B*N); plus the terminal leaf select (one more one-hot and
+    table dot).  Bytes: the uint8 bins plane read ONCE (the kernel's
+    point — the XLA lowering reads it per tree), per-tree node tables
+    and masks once, [T, N] f32 out written once."""
+    sel = (1.0 + 2.0 * _TAB_ROWS) * n_nodes
+    level = sel + 3.0 * n_feat + 2.0 * n_nodes * n_bins + 3.0 * n_bins
+    flops = float(rows) * n_trees * (depth * level + sel)
     read = 1.0 * rows * n_feat \
         + n_trees * (4.0 * n_nodes + 1.0 * n_nodes * n_bins
                      + 4.0 * n_nodes)

@@ -30,17 +30,13 @@ import time
 
 
 def _force_small_cpu() -> None:
-    """Pin the demo to 2 virtual CPU devices (replacing any inherited
-    count — the test suite exports 8) and its own compile cache, like
-    tests/helpers/multihost_worker.py."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    """Under ``JAX_PLATFORMS=cpu`` (what the tests and a CPU-rig bench
+    export), pin the demo to 2 virtual CPU devices, replacing any
+    inherited count (the test suite exports 8).  Any other platform is
+    taken as given — the launcher decides which devices a controller
+    owns (``parallel.mesh.refuse_children_on_chip``)."""
     if os.environ.get("JAX_PLATFORMS") != "cpu":
-        return                      # a real accelerator rig: leave it be
-    # own compilation cache: the suite's persistent cache may hold AOT
-    # entries recorded under a different device count / machine features
-    # (same hazard tests/helpers/multihost_worker.py guards against)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = \
-        os.environ.get("SHIFU_MH_CACHE", "/tmp/shifu_tpu_mh_cache")
+        return
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]
     flags.append("--xla_force_host_platform_device_count=2")
@@ -88,6 +84,8 @@ def main(argv=None) -> int:
     ap.add_argument("--timeout-ms", type=float, default=None)
     ap.add_argument("--staleness", type=int, default=None)
     args = ap.parse_args(argv)
+    from .. import compile_cache
+    compile_cache.configure()
     _force_small_cpu()
 
     import numpy as np
@@ -146,6 +144,15 @@ def main(argv=None) -> int:
     mask_fn = mask_fn_from_settings(1, valid_rate=0.25, seed=7)
 
     ctx = ElasticContext(out, proc=f"ctrl-{args.proc}").start()
+    # a FRESH job starts together: the first controller up would see
+    # itself as the only live member and close the opening steps alone
+    # (a lone survivor always proceeds), so results would depend on
+    # process start skew.  A rejoiner never waits — the job is moving.
+    deadline = time.time() + 120.0
+    while not ctx.rejoined and ctx.board.last_closed_step() < 0 \
+            and len(ctx.board.members()) < args.nproc \
+            and time.time() < deadline:
+        time.sleep(0.05)
     t_train = time.time()
     try:
         res = train_ensemble_streamed(stream, spec, settings, 1, mask_fn,

@@ -45,7 +45,10 @@ def create_new_model(name: str, base_dir: str = ".", algorithm: str = "NN",
 
 # per-algorithm train#params defaults (reference `shifu init -model`,
 # ``BasicModelProcessor.java:404-500`` checkAlgorithmParam): when the
-# sentinel key is absent the whole params map is replaced and saved
+# sentinel key is absent the whole params map is replaced and saved.
+# Every default must pass ``config.meta.validate_train_params`` for its
+# algorithm — the reference's GBT ``DropoutRate`` is left out because the
+# tree trainers here have no dropout and ``train`` rejects the key.
 _ALG_DEFAULT_PARAMS = {
     "LR": ("LearningRate", {"LearningRate": 0.1}),
     "NN": ("Propagation", {"Propagation": "R", "LearningRate": 0.1,
@@ -60,7 +63,7 @@ _ALG_DEFAULT_PARAMS = {
     "GBT": ("MaxDepth", {"TreeNum": 100,
                          "FeatureSubsetStrategy": "TWOTHIRDS",
                          "MaxDepth": 7, "MinInstancesPerNode": 5,
-                         "MinInfoGain": 0.0, "DropoutRate": 0.0,
+                         "MinInfoGain": 0.0,
                          "Impurity": "variance", "LearningRate": 0.05,
                          "Loss": "squared"}),
 }

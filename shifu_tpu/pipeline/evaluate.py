@@ -232,8 +232,8 @@ class EvalProcessor(BasicProcessor):
             return 0
 
         # host sweep by choice: the per-row score CSV above already forced
-        # the scores to the host, and re-uploading them to sweep on device
-        # costs more than the host argsort on this link (~5 MB/s up).  The
+        # the scores to the host; re-uploading them to sweep on device is
+        # a second transfer for one argsort.  The
         # device plane (metrics.sweep_device / Scorer.score_device) serves
         # callers whose scores are HBM-resident.
         from ..eval.metrics import evaluate_curves, sweep

@@ -11,8 +11,8 @@ dispatch round trip.
 Modules:
 
 - :mod:`scorer`  — :class:`AOTScorer`: the modelset's ensemble pinned in
-  HBM once, ``lower()→compile()`` one executable per batch bucket with
-  donated input buffers (no per-request tracing; the recompile sentinel
+  HBM once, ``lower()→compile()`` one executable per batch bucket
+  (no per-request tracing; the recompile sentinel
   from :mod:`shifu_tpu.obs.costs` polices shape churn);
 - :mod:`batcher` — :class:`MicroBatcher`: request queue + deadline
   batcher that coalesces requests into the smallest covering bucket of a

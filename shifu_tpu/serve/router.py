@@ -703,6 +703,8 @@ def spawn_worker(model_set_dir: str, name: str, announce: str,
     ``-D`` properties set in THIS process are forwarded on the worker's
     command line so fleet knobs behave like single-process knobs."""
     from ..config import environment
+    from ..parallel.mesh import refuse_children_on_chip
+    refuse_children_on_chip("a serve replica fleet")
     cmd = [sys.executable, "-m", "shifu_tpu.cli"]
     cmd += [f"-D{k}={v}" for k, v in
             sorted(environment.all_properties().items())]

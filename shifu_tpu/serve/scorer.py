@@ -7,7 +7,7 @@ dispatch is pure per-request overhead, so :class:`AOTScorer` builds ONE
 fused traceable function over the whole ensemble — every model's scores
 as device sub-expressions of a single graph, no host hop between the
 models of a bag — and ``lower()→compile()``s it ONCE per batch bucket at
-startup, with donated input buffers.  A request batch then costs: pad to
+startup.  A request batch then costs: pad to
 the smallest covering bucket, one compiled launch, trim.
 
 Every bucket executable registers with the cost-attribution plane
@@ -282,8 +282,8 @@ class AOTScorer:
 
     ``warm()`` compiles every rung of the ladder up front;
     :meth:`score_batch` then pads to the covering rung, launches the
-    compiled executable (donated input buffers — the pad copy is the
-    only host-side byte movement), and trims.  Thread-safe: the batcher
+    compiled executable (the pad copy is the only host-side byte
+    movement), and trims.  Thread-safe: the batcher
     worker launches while a hot-swap builds the NEXT scorer instance
     elsewhere; one instance's executables are immutable after warm.
     """
@@ -307,26 +307,25 @@ class AOTScorer:
         # bucket records one model launch per quant-kernel forest
         # (serving MFU rows stay honest — the hist_kernel_cost pattern)
         self._quant_kernel_shapes = []
-        if tq.quant_scoring() and tq.quant_kernel():
+        if tq.quant_scoring():
             for m in models:
-                if type(m).__name__ == "IndependentTreeModel" \
-                        and tq.bins_fit_uint8(m.spec.n_bins):
-                    from ..ops.tree import n_tree_nodes
+                if type(m).__name__ != "IndependentTreeModel" \
+                        or not tq.bins_fit_uint8(m.spec.n_bins):
+                    continue
+                t0 = m.trees[0]
+                if tq.quant_lowering(None, t0.n_nodes,
+                                     np.ndim(t0.leaf_value) + 1) == "pallas":
                     self._quant_kernel_shapes.append(dict(
-                        n_feat=self.n_bins_cols,
-                        n_bins=m.spec.n_bins,
-                        n_nodes=n_tree_nodes(m.trees[0].depth),
-                        depth=m.trees[0].depth,
+                        n_feat=self.n_bins_cols, n_bins=m.spec.n_bins,
+                        n_nodes=t0.n_nodes, depth=t0.depth,
                         n_trees=len(m.trees)))
         fn, self.needs_bins = build_ensemble_fn(self.scorer)
-        # donated input buffers: the padded batch is dead the moment the
-        # launch reads it, so XLA may overwrite it in place (CPU's PJRT
-        # cannot donate — gating avoids a warning per compile there)
-        donate = () if jax.default_backend() == "cpu" \
-            else ((0, 1) if self.needs_bins else (0,))
         # AOT template only — never launched directly; every bucket's
-        # executable registers with record_executable in _ensure_compiled
-        self._jitted = jax.jit(fn, donate_argnums=donate)  # shifu-lint: disable=recompile-hazard
+        # executable registers with record_executable in _ensure_compiled.
+        # Inputs are NOT donated: no [n, M] score output can alias an
+        # [n, features] / uint8 bins / packed-wire input, and on the chip
+        # XLA says so once per bucket ("donated buffers were not usable")
+        self._jitted = jax.jit(fn)  # shifu-lint: disable=recompile-hazard
         self._compiled: dict = {}
         self._compiled_raw: dict = {}
         # raw-record family: the norm transform fused as a jnp prelude of
@@ -355,10 +354,8 @@ class AOTScorer:
                 if not needs_bins:
                     return fn(xx)
                 return fn(xx, bb[:, :nbc].astype(bdt))
-            donate_raw = () if jax.default_backend() == "cpu" else (0,)
             # AOT template only — per-bucket executables register below
-            self._jitted_raw = jax.jit(  # shifu-lint: disable=recompile-hazard
-                raw_fn, donate_argnums=donate_raw)
+            self._jitted_raw = jax.jit(raw_fn)  # shifu-lint: disable=recompile-hazard
         self._lock = threading.Lock()
         self._pin_params()
 

@@ -23,7 +23,7 @@ and rewrites the lookup and the update around that layout:
   of Weight Update in Data-Parallel Training");
 - **dense leaves stay replicated**: their per-device partial grads psum
   AFTER ``jax.grad`` — never inside it, because with replication
-  tracking off (``check_rep/check_vma=False``) a ``psum`` inside the
+  tracking off (``check_vma=False``) a ``psum`` inside the
   differentiated region transposes to another psum and inflates every
   cotangent by the axis size.  The loss normalizer is parameter-free,
   so it is computed outside the grad for the same reason (exact);
@@ -44,6 +44,7 @@ the classic forward's clip resolves with no code change).
 from __future__ import annotations
 
 import logging
+from functools import partial
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -61,14 +62,9 @@ log = logging.getLogger(__name__)
 _AXIS = "data"
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (same shim as ops/hist_pallas)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+# replication tracking off: see the module docstring's dense-leaves note
+# for what that obliges
+_shard_map = partial(jax.shard_map, check_vma=False)
 
 
 # ------------------------------------------------------------------ knobs
@@ -372,7 +368,7 @@ def build_inram_fns(plane: WDLShardPlane, stacked, opt_state, opt,
         return st, os_, losses
 
     step = obs.costed_jit("wdl.shard_step", _shard_map(
-        step_local, mesh,
+        step_local, mesh=mesh,
         in_specs=(pspecs, ospecs, P(_AXIS, None), P(_AXIS, None),
                   P(_AXIS), P("ensemble", _AXIS)),
         out_specs=(pspecs, ospecs, P("ensemble"))))
@@ -392,7 +388,7 @@ def build_inram_fns(plane: WDLShardPlane, stacked, opt_state, opt,
         return st, os_
 
     epoch_steps = obs.costed_jit("wdl.shard_epoch_steps", _shard_map(
-        epoch_local, mesh,
+        epoch_local, mesh=mesh,
         in_specs=(pspecs, ospecs, P(None, _AXIS, None),
                   P(None, _AXIS, None), P(None, _AXIS),
                   P("ensemble", None, _AXIS), P(None)),
@@ -411,7 +407,7 @@ def build_inram_fns(plane: WDLShardPlane, stacked, opt_state, opt,
         return jax.vmap(one)(st, tw), jax.vmap(one)(st, vw)
 
     eval_errors = obs.costed_jit("wdl.shard_eval", _shard_map(
-        eval_local, mesh,
+        eval_local, mesh=mesh,
         in_specs=(pspecs, P("ensemble", _AXIS), P("ensemble", _AXIS),
                   P(_AXIS, None), P(_AXIS, None), P(_AXIS)),
         out_specs=(P("ensemble"), P("ensemble"))))
@@ -446,7 +442,7 @@ def build_streamed_fns(plane: WDLShardPlane, stacked, opt_state, opt,
 
     grad_eval_window = obs.costed_jit(
         "wdl.shard_grad_eval_window", _shard_map(
-            gew_local, mesh,
+            gew_local, mesh=mesh,
             in_specs=(pspecs, pspecs, P("ensemble", None), P(_AXIS, None),
                       P(_AXIS, None), P(_AXIS), P("ensemble", _AXIS),
                       P("ensemble", _AXIS)),
@@ -459,7 +455,7 @@ def build_streamed_fns(plane: WDLShardPlane, stacked, opt_state, opt,
         return sacc + stats
 
     eval_window = obs.costed_jit("wdl.shard_eval_window", _shard_map(
-        ew_local, mesh,
+        ew_local, mesh=mesh,
         in_specs=(pspecs, P("ensemble", None), P(_AXIS, None),
                   P(_AXIS, None), P(_AXIS), P("ensemble", _AXIS),
                   P("ensemble", _AXIS)),
@@ -481,7 +477,7 @@ def build_streamed_fns(plane: WDLShardPlane, stacked, opt_state, opt,
         return jax.vmap(one)(st, os_, gacc, wsum)
 
     apply_update = obs.costed_jit("wdl.shard_apply_update", _shard_map(
-        au_local, mesh,
+        au_local, mesh=mesh,
         in_specs=(pspecs, ospecs, pspecs, P("ensemble")),
         out_specs=(pspecs, ospecs)))
 
@@ -631,10 +627,10 @@ def build_serve_forward(spec, params):
         return jax.lax.psum(_gather_rows(tabs, xc, cards, vs, me), _AXIS)
 
     n_tab = len(cards)
-    emb_fn = _shard_map(lookup_local, mesh,
+    emb_fn = _shard_map(lookup_local, mesh=mesh,
                         in_specs=([P(_AXIS, None)] * n_tab, P(None, None)),
                         out_specs=P(None, None, None))
-    wide_fn = _shard_map(lookup_local, mesh,
+    wide_fn = _shard_map(lookup_local, mesh=mesh,
                          in_specs=([P(_AXIS)] * n_tab, P(None, None)),
                          out_specs=P(None, None))
 

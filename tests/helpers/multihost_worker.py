@@ -10,11 +10,6 @@ nproc = int(sys.argv[2])
 port = sys.argv[3]
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-# own compilation cache: the suite's persistent cache (conftest) may hold
-# AOT entries whose recorded machine features mismatch this worker's
-# loader and fail with "Target machine feature ... not supported"
-os.environ["JAX_COMPILATION_CACHE_DIR"] = \
-    os.environ.get("SHIFU_MH_CACHE", "/tmp/shifu_tpu_mh_cache")
 # force EXACTLY 4 local devices, replacing any inherited count (pytest's
 # conftest exports 8)
 flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
@@ -23,15 +18,6 @@ flags.append("--xla_force_host_platform_device_count=4")
 os.environ["XLA_FLAGS"] = " ".join(flags)
 
 import jax  # noqa: E402
-import jax._src.xla_bridge as _xb  # noqa: E402
-
-# keep "tpu" registered like the suite conftest does: pallas/mosaic
-# registers tpu MLIR lowerings at import time and needs the platform
-# known, even under JAX_PLATFORMS=cpu
-for _name in [n for n in list(getattr(_xb, "_backend_factories", {}))
-              if n not in ("cpu", "tpu")]:
-    _xb._backend_factories.pop(_name, None)
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

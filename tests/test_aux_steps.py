@@ -92,6 +92,16 @@ def test_init_model_fills_algorithm_defaults(model_set):
         assert json.load(f)["train"]["params"]["MaxDepth"] == 5
 
 
+def test_init_model_defaults_pass_train_validation():
+    """Whatever `init -model` writes, `train` must accept: the GBT
+    defaults once carried a DropoutRate the validator rejects (1051)."""
+    from shifu_tpu.config.meta import validate_train_params
+    from shifu_tpu.config.model_config import Algorithm
+    from shifu_tpu.pipeline.create import _ALG_DEFAULT_PARAMS
+    for alg, (_, defaults) in _ALG_DEFAULT_PARAMS.items():
+        assert validate_train_params(defaults, Algorithm[alg]) == [], alg
+
+
 def test_export_pmml_nn(prepared_set):
     model_set = prepared_set
     from shifu_tpu.pipeline.export import ExportProcessor

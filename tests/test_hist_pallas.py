@@ -188,3 +188,56 @@ def test_gbt_mesh_equivalence_with_onehot_traversal(monkeypatch):
         np.testing.assert_array_equal(t1.left_mask, t8.left_mask)
         np.testing.assert_allclose(t1.leaf_value, t8.leaf_value,
                                    rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------------------------- TPU lowering, no chip
+def _lowers_for_tpu(fn, *avals) -> str:
+    """StableHLO text of ``fn`` exported for the TPU platform — runs the
+    Pallas->Mosaic lowering (block-shape rules, op types, MLIR
+    verification) on this CPU host; what Mosaic then does with the
+    kernel (VMEM, layouts) only ``chip_smoke.py`` can tell.  Lowered in
+    x32, the chip's configuration (Mosaic has no 64-bit types)."""
+    import jax
+    from jax import export
+    with jax.enable_x64(False):
+        return export.export(jax.jit(fn),
+                             platforms=["tpu"])(*avals).mlir_module()
+
+
+# chip_smoke.py's shapes: 131,072 rows x 66 columns; 65 bins (maxNumBin 64
+# + the missing bin: flat 128-lane tiles) and 64 (paired-lane tiles); the
+# level widths histogram subtraction leaves at MaxDepth 7, K_MAX split
+@pytest.mark.parametrize("n_bins", [65, 64])
+@pytest.mark.parametrize("k", [1, 8, 32, 128])
+def test_tree_hist_kernels_lower_for_tpu(k, n_bins):
+    import jax
+    from functools import partial
+
+    from shifu_tpu.ops.hist_pallas import (build_histograms_pallas,
+                                           build_histograms_pallas_batch)
+    n, c, tb = 131072, 66, 8
+    S = jax.ShapeDtypeStruct
+    text = _lowers_for_tpu(
+        partial(build_histograms_pallas, n_nodes=k, n_bins=n_bins),
+        S((n, c), jnp.uint8), S((n,), jnp.int32), S((n, 2), jnp.float32))
+    assert "tpu_custom_call" in text
+    text = _lowers_for_tpu(
+        partial(build_histograms_pallas_batch, n_nodes=k, n_bins=n_bins),
+        S((n, c), jnp.uint8), S((tb, n), jnp.int32),
+        S((tb, n, 2), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_stats_hist_kernel_lowers_for_tpu():
+    import jax
+    from functools import partial
+
+    from shifu_tpu.ops.hist_pallas import stats_histograms_pallas
+    S = jax.ShapeDtypeStruct
+    for n, c, s, exact in ((131072, 64, 4, (True, True, False, False)),
+                           (131072, 64, 2, (True, True)),
+                           (262144, 256, 4, None)):
+        text = _lowers_for_tpu(
+            partial(stats_histograms_pallas, num_buckets=4096, exact=exact),
+            S((n, c), jnp.int32), S((n, s), jnp.float32))
+        assert "tpu_custom_call" in text

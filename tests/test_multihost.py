@@ -15,6 +15,7 @@ import os
 import socket
 import subprocess
 import sys
+import tempfile
 
 import numpy as np
 import pytest
@@ -46,16 +47,28 @@ def _launch_demo(out: str, proc: int, nproc: int, mode_args,
     cmd = [sys.executable, "-m", "shifu_tpu.parallel.elastic_demo",
            "--out", out, "--proc", str(proc), "--nproc", str(nproc)] \
         + DEMO_SHAPE + list(mode_args)
-    return subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
-                            stderr=subprocess.STDOUT, text=True)
+    # output goes to a FILE, not a pipe: controllers run concurrently but
+    # are waited on one at a time, and a controller blocked on a full
+    # 64 KB pipe (XLA's cache loader can log that much) never reaches its
+    # step boundary — its peers then wait on it forever
+    os.makedirs(out, exist_ok=True)
+    fd, log_path = tempfile.mkstemp(prefix=f"ctrl-{proc}-", suffix=".log",
+                                    dir=out)
+    with os.fdopen(fd, "w") as log:
+        p = subprocess.Popen(cmd, env=env, stdout=log,
+                             stderr=subprocess.STDOUT)
+    p.log_path = log_path
+    return p
 
 
 def _wait(p, what: str, rc_expect: int = 0) -> str:
     try:
-        out, _ = p.communicate(timeout=300)
+        p.wait(timeout=300)
     except subprocess.TimeoutExpired:
         p.kill()
         pytest.fail(f"{what} hung")
+    with open(p.log_path) as f:
+        out = f.read()
     assert p.returncode == rc_expect, \
         f"{what}: rc={p.returncode} (wanted {rc_expect})\n{out[-3000:]}"
     return out

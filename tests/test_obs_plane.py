@@ -608,13 +608,32 @@ def test_analysis_telemetry_missing_empty_torn(tmp_path, capsys):
 
 
 # ------------------------------------------------------ bench --compare
-def test_bench_compare_checked_in_trajectory(capsys):
-    """The in-repo BENCH_r0N files are the compare's native input: r04 ->
-    r05 must parse, print a table, and agree with a hand computation."""
+def _bench_payload(path, scale: float, wrapped: bool) -> str:
+    """A bench payload on disk in either shape the compare reads: the raw
+    JSON line ``bench.py`` prints, or a driver wrapper around it."""
+    doc = {"metric": "nn_train_throughput", "value": 1000.0 * scale,
+           "unit": "rows/sec", "vs_baseline": 2.0,
+           "extra": {"gbt_train_throughput_resident": 500.0 * scale,
+                     "gbt_train_throughput_resident_vs_baseline": 1.2,
+                     "rf_train_throughput": 400.0,
+                     "stats_throughput": 800.0 * (2.0 - scale),
+                     "serve_low_p99_ms": 3.0,
+                     "resume_first_tree_s": 1.5,
+                     "streamed_bench_shape": {"tail": "not a metric"}}}
+    with open(path, "w") as f:
+        json.dump({"n": 1, "rc": 0, "parsed": doc} if wrapped else doc, f)
+    return str(path)
+
+
+def test_bench_compare_recorded_payloads(tmp_path, capsys):
+    """Recorded payloads are the compare's native input: a raw line and
+    a driver wrapper must parse, print a table, and agree with a hand
+    computation."""
     from shifu_tpu.bench import (bench_metrics, compare_bench,
                                  load_bench_file, run_compare)
-    old = load_bench_file(os.path.join(REPO, "BENCH_r04.json"))
-    new = load_bench_file(os.path.join(REPO, "BENCH_r05.json"))
+    po = _bench_payload(tmp_path / "BENCH_r04.json", 1.0, wrapped=True)
+    pn = _bench_payload(tmp_path / "BENCH_r05.json", 0.8, wrapped=False)
+    old, new = load_bench_file(po), load_bench_file(pn)
     om, nm = bench_metrics(old), bench_metrics(new)
     assert "nn_train_throughput" in om and om["nn_train_throughput"] > 0
     rows, regressed = compare_bench(old, new, threshold=0.9)
@@ -622,11 +641,10 @@ def test_bench_compare_checked_in_trajectory(capsys):
             if n in nm and ("throughput" in n or n.endswith("_per_sec"))
             and not n.endswith("_vs_baseline")
             and nm[n] < 0.9 * om[n]]
-    assert sorted(regressed) == sorted(hand)
-    rc = run_compare(os.path.join(REPO, "BENCH_r04.json"),
-                     os.path.join(REPO, "BENCH_r05.json"), threshold=0.9)
+    assert hand and sorted(regressed) == sorted(hand)
+    rc = run_compare(po, pn, threshold=0.9)
     out = capsys.readouterr().out
-    assert rc == (2 if hand else 0)
+    assert rc == 2
     assert "nn_train_throughput" in out and "ratio" in out
 
 
@@ -657,22 +675,17 @@ def test_bench_compare_cli_exit_codes(tmp_path):
     """The shipped entry point: `python bench.py --compare` (no
     benchmark run, no jax traffic) exits 0/2 per the threshold."""
     env = _subprocess_env()
-    r04 = os.path.join(REPO, "BENCH_r04.json")
+    good = _bench_payload(tmp_path / "good.json", 1.0, wrapped=True)
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),
-         "--compare", r04, r04],
+         "--compare", good, good],
         capture_output=True, text=True, env=env, cwd=REPO, timeout=120)
     assert p.returncode == 0, p.stderr
     assert "no tracked throughput regressions" in p.stdout
-    bad = str(tmp_path / "bad.json")
-    doc = json.load(open(r04))
-    doc = doc.get("parsed", doc)
-    doc["value"] = doc["value"] * 0.5
-    with open(bad, "w") as f:
-        json.dump(doc, f)
+    bad = _bench_payload(tmp_path / "bad.json", 0.5, wrapped=False)
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),
-         "--compare", r04, bad, "--threshold", "0.9"],
+         "--compare", good, bad, "--threshold", "0.9"],
         capture_output=True, text=True, env=env, cwd=REPO, timeout=120)
     assert p.returncode == 2, p.stdout + p.stderr
     assert "REGRESSED" in p.stdout

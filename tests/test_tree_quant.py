@@ -205,3 +205,26 @@ def test_cost_model_registered():
               n_trees=1)
     assert est["bytes_accessed"] - est1["bytes_accessed"] < \
         50 * 512 * 32          # grows with trees' arrays, not the plane
+
+
+# serve buckets (1, 8, 64, 512) and the eval plane of chip_smoke.py's GBT
+# (16 trees, MaxDepth 7 = 255 nodes, 65 bins, 66 columns)
+@pytest.mark.parametrize("n", [1, 8, 64, 512, 131072])
+def test_traversal_kernel_lowers_for_tpu(n):
+    """The kernel exported for the TPU platform on this CPU host: runs
+    the Pallas->Mosaic lowering, which is where illegal block shapes and
+    op types are refused (a [T, K] operand blocked (1, K); a float
+    iota) — what Mosaic then does with the kernel only the chip shows."""
+    from functools import partial
+
+    from jax import export
+    t, depth, b, c = 16, 7, 65, 66
+    k = (1 << (depth + 1)) - 1
+    S = jax.ShapeDtypeStruct
+    with jax.enable_x64(False):     # the chip's configuration
+        exp = export.export(
+            jax.jit(partial(tq._predict_quant_pallas, depth=depth)),
+            platforms=["tpu"])(
+            S((t, k), jnp.int32), S((t, k, b), jnp.uint8),
+            S((t, k), jnp.float32), S((n, c), jnp.uint8))
+    assert "tpu_custom_call" in exp.mlir_module()
