@@ -105,7 +105,9 @@ from . import ioutil, obs
 # e2e plane emits pipeline_e2e_wall_s (tracked LOWER-is-better via the
 # new *_wall_s suffix) + pipeline_e2e_disk_passes (the telemetry-backed
 # raw-plane pass count across the whole scripted pipeline).
-BENCH_TELEMETRY_SCHEMA = 14
+# v15: spans also ride the jax.profiler clock; no record this bench
+# emits changed
+BENCH_TELEMETRY_SCHEMA = 15
 
 # measured on this rig (tools/measure_baseline.py); provenance in
 # BASELINE.md — every headline divides by a MEASURED reference-class

@@ -57,8 +57,6 @@ _DECLS: Tuple[Knob, ...] = (
        "master telemetry switch (same as --telemetry / SHIFU_TPU_TELEMETRY)"),
     _k("shifu.tpu.telemetry", "property", "bool", "off",
        "alias of shifu.telemetry (env-folded SHIFU_TPU_TELEMETRY form)"),
-    _k("shifu.telemetry.fence", "property", "bool", "off",
-       "block_until_ready-fence spans for exact device timings"),
     _k("shifu.telemetry.heartbeatSeconds", "property", "float", "5",
        "heartbeat commit interval for obs/health writers"),
     _k("shifu.profile", "property", "str", "",
@@ -67,8 +65,6 @@ _DECLS: Tuple[Knob, ...] = (
        "PSI above which the drift monitor flags a column"),
     _k("SHIFU_TPU_TELEMETRY", "env", "bool", "0",
        "enable telemetry (1/true/on; same as shifu.telemetry)"),
-    _k("SHIFU_TPU_TELEMETRY_FENCE", "env", "bool", "0",
-       "env form of shifu.telemetry.fence"),
     _k("SHIFU_TPU_HEARTBEAT_S", "env", "float", "5",
        "env form of shifu.telemetry.heartbeatSeconds"),
     _k("SHIFU_TPU_LOG", "env", "str", "",

@@ -189,10 +189,27 @@ class Shards:
                         f"{bad_threshold}; last: {f} ({e})") from e
 
     def load_all(self) -> Dict[str, np.ndarray]:
-        parts = list(self.iter_shards())
-        if not parts:
-            raise FileNotFoundError(f"no shards in {self.directory}")
-        return {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
+        from .. import obs
+        with obs.span("data.load"):
+            parts = []
+            shards = self.iter_shards()
+            for i in range(self.n_shards):
+                # a quarantined shard is skipped inside the iterator: the
+                # span then covers it and the next good one
+                with obs.span("data.shard_decode", shard=i) as sp:
+                    part = next(shards, None)
+                    if part is None:
+                        break
+                    sp.set(rows=len(next(iter(part.values()))),
+                           bytes=sum(a.nbytes for a in part.values()))
+                parts.append(part)
+            if not parts:
+                raise FileNotFoundError(f"no shards in {self.directory}")
+            with obs.span("data.concat") as sp:
+                out = {k: np.concatenate([p[k] for p in parts])
+                       for k in parts[0]}
+                sp.set(bytes=sum(a.nbytes for a in out.values()))
+            return out
 
     def _sidecar_sig(self) -> List[List]:
         return [[os.path.basename(f), os.path.getsize(f)]

@@ -9,8 +9,8 @@ processor, trainer, and plane reports through, with a JSONL sink under
 
 Modules:
 
-- :mod:`tracer` — nested wall-clock spans (optionally
-  ``jax.block_until_ready``-fenced) + point events, thread-safe
+- :mod:`tracer` — nested wall-clock spans (each also a ``shifu:``
+  annotation on the ``jax.profiler`` clock) + point events, thread-safe
   collector with a live-span registry, JSONL sink;
 - :mod:`registry` — named counters/gauges/histograms (rows, epochs,
   loss, throughput, device-memory high-water, XLA compile accounting);
@@ -59,8 +59,8 @@ Modules:
 
 Everything is ZERO-COST when disabled (the default): ``span()`` returns
 a shared no-op singleton, instruments are no-op singletons, heartbeat /
-exporter / drift factories return ``None``, no threads, no fencing, no
-files.  Enable with env ``SHIFU_TPU_TELEMETRY=1``, property
+exporter / drift factories return ``None``, no threads, no annotations,
+no files.  Enable with env ``SHIFU_TPU_TELEMETRY=1``, property
 ``-Dshifu.telemetry=on``, or the per-step ``--telemetry`` flag.
 """
 
@@ -68,9 +68,8 @@ from .registry import (counter, gauge, histogram,             # noqa: F401
                        sample_device_memory, ensure_compile_listener,
                        snapshot, get_registry)
 from .tracer import (SCHEMA_VERSION, enabled, set_enabled,    # noqa: F401
-                     fencing_enabled, span, event, fence, flush,
-                     record_span, pending_records, live_spans,
-                     reset_for_tests)
+                     span, event, flush, record_span, pending_records,
+                     live_spans, reset_for_tests)
 from .manifest import (MANIFEST, PREFIXES, SPANS,             # noqa: F401
                        SPAN_PREFIXES, is_declared, is_declared_span)
 from .slo import (SLOTracker, LogBins, LOG_BINS,              # noqa: F401
@@ -98,9 +97,8 @@ from .costs import (costed_jit, record_executable,            # noqa: F401
 
 __all__ = [
     # tracer
-    "SCHEMA_VERSION", "enabled", "set_enabled", "fencing_enabled",
-    "span", "event", "fence", "flush", "record_span", "pending_records",
-    "live_spans", "reset_for_tests",
+    "SCHEMA_VERSION", "enabled", "set_enabled", "span", "event", "flush",
+    "record_span", "pending_records", "live_spans", "reset_for_tests",
     # registry
     "counter", "gauge", "histogram", "sample_device_memory",
     "ensure_compile_listener", "snapshot", "get_registry",

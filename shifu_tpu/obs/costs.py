@@ -6,7 +6,7 @@ records its XLA-estimated FLOPs and bytes accessed
 (``lowered.cost_analysis()``), its compiled memory footprint
 (``compiled.memory_analysis()``), and compile/launch counts, keyed by
 ``(name, abstract input shapes/dtypes)``.  The utilization report
-(:mod:`obs.utilization`) joins these against fenced span wall times to
+(:mod:`obs.utilization`) joins these against span wall times to
 report achieved FLOP/s, bytes/s, percent-of-peak and a roofline verdict
 per plane — the per-op cost visibility the TF system paper ties its
 performance story to.

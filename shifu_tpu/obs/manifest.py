@@ -318,6 +318,30 @@ SPANS: Dict[str, str] = {
     "refresh.retrain": ("warm-start retraining of a refresh candidate "
                         "(checkpoint resume over the data-window "
                         "cursor)"),
+    # ---- the in-RAM NN train job's path (attrs are counts the code
+    # already holds; none costs a device sync)
+    "data.load": "Shards.load_all: every shard decoded, then joined",
+    "data.shard_decode": "one shard read and decoded (shard, rows, bytes)",
+    "data.concat": "the decoded shards joined into one plane (bytes)",
+    "train.split": ("member_masks and the weight products: the "
+                    "train/validation row weights of every member (rows)"),
+    "nn.init": "mesh, params and optimizer state, their device_put",
+    "nn.h2d": ("row padding to the mesh and the first device_put of "
+               "x/y/weights (bytes); ends at dispatch, the copy is "
+               "waited for by whoever next needs it"),
+    "nn.repad": ("MiniBatchs: the plane gathered back to the host "
+                 "(bytes_down), padded to a batch multiple and put on "
+                 "the device again (bytes)"),
+    "nn.epoch": "one epoch of the in-RAM NN trainer (epoch)",
+    "nn.epoch.dispatch": ("rng split and the step / epoch_steps and "
+                          "eval_errors calls (builds them in epoch 0)"),
+    "nn.epoch.fetch": "the packed train/validation error fetch that waits",
+    "nn.epoch.best_copy": "device->host copy of improved members' params",
+    "nn.epoch.progress": "the progress callback (progress file line)",
+    "nn.epoch.checkpoint": "tmp-model and trainer-state checkpoints",
+    "xla.build": ("jax traced / lowered / built (compiled or loaded from "
+                  "the compile cache) one program (stage, program, secs); "
+                  "recorded when it ends"),
 }
 
 # span families whose names embed data (the bench's per-plane spans)

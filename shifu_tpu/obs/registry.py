@@ -250,26 +250,42 @@ def sample_device_memory() -> None:
 _compile_listener_installed = False
 
 
+# jax's own stages of one compilation -> the ``stage`` of an ``xla.build``
+# span: jaxpr trace, lowering, and the backend's build (a compilation, or
+# a load from the persistent cache)
+_BUILD_STAGES = {"jaxpr_trace_duration": "trace",
+                 "jaxpr_to_mlir_module_duration": "lower",
+                 "backend_compile_duration": "compile"}
+_TRACE_FLOOR_S = 1e-3       # shorter traces get no ``xla.build`` span
+
+
 def ensure_compile_listener() -> None:
     """Install (once per process) a ``jax.monitoring`` duration listener
-    that accumulates XLA compilations into ``xla.compile_count`` and the
+    that accumulates XLA compilations into ``xla.compile_count``, the
     time spent tracing, lowering and compiling them into
-    ``xla.compile_time_s``.  The listener itself checks ``enabled()`` so
-    a later disable costs one branch per compile, nothing more."""
+    ``xla.compile_time_s``, and records each stage as an ``xla.build``
+    span.  The listener itself checks ``enabled()`` so a later disable
+    costs one branch per compile, nothing more."""
     global _compile_listener_installed
     if _compile_listener_installed:
         return
     from jax.monitoring import register_event_duration_secs_listener
 
     def _listener(name: str, secs: float, **kw) -> None:
-        # jax's own stages of one compilation: jaxpr trace, lowering and
-        # backend compile (a persistent-cache hit is a short backend
-        # compile).  Matching any name with "compile" in it also summed
+        # Matching any name with "compile" in it also summed
         # /jax/compilation_cache/compile_time_saved_sec — time NOT spent
         if name.startswith("/jax/core/compile/") and tracer.enabled():
-            if name.endswith("/backend_compile_duration"):
+            stage = _BUILD_STAGES.get(name.rsplit("/", 1)[-1])
+            if stage == "compile":
                 _registry.counter("xla.compile_count").inc()
             _registry.counter("xla.compile_time_s").inc(secs)
+            # every jnp wrapper traced inside a larger trace reports its
+            # own trace: hundreds a job, each inside the outer one's span
+            if stage and not (stage == "trace" and secs < _TRACE_FLOOR_S):
+                prog = str(kw.get("fun_name") or "")
+                if prog.startswith("jit(") and prog.endswith(")"):
+                    prog = prog[4:-1]       # lower/compile say jit(<name>)
+                tracer.record_build(stage, secs, prog)
 
     register_event_duration_secs_listener(_listener)
     _compile_listener_installed = True

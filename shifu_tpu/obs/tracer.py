@@ -18,15 +18,24 @@ JSONL schema (``SCHEMA_VERSION``) — one JSON object per line, keyed by
 - ``metric``: one registry instrument snapshot (see
   :mod:`shifu_tpu.obs.registry`).
 
+One clock: a live :class:`Span` also holds a
+``jax.profiler.TraceAnnotation`` named ``shifu:<name>`` for its lifetime
+(stats: ``id``, ``parent``, the numeric attrs).  Inside a ``jax.profiler``
+session (``--profile``) the span therefore lies in the ``.xplane.pb`` on
+``/host:CPU``, on the clock of the device's ``XLA Ops`` / ``XLA Modules``
+lines; outside a session an annotation costs an atomic load.
+
 Zero-cost when disabled: :func:`span` returns a shared no-op singleton
-(one function call + one branch per call site), :func:`event` returns
-immediately, :func:`fence` never touches jax.
+(one function call + one branch per call site) and builds no annotation,
+:func:`event` returns immediately.
 """
 
 from __future__ import annotations
 
 import json
+import numbers
 import os
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -124,7 +133,14 @@ from typing import Any, Dict, List, Optional
 # (``stats_throughput`` / ``norm_throughput`` serial-vs-pooled) +
 # ``pipeline_e2e_wall_s`` / ``pipeline_e2e_disk_passes`` on ``--plane
 # e2e``
-SCHEMA_VERSION = 14
+# v15: spans on the profiler's clock (``shifu:`` TraceAnnotations), the
+# NN train job's load-path spans (``data.*``, ``train.split``, ``nn.*``)
+# and ``xla.build`` records
+SCHEMA_VERSION = 15
+
+# the prefix of every annotation the program writes into a profiler
+# session (``bench:`` belongs to the benchmark's own markers)
+ANNOTATION_PREFIX = "shifu:"
 
 _TRUE = ("1", "true", "on", "yes")
 
@@ -133,7 +149,6 @@ _TRUE = ("1", "true", "on", "yes")
 # hot path; reset_for_tests()/set_enabled(None) clears it.
 _enabled_override: Optional[bool] = None
 _enabled_cache: Optional[bool] = None
-_fence_cache: Optional[bool] = None
 
 
 def _truthy(v: Optional[str]) -> bool:
@@ -166,24 +181,9 @@ def enabled() -> bool:
 def set_enabled(value: Optional[bool]) -> None:
     """Programmatic override (CLI flag, tests); ``None`` restores the
     env/property lookup."""
-    global _enabled_override, _enabled_cache, _fence_cache
+    global _enabled_override, _enabled_cache
     _enabled_override = value
     _enabled_cache = None
-    _fence_cache = None
-
-
-def fencing_enabled() -> bool:
-    """Fenced spans: ``jax.block_until_ready`` at :meth:`Span.fence` so a
-    span's wall-clock covers the device work it launched, not just the
-    dispatch.  Env ``SHIFU_TPU_TELEMETRY_FENCE`` / property
-    ``shifu.telemetry.fence``; only active while telemetry is on."""
-    global _fence_cache
-    if not enabled():
-        return False
-    if _fence_cache is None:
-        _fence_cache = _lookup("SHIFU_TPU_TELEMETRY_FENCE",
-                               "shifu.telemetry.fence")
-    return _fence_cache
 
 
 # ------------------------------------------------------------- collector
@@ -254,12 +254,28 @@ class _Collector:
 _collector = _Collector()
 
 
+def _stats(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """The attrs an annotation can carry: numbers only (a string could
+    hold the separators of the annotation's own ``name#k=v,...#`` form)."""
+    return {k: v for k, v in attrs.items() if isinstance(v, numbers.Real)}
+
+
+def _annotation(name: str, span_id: int, parent: Optional[int],
+                stats: Dict[str, Any]):
+    """The ``shifu:<name>`` annotation of one span; ``stats`` is owned."""
+    from jax.profiler import TraceAnnotation
+    stats["id"] = span_id
+    if parent is not None:
+        stats["parent"] = parent
+    return TraceAnnotation(ANNOTATION_PREFIX + name, **stats)
+
+
 class Span:
     """A live span; use via ``with span("name", k=v) as sp:``.  Extra
-    attributes attach with :meth:`set`; :meth:`fence` blocks on device
-    values when fencing is on so the duration covers real work."""
+    attributes attach with :meth:`set`.  Entered and left on ONE thread:
+    the annotation it holds is that thread's."""
 
-    __slots__ = ("name", "attrs", "id", "parent", "_ts", "_t0")
+    __slots__ = ("name", "attrs", "id", "parent", "_ts", "_t0", "_ann")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
@@ -268,16 +284,21 @@ class Span:
         self.parent: Optional[int] = None
         self._ts = 0.0
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "Span":
         self.parent = _collector.current_parent()
         _collector.stack.append(self.id)
         self._ts = time.time()
-        self._t0 = time.perf_counter()
         _collector.span_opened(self.id, self.name, self._ts)
+        self._ann = _annotation(self.name, self.id, self.parent,
+                                _stats(self.attrs))
+        self._t0 = time.perf_counter()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
         dur = time.perf_counter() - self._t0
         st = _collector.stack
         if st and st[-1] == self.id:
@@ -294,16 +315,9 @@ class Span:
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_stats(attrs))
         return self
-
-    def fence(self, value: Any) -> Any:
-        """Block until ``value``'s device buffers are ready (fencing mode
-        only) so async dispatch doesn't flatter this span; returns the
-        value either way."""
-        if fencing_enabled():
-            import jax
-            jax.block_until_ready(value)
-        return value
 
 
 class _NullSpan:
@@ -321,9 +335,6 @@ class _NullSpan:
 
     def set(self, **attrs: Any) -> "_NullSpan":
         return self
-
-    def fence(self, value: Any) -> Any:
-        return value
 
 
 _NULL_SPAN = _NullSpan()
@@ -368,12 +379,27 @@ def record_span(name: str, ts: float, dur_s: float,
     return sid
 
 
-def fence(value: Any) -> Any:
-    """Module-level fence for call sites without a span handle."""
-    if fencing_enabled():
-        import jax
-        jax.block_until_ready(value)
-    return value
+_UNSAFE = re.compile(r"[^\w.<>-]")      # kept out of an annotation's stats
+
+
+def record_build(stage: str, secs: float, program: str = "") -> None:
+    """One ``xla.build`` span: jax traced (``stage`` ``trace``), lowered
+    (``lower``) or built (``compile``: compiled, or loaded from the
+    compile cache) ``program`` in the ``secs`` that have just ended, on
+    this thread.  The ``jax.monitoring`` listener learns of the work when
+    it ends and an annotation cannot be back-dated, so on the profiler's
+    clock this is a zero-length marker that carries ``secs``: a reader
+    rebuilds the interval [end - secs, end]."""
+    if not enabled():
+        return
+    parent = _collector.current_parent()
+    sid = record_span("xla.build", time.time() - secs, secs,
+                      {"stage": stage, "program": program}, parent=parent)
+    stats: Dict[str, Any] = {"secs": secs, "stage": stage}
+    if program:
+        stats["program"] = _UNSAFE.sub("_", program)
+    with _annotation("xla.build", sid, parent, stats):
+        pass
 
 
 def pending_records() -> List[Dict[str, Any]]:

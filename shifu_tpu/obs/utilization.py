@@ -2,7 +2,7 @@
 
 ``shifu-tpu analysis --telemetry --utilization`` joins the cost records
 (:mod:`obs.costs`: per-executable FLOPs / bytes accessed × launches)
-against the fenced span wall times of each flush block and reports, per
+against the span wall times of each flush block and reports, per
 PLANE (the executable-name prefix: ``nn.``, ``gbt.``, ``stats.``, …):
 
 - total FLOPs and bytes moved, achieved FLOP/s and bytes/s over the

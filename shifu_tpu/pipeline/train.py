@@ -18,7 +18,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .. import faults
+from .. import faults, obs
 from ..config.model_config import Algorithm
 from ..config.validator import ModelStep
 from ..data.shards import Shards
@@ -294,34 +294,38 @@ class TrainProcessor(BasicProcessor):
                     # arbitrary classes
                     log.warning("upSampleWeight ignored for multi-class")
                     up_w = 1.0
-                train_w, valid_w = member_masks(
-                    n, 1 if is_gs else bags,
-                    valid_rate=mc.train.validSetRate,
-                    kfold=run_kfold,
-                    sample_rate=mc.train.baggingSampleRate,
-                    replacement=mc.train.baggingWithReplacement,
-                    stratified=mc.train.stratifiedSample,
-                    up_sample_weight=up_w,
-                    targets=y, seed=settings.seed)
-                if is_gs:
-                    # every trial in the group sees the SAME split — they
-                    # must differ only in hypers, never in data draw
-                    train_w = np.tile(train_w, (len(run), 1))
-                    valid_w = np.tile(valid_w, (len(run), 1))
-                y_members = None
-                if ova:
-                    # fan each bagging member out per class: member b*K+k
-                    # trains class k's binary task on bag b's mask
-                    b0 = train_w.shape[0]
-                    train_w = np.repeat(train_w, K, axis=0)
-                    valid_w = np.repeat(valid_w, K, axis=0)
-                    y_members = np.tile(
-                        np.stack([(y == k).astype(np.float32)
-                                  for k in range(K)]), (b0, 1))
-                    spec.extra.update({"ova_classes": K, "n_classes": K})
-                n_members = train_w.shape[0]  # kfold mode yields numKFold
-                train_w = train_w * w[None, :]
-                valid_w = valid_w * w[None, :]
+                with obs.span("train.split", rows=n):
+                    train_w, valid_w = member_masks(
+                        n, 1 if is_gs else bags,
+                        valid_rate=mc.train.validSetRate,
+                        kfold=run_kfold,
+                        sample_rate=mc.train.baggingSampleRate,
+                        replacement=mc.train.baggingWithReplacement,
+                        stratified=mc.train.stratifiedSample,
+                        up_sample_weight=up_w,
+                        targets=y, seed=settings.seed)
+                    if is_gs:
+                        # every trial in the group sees the SAME split —
+                        # they must differ only in hypers, never in data
+                        # draw
+                        train_w = np.tile(train_w, (len(run), 1))
+                        valid_w = np.tile(valid_w, (len(run), 1))
+                    y_members = None
+                    if ova:
+                        # fan each bagging member out per class: member
+                        # b*K+k trains class k's binary task on bag b's mask
+                        b0 = train_w.shape[0]
+                        train_w = np.repeat(train_w, K, axis=0)
+                        valid_w = np.repeat(valid_w, K, axis=0)
+                        y_members = np.tile(
+                            np.stack([(y == k).astype(np.float32)
+                                      for k in range(K)]), (b0, 1))
+                        spec.extra.update(
+                            {"ova_classes": K, "n_classes": K})
+                    # kfold mode yields numKFold members
+                    n_members = train_w.shape[0]
+                    train_w = train_w * w[None, :]
+                    valid_w = valid_w * w[None, :]
                 init_list = self._continuous_init(spec, n_members, alg,
                                                   settings)
 

@@ -292,46 +292,55 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
     queue of jobs (``gs/GridSearch.java:62``)."""
     bags = train_w.shape[0]
     n = x.shape[0]
-    if mesh is None:
-        mesh = meshlib.device_mesh(n_ensemble=bags)
-    data_size = mesh.shape["data"]
-    if y_members is not None:
-        # fold the per-member targets through the same row padding as the
-        # weights, then restore the shared-y variable for the common path
-        x, y, train_w, valid_w, y_members = _pad_all(
-            x, y, train_w, valid_w, data_size, y_members)
-    else:
-        x, y, train_w, valid_w = _pad_all(x, y, train_w, valid_w, data_size)
-
-    key = jax.random.PRNGKey(settings.seed)
-    if init_params_list is None:
-        keys = jax.random.split(key, bags)
-        init_params_list = [nn_model.init_params(k, spec, settings.weight_initializer)
-                            for k in keys]
-    opt = make_optimizer(settings.optimizer, settings.learning_rate,
-                         **settings.opt_kwargs)
-    # ---- precision ladder (shifu.train.precision): bf16/mixed cast the
-    # training params narrow; mixed keeps the f32 master in the opt state
-    precision = resolve_precision(settings.precision)
-    if precision != "f32":
-        init_params_list = [cast_tree(p, jnp.bfloat16)
-                            for p in init_params_list]
-    stacked = _stack(init_params_list)
-    if precision == "mixed":
-        opt_state = _stack([mixed_init(opt, p) for p in init_params_list])
-    else:
-        opt_state = _stack([opt.init(p) for p in init_params_list])
-
     from jax.sharding import NamedSharding, PartitionSpec as P
-    sh_ens = NamedSharding(mesh, P("ensemble"))
-    stacked = jax.device_put(stacked, sh_ens)
-    opt_state = jax.device_put(opt_state, sh_ens)
-    xd = jax.device_put(x, NamedSharding(mesh, P("data", None)))
-    yd = jax.device_put(y, NamedSharding(mesh, P("data")))
-    twd = jax.device_put(train_w, NamedSharding(mesh, P("ensemble", "data")))
-    vwd = jax.device_put(valid_w, NamedSharding(mesh, P("ensemble", "data")))
-    ymd = None if y_members is None else jax.device_put(
-        y_members, NamedSharding(mesh, P("ensemble", "data")))
+    with obs.span("nn.init", members=bags):
+        if mesh is None:
+            mesh = meshlib.device_mesh(n_ensemble=bags)
+        data_size = mesh.shape["data"]
+        key = jax.random.PRNGKey(settings.seed)
+        if init_params_list is None:
+            keys = jax.random.split(key, bags)
+            init_params_list = [
+                nn_model.init_params(k, spec, settings.weight_initializer)
+                for k in keys]
+        opt = make_optimizer(settings.optimizer, settings.learning_rate,
+                             **settings.opt_kwargs)
+        # ---- precision ladder (shifu.train.precision): bf16/mixed cast
+        # the training params narrow; mixed keeps the f32 master in the
+        # opt state
+        precision = resolve_precision(settings.precision)
+        if precision != "f32":
+            init_params_list = [cast_tree(p, jnp.bfloat16)
+                                for p in init_params_list]
+        stacked = _stack(init_params_list)
+        if precision == "mixed":
+            opt_state = _stack([mixed_init(opt, p)
+                                for p in init_params_list])
+        else:
+            opt_state = _stack([opt.init(p) for p in init_params_list])
+        sh_ens = NamedSharding(mesh, P("ensemble"))
+        stacked = jax.device_put(stacked, sh_ens)
+        opt_state = jax.device_put(opt_state, sh_ens)
+
+    def put_plane(x, y, train_w, valid_w, y_members, multiple):
+        """Pad the rows to ``multiple`` and put the plane on the mesh."""
+        x, y, train_w, valid_w, *ym = _pad_all(
+            x, y, train_w, valid_w, multiple, y_members)
+        y_members = ym[0] if ym else None
+        sh_members = NamedSharding(mesh, P("ensemble", "data"))
+        return (jax.device_put(x, NamedSharding(mesh, P("data", None))),
+                jax.device_put(y, NamedSharding(mesh, P("data"))),
+                jax.device_put(train_w, sh_members),
+                jax.device_put(valid_w, sh_members),
+                None if y_members is None
+                else jax.device_put(y_members, sh_members))
+
+    with obs.span("nn.h2d") as sp:
+        # per-member targets (one-vs-all) fold through the same row
+        # padding as the weights
+        plane = put_plane(x, y, train_w, valid_w, y_members, data_size)
+        sp.set(bytes=_nbytes(plane))
+    xd, yd, twd, vwd, ymd = plane
 
     # per-member hyper rows [B, 4]: lr_scale, l2, l1, dropout — uniform from
     # settings unless stacked grid trials supplied their own
@@ -419,20 +428,11 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
         # pad rows to a batch multiple so the tail is never dropped;
         # padded rows carry zero weight (_gather_np: a plain np.asarray
         # cannot read cross-host-sharded arrays under multiple controllers)
-        if ymd is None:
-            x, y, train_w, valid_w = _pad_all(
-                _gather_np(xd), _gather_np(yd), _gather_np(twd),
-                _gather_np(vwd), bs)
-        else:
-            x, y, train_w, valid_w, y_members = _pad_all(
-                _gather_np(xd), _gather_np(yd), _gather_np(twd),
-                _gather_np(vwd), bs, _gather_np(ymd))
-            ymd = jax.device_put(y_members,
-                                 NamedSharding(mesh, P("ensemble", "data")))
-        xd = jax.device_put(x, NamedSharding(mesh, P("data", None)))
-        yd = jax.device_put(y, NamedSharding(mesh, P("data")))
-        twd = jax.device_put(train_w, NamedSharding(mesh, P("ensemble", "data")))
-        vwd = jax.device_put(valid_w, NamedSharding(mesh, P("ensemble", "data")))
+        with obs.span("nn.repad", bytes_down=_nbytes(plane)) as sp:
+            plane = put_plane(*(None if a is None else _gather_np(a)
+                                for a in plane), bs)
+            sp.set(bytes=_nbytes(plane))
+        xd, yd, twd, vwd, ymd = plane
 
     stops = [WindowEarlyStop(settings.early_stop_window) for _ in range(bags)]
     best_valid = np.full(bags, np.inf)
@@ -507,64 +507,77 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
 
     obs_on = obs.enabled()
     for epoch in range(start_epoch, epochs_target):
-        ep_t0 = time.perf_counter()
-        key, sub = jax.random.split(key)
-        rngs = jax.random.split(sub, bags)
-        if bs and bs < n_padded:
-            stacked, opt_state = epoch_steps(
-                stacked, opt_state, rngs, lr_scale, xd,
-                yd if ymd is None else ymd, twd, bs,
-                (n_padded - bs) // bs + 1)
-        else:
-            stacked, opt_state, _ = step(stacked, opt_state, xd,
-                                         yd if ymd is None else ymd, twd,
-                                         rngs, lr_scale)
-        tr, va = eval_errors(stacked, twd, vwd, xd,
-                             yd if ymd is None else ymd)
-        tr, va = _gather_np(jnp.stack([tr, va]))       # one fetch
-        history.append((float(tr.mean()), float(va.mean())))
-        epochs_run = epoch + 1
-        if obs_on:
-            # host-side per-epoch metrics: the _gather_np fetch above IS
-            # the value-forcing sync, so the wall-clock covers real work
-            dt = time.perf_counter() - ep_t0
-            obs.counter("train.epochs").inc()
-            obs.histogram("train.epoch_s").observe(dt)
-            obs.gauge("train.valid_err").set(float(va.mean()))
-            obs.event("epoch", trainer="nn", epoch=epoch,
-                      train_err=round(float(tr.mean()), 6),
-                      valid_err=round(float(va.mean()), 6), rows=n,
-                      rows_per_sec=round(n / max(dt, 1e-9), 1))
+        with obs.span("nn.epoch", epoch=epoch):
+            ep_t0 = time.perf_counter()
+            with obs.span("nn.epoch.dispatch"):
+                key, sub = jax.random.split(key)
+                rngs = jax.random.split(sub, bags)
+                if bs and bs < n_padded:
+                    stacked, opt_state = epoch_steps(
+                        stacked, opt_state, rngs, lr_scale, xd,
+                        yd if ymd is None else ymd, twd, bs,
+                        (n_padded - bs) // bs + 1)
+                else:
+                    stacked, opt_state, _ = step(
+                        stacked, opt_state, xd,
+                        yd if ymd is None else ymd, twd, rngs, lr_scale)
+                tr, va = eval_errors(stacked, twd, vwd, xd,
+                                     yd if ymd is None else ymd)
+                packed = jnp.stack([tr, va])
+            with obs.span("nn.epoch.fetch"):
+                tr, va = _gather_np(packed)            # one fetch
+            history.append((float(tr.mean()), float(va.mean())))
+            epochs_run = epoch + 1
+            if obs_on:
+                # host-side per-epoch metrics: the _gather_np fetch above
+                # IS the value-forcing sync, so the wall-clock covers real
+                # work
+                dt = time.perf_counter() - ep_t0
+                obs.counter("train.epochs").inc()
+                obs.histogram("train.epoch_s").observe(dt)
+                obs.gauge("train.valid_err").set(float(va.mean()))
+                obs.event("epoch", trainer="nn", epoch=epoch,
+                          train_err=round(float(tr.mean()), 6),
+                          valid_err=round(float(va.mean()), 6), rows=n,
+                          rows_per_sec=round(n / max(dt, 1e-9), 1))
 
-        improved = np.flatnonzero(va < best_valid)
-        if improved.size:
-            host = _to_host(stacked)
-            for i in improved:
-                best_valid[i], best_train[i] = va[i], tr[i]
-                best_params[i] = jax.tree_util.tree_map(lambda a: a[i].copy(), host)
-        if progress:
-            progress(epoch, float(tr.mean()), float(va.mean()))
-        if checkpoint and settings.tmp_model_every and \
-                (epoch + 1) % settings.tmp_model_every == 0:
-            checkpoint(epoch, _unstack(stacked, bags))
-        if settings.learning_decay > 0:
-            lr_scale *= (1.0 - settings.learning_decay)
-        stop_now = False
-        if settings.early_stop_window > 0:
-            # evaluate every member's window (no short-circuit: the stop
-            # counters must advance uniformly) then stop when all agree
-            flags = [s.should_stop(float(v)) for s, v in zip(stops, va)]
-            stop_now = all(flags)
-        if settings.checkpoint_dir and settings.checkpoint_every and \
-                ((epoch + 1) % settings.checkpoint_every == 0 or stop_now):
-            # saved AFTER the early-stop windows advanced (and forced on
-            # the stop epoch): a resumed run replays the exact stop state
-            from . import checkpoint as ckpt
-            ckpt.save_state(settings.checkpoint_dir, epoch + 1,
-                            _ckpt_state(stacked, opt_state, key,
-                                        best_valid, best_train,
-                                        best_params, stops),
-                            precision=precision)
+            improved = np.flatnonzero(va < best_valid)
+            if improved.size:
+                with obs.span("nn.epoch.best_copy", members=improved.size):
+                    host = _to_host(stacked)
+                    for i in improved:
+                        best_valid[i], best_train[i] = va[i], tr[i]
+                        best_params[i] = jax.tree_util.tree_map(
+                            lambda a: a[i].copy(), host)
+            if progress:
+                with obs.span("nn.epoch.progress"):
+                    progress(epoch, float(tr.mean()), float(va.mean()))
+            if checkpoint and settings.tmp_model_every and \
+                    (epoch + 1) % settings.tmp_model_every == 0:
+                with obs.span("nn.epoch.checkpoint"):
+                    checkpoint(epoch, _unstack(stacked, bags))
+            if settings.learning_decay > 0:
+                lr_scale *= (1.0 - settings.learning_decay)
+            stop_now = False
+            if settings.early_stop_window > 0:
+                # evaluate every member's window (no short-circuit: the
+                # stop counters must advance uniformly) then stop when all
+                # agree
+                flags = [s.should_stop(float(v)) for s, v in zip(stops, va)]
+                stop_now = all(flags)
+            if settings.checkpoint_dir and settings.checkpoint_every and \
+                    ((epoch + 1) % settings.checkpoint_every == 0
+                     or stop_now):
+                # saved AFTER the early-stop windows advanced (and forced
+                # on the stop epoch): a resumed run replays the exact stop
+                # state
+                from . import checkpoint as ckpt
+                with obs.span("nn.epoch.checkpoint"):
+                    ckpt.save_state(settings.checkpoint_dir, epoch + 1,
+                                    _ckpt_state(stacked, opt_state, key,
+                                                best_valid, best_train,
+                                                best_params, stops),
+                                    precision=precision)
         if stop_now:
             obs.event("early_stop", trainer="nn", epoch=epoch,
                       window=settings.early_stop_window)
@@ -580,6 +593,11 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
     return EnsembleResult(params=best_params, train_errors=best_train,
                           valid_errors=best_valid, epochs_run=epochs_run,
                           history=history)
+
+
+def _nbytes(arrays) -> int:
+    """Bytes of the arrays that are there (shapes only: no sync)."""
+    return sum(a.nbytes for a in arrays if a is not None)
 
 
 def _pad_all(x, y, train_w, valid_w, multiple, y_members=None):

@@ -74,15 +74,6 @@ def test_span_error_marked(telemetry):
     assert rec["attrs"]["error"] == "ValueError"
 
 
-def test_span_fence_blocks_values(telemetry, monkeypatch):
-    monkeypatch.setenv("SHIFU_TPU_TELEMETRY_FENCE", "1")
-    obs.set_enabled(True)            # re-derive the fence cache
-    assert obs.fencing_enabled()
-    with obs.span("fenced") as sp:
-        out = sp.fence(jnp.ones((4,)) * 2.0)
-    np.testing.assert_array_equal(np.asarray(out), 2.0 * np.ones(4))
-
-
 # ------------------------------------------------------- JSONL round-trip
 def test_jsonl_schema_roundtrip(telemetry, tmp_path):
     with obs.span("STATS", kind="step") as sp:
@@ -159,7 +150,7 @@ def test_registry_gauge_high_water_and_type_guard(telemetry):
 def test_disabled_mode_writes_nothing(telemetry_off, tmp_path):
     assert obs.span("x") is obs.span("y")    # shared null singleton
     with obs.span("root") as sp:
-        sp.set(a=1).fence(jnp.ones(3))
+        sp.set(a=1)
         obs.event("tick")
         obs.counter("c").inc()
         obs.gauge("g").set(1)
@@ -253,12 +244,12 @@ def test_disabled_telemetry_overhead_within_noise(telemetry_off):
 
     def instrumented(p):
         for i in range(200):
-            with obs.span("train_step", i=i) as sp:
+            with obs.span("train_step", i=i):
                 # the v2 plane's per-window hot-path additions: the
                 # ingest prep/wait spans (null singletons when off) —
                 # they must cost one call + one branch, nothing more
                 with obs.span("ingest.window_prep", window=i):
-                    p = sp.fence(step(p, x))
+                    p = step(p, x)
                 obs.counter("steps").inc()
                 obs.histogram("loss").observe(0.0)
         return float(p)

@@ -195,7 +195,7 @@ def test_flush_emits_cost_records_and_backend_meta(telemetry, tmp_path):
     trace = str(tmp_path / "telemetry" / "trace.jsonl")
     assert obs.flush(trace, step="TRAIN")
     lines = [json.loads(line) for line in open(trace)]
-    assert lines[0]["schema_version"] == obs.SCHEMA_VERSION == 14
+    assert lines[0]["schema_version"] == obs.SCHEMA_VERSION == 15
     assert lines[0]["backend"]["platform"]      # peak-table resolver key
     costs = [ln for ln in lines if ln["kind"] == "cost"]
     assert len(costs) == 1 and costs[0]["name"] == "test.flushme"
@@ -461,7 +461,7 @@ def test_monitor_json_snapshot_and_exit_codes(tmp_path):
                               exit_code=0), f)
     doc, rc = monitor_mod.status_json(str(tmp_path), now=now)
     assert rc == 0
-    assert doc["kind"] == "monitor" and doc["schema_version"] == 14
+    assert doc["kind"] == "monitor" and doc["schema_version"] == 15
     assert doc["summary"]["counts"] == {"live": 1, "stalled": 0,
                                         "stale": 0, "exited": 1}
     assert doc["summary"]["quorum"] == 1.0
