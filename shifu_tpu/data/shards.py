@@ -5,13 +5,21 @@ from __future__ import annotations
 import json
 import logging
 import os
+import struct
 import zipfile
+import zlib
+from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 from dataclasses import dataclass, field
-from typing import Dict, Iterator, List, Optional, Sequence
+from typing import (Callable, Dict, Iterator, List, NamedTuple, Optional,
+                    Sequence, Tuple, TypeVar)
 
 import numpy as np
+from numpy.lib import format as npf
 
 log = logging.getLogger(__name__)
+
+T = TypeVar("T")
 
 # sidecar manifest of per-shard row counts (written on first scan; the
 # norm step writes the counts straight into schema.json as "shardRows",
@@ -31,24 +39,179 @@ def bins_wire_dtype(n_bins: int) -> np.dtype:
     return np.dtype(np.int32)
 
 
+def _npy_header(f) -> Tuple[tuple, bool, np.dtype]:
+    """(shape, fortran_order, dtype) of the npy stream ``f`` stands at the
+    start of; ``f`` is left at the array's first byte."""
+    if npf.read_magic(f) == (1, 0):
+        return npf.read_array_header_1_0(f)
+    return npf.read_array_header_2_0(f)
+
+
 def _npz_rows(path: str) -> int:
     """Row count of one npz shard WITHOUT decoding any array: read the
     npy header of one member through the zip directory.  Falls back to a
     full load on any format surprise."""
     try:
-        from numpy.lib import format as npf
         with zipfile.ZipFile(path) as z:
             names = z.namelist()
             name = "y.npy" if "y.npy" in names else names[0]
             with z.open(name) as f:
-                ver = npf.read_magic(f)
-                if ver == (1, 0):
-                    shape, _, _ = npf.read_array_header_1_0(f)
-                else:
-                    shape, _, _ = npf.read_array_header_2_0(f)
+                shape = _npy_header(f)[0]
                 return int(shape[0]) if shape else 0
     except Exception:
         return int(len(np.load(path)["y"]))
+
+
+# the direct fill reads and checksums a member in pieces of this size, so
+# the CRC pass finds the bytes it has just read still in the cache
+_FILL_PIECE = 4 << 20
+
+
+class _Member(NamedTuple):
+    """One array of an npz shard, as the zip directory and its npy header
+    describe it.  ``start`` is the file offset of the member's npy
+    header when the array can be read straight into its destination (a
+    *stored* member in C order with a plain dtype — what ``np.savez``
+    writes); None sends the member through ``np.load``."""
+    shape: tuple
+    dtype: np.dtype
+    start: Optional[int] = None
+    head: int = 0              # length of the npy header
+    crc: int = 0               # the directory's CRC-32 of the member
+
+
+class _ShardPlan(NamedTuple):
+    path: str
+    rows: int
+    members: Dict[str, _Member]
+
+    @property
+    def layout(self) -> Dict[str, tuple]:
+        """What every shard of a set must agree on, key by key."""
+        return {k: (m.dtype, m.shape[1:]) for k, m in self.members.items()}
+
+
+def _plan_npz(path: str) -> _ShardPlan:
+    """Sizes before bytes: the shape, dtype and whereabouts of every
+    array of one npz shard, read from the zip directory and the npy
+    headers alone.  A member whose length disagrees with its header is a
+    ``BadZipFile`` here, before a byte of the plane is allocated."""
+    members: Dict[str, _Member] = {}
+    with open(path, "rb") as f, zipfile.ZipFile(f) as z:
+        for info in z.infolist():
+            with z.open(info) as m:
+                shape, fortran, dtype = _npy_header(m)
+                head = m.tell()
+            start = None
+            if info.compress_type == zipfile.ZIP_STORED and not fortran \
+                    and not dtype.hasobject:
+                nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
+                if info.file_size != head + nbytes:
+                    raise zipfile.BadZipFile(
+                        f"{path}: member {info.filename} holds "
+                        f"{info.file_size} bytes, its header describes "
+                        f"{head + nbytes}")
+                f.seek(info.header_offset)
+                local = f.read(30)
+                if local[:4] != b"PK\x03\x04":
+                    raise zipfile.BadZipFile(
+                        f"{path}: no local header for {info.filename}")
+                start = info.header_offset + 30 + sum(
+                    struct.unpack("<HH", local[26:30]))
+            key = info.filename[:-4] if info.filename.endswith(".npy") \
+                else info.filename
+            members[key] = _Member(tuple(shape), dtype, start, head,
+                                   info.CRC)
+    rows = {m.shape[0] if m.shape else None for m in members.values()}
+    if len(rows) != 1 or None in rows:
+        raise ValueError(f"{path}: arrays of unequal or no length "
+                         f"{ {k: m.shape for k, m in members.items()} }")
+    return _ShardPlan(path, int(rows.pop()), members)
+
+
+def _fill_npz(plan: _ShardPlan, dest: Dict[str, np.ndarray]) -> bool:
+    """Read one npz shard into ``dest`` (its row slices of the plane).
+    Stored members go file -> slice in one copy, checked against the
+    directory's CRC-32 as ``zipfile`` would; the others are decoded by
+    ``np.load`` and assigned.  True when every member went straight."""
+    decode = [k for k, m in plan.members.items() if m.start is None]
+    with open(plan.path, "rb") as f:
+        for k, m in plan.members.items():
+            if m.start is None:
+                continue
+            f.seek(m.start)
+            crc = zlib.crc32(f.read(m.head))
+            view = dest[k].reshape(-1).view(np.uint8)
+            for a in range(0, len(view), _FILL_PIECE):
+                piece = view[a:a + _FILL_PIECE]
+                if f.readinto(piece) != len(piece):
+                    raise zipfile.BadZipFile(
+                        f"{plan.path}: member {k} ends early")
+                crc = zlib.crc32(piece, crc)
+            if crc != m.crc:
+                raise zipfile.BadZipFile(
+                    f"{plan.path}: bad CRC-32 for member {k}")
+    if decode:
+        with np.load(plan.path) as z:
+            for k in decode:
+                dest[k][...] = z[k]
+    return not decode
+
+
+def _fill_width(n_shards: int) -> int:
+    """Threads of a resident load: what the machine gives this process,
+    no more than the shards, and no more than saturate a host's memory."""
+    return max(1, min(n_shards, len(os.sched_getaffinity(0)), 8))
+
+
+def _close_holes(out: Dict[str, np.ndarray], cum: np.ndarray,
+                 holes: List[int]) -> Dict[str, np.ndarray]:
+    """The rare branch of a resident load: shards ``holes`` were
+    quarantined after the plane was sized, so every later shard's rows
+    move down over them and the plane ends where the good rows do."""
+    keep = sorted(set(range(len(cum) - 1)) - set(holes))
+    for a in out.values():
+        at = 0
+        for i in keep:
+            n = int(cum[i + 1] - cum[i])
+            if at != cum[i]:
+                a[at:at + n] = a[cum[i]:cum[i + 1]]
+            at += n
+    return {k: a[:at] for k, a in out.items()}
+
+
+class _Quarantine:
+    """The ``shifu.data.badThreshold`` rule of one pass over a shard set:
+    an undecodable shard is skipped, counted and logged as long as the
+    quarantined fraction stays under the threshold."""
+
+    ERRORS = (OSError, ValueError, zipfile.BadZipFile)
+
+    def __init__(self, n_shards: int, strict: bool = False):
+        from ..config import environment
+        self.threshold = 0.0 if strict else \
+            environment.get_float("shifu.data.badThreshold", 0.0)
+        self.n_shards = n_shards
+        self.count = 0
+
+    def skip(self, path: str, e: BaseException) -> None:
+        """Called while ``e`` is being handled: re-raises it unless the
+        rule lets the shard at ``path`` go."""
+        if self.threshold <= 0:
+            raise
+        from .. import obs
+        self.count += 1
+        # quarantine is the rare branch by definition —
+        # bounded by shifu.data.badThreshold
+        obs.counter("data.quarantined_shards").inc()  # shifu-lint: disable=telemetry-guard
+        log.warning("quarantined undecodable shard %s: %s", path, e)
+        if self.count / max(self.n_shards, 1) > self.threshold:
+            from ..config.errors import ErrorCode, ShifuError
+            raise ShifuError(
+                ErrorCode.ERROR_BAD_DATA_THRESHOLD,
+                f"{self.count}/{self.n_shards} shards "
+                f"quarantined exceeds shifu.data.badThreshold="
+                f"{self.threshold}; last: {path} ({e})") from e
 
 
 class _WireView:
@@ -135,17 +298,23 @@ class Shards:
         rd = self._wire_rd
         return _WireView(rd, self._wire_base) if self._wire_base else rd
 
-    def _iter_wire(self, start: int) -> Iterator[Dict[str, np.ndarray]]:
+    def _read_shard(self, i: int, read: Callable[[], T], what: str) -> T:
+        """The contract of every read of shard ``i``, whoever asks and on
+        whichever thread: the fault hook sees the shard once an attempt
+        and transient IO errors ride the retry ladder."""
         from .. import faults
         from ..ioutil import io_retry
-        rd = self.wire_reader()
-        keys = list(self.schema.get("wireKeys") or [])
-        for i in range(start, len(rd.shard_rows)):
-            def _load(i=i):
-                faults.fire("shards", "shard", i, path=self.directory)
-                s, e = int(rd.cum[i]), int(rd.cum[i + 1])
-                return {k: np.asarray(rd.memmap(k)[s:e]) for k in keys}
-            yield io_retry(_load, "wire shard read", self.directory)
+        path = self.directory if self.is_wire else self.files[i]
+
+        def attempt():
+            faults.fire("shards", "shard", i, path=path)
+            return read()
+        return io_retry(attempt, what, path)
+
+    @staticmethod
+    def _wire_rows(rd, i: int, keys) -> Dict[str, np.ndarray]:
+        s, e = int(rd.cum[i]), int(rd.cum[i + 1])
+        return {k: np.asarray(rd.memmap(k)[s:e]) for k in keys}
 
     def iter_shards(self, start: int = 0,
                     strict: bool = False) -> Iterator[Dict[str, np.ndarray]]:
@@ -157,58 +326,98 @@ class Shards:
         shard position and cannot tolerate a silently missing shard.
         Wire-mode planes serve the same per-shard dicts as mmap slices
         (consumers cannot tell which backing they got)."""
-        from .. import faults, obs
-        from ..config import environment
+        if self.is_wire:
+            rd = self.wire_reader()
+            keys = list(self.schema.get("wireKeys") or [])
+            for i in range(start, len(rd.shard_rows)):
+                yield self._read_shard(
+                    i, partial(self._wire_rows, rd, i, keys),
+                    "wire shard read")
+            return
+        bad = _Quarantine(len(self.files), strict)
+        for i, f in enumerate(self.files[start:], start=start):
+            try:
+                yield self._read_shard(i, lambda f=f: dict(np.load(f)),
+                                       "shard decode")
+            except _Quarantine.ERRORS as e:
+                bad.skip(f, e)
+
+    def _plan_all(self, bad: _Quarantine) -> List[Optional[_ShardPlan]]:
+        """Every shard's sizes, None where the shard was quarantined for
+        a directory or a header that cannot be read."""
         from ..ioutil import io_retry
         if self.is_wire:
-            yield from self._iter_wire(start)
-            return
-        bad_threshold = 0.0 if strict else \
-            environment.get_float("shifu.data.badThreshold", 0.0)
-        quarantined = 0
-        for i, f in enumerate(self.files[start:], start=start):
-            def _load(f=f, i=i):
-                faults.fire("shards", "shard", i, path=f)
-                return dict(np.load(f))
+            rd = self.wire_reader()
+            members = {k: _Member(rd.memmap(k).shape, rd.memmap(k).dtype)
+                       for k in self.schema.get("wireKeys") or []}
+            return [_ShardPlan(self.directory, int(r), members)
+                    for r in rd.shard_rows]
+        plans: List[Optional[_ShardPlan]] = []
+        for f in self.files:
             try:
-                yield io_retry(_load, "shard decode", f)
-            except (OSError, ValueError, zipfile.BadZipFile) as e:
-                if bad_threshold <= 0:
-                    raise
-                quarantined += 1
-                # quarantine is the rare branch by definition —
-                # bounded by shifu.data.badThreshold
-                obs.counter("data.quarantined_shards").inc()  # shifu-lint: disable=telemetry-guard
-                log.warning("quarantined undecodable shard %s: %s", f, e)
-                if quarantined / max(len(self.files), 1) > bad_threshold:
-                    from ..config.errors import ErrorCode, ShifuError
-                    raise ShifuError(
-                        ErrorCode.ERROR_BAD_DATA_THRESHOLD,
-                        f"{quarantined}/{len(self.files)} shards "
-                        f"quarantined exceeds shifu.data.badThreshold="
-                        f"{bad_threshold}; last: {f} ({e})") from e
+                plans.append(io_retry(partial(_plan_npz, f),
+                                      "shard open", f))
+            except _Quarantine.ERRORS as e:
+                bad.skip(f, e)
+                plans.append(None)
+        return plans
 
     def load_all(self) -> Dict[str, np.ndarray]:
+        """The whole set as one resident plane: each key's array is
+        allocated once at its final size and every shard is read straight
+        into its row slice, several shards at a time.  Quarantine as in
+        :meth:`iter_shards`; the bytes are in the returned arrays when
+        this returns."""
         from .. import obs
-        with obs.span("data.load"):
-            parts = []
-            shards = self.iter_shards()
-            for i in range(self.n_shards):
-                # a quarantined shard is skipped inside the iterator: the
-                # span then covers it and the next good one
-                with obs.span("data.shard_decode", shard=i) as sp:
-                    part = next(shards, None)
-                    if part is None:
-                        break
-                    sp.set(rows=len(next(iter(part.values()))),
-                           bytes=sum(a.nbytes for a in part.values()))
-                parts.append(part)
-            if not parts:
-                raise FileNotFoundError(f"no shards in {self.directory}")
-            with obs.span("data.concat") as sp:
-                out = {k: np.concatenate([p[k] for p in parts])
-                       for k in parts[0]}
+        with obs.span("data.load") as load_sp:
+            with obs.span("data.alloc"):
+                bad = _Quarantine(self.n_shards, strict=self.is_wire)
+                plans = self._plan_all(bad)
+                first = next((p for p in plans if p is not None), None)
+                if first is None:
+                    raise FileNotFoundError(f"no shards in {self.directory}")
+                for p in plans:
+                    # an error of the set, not of a shard: never quarantined
+                    if p is not None and p.layout != first.layout:
+                        raise ValueError(
+                            f"{p.path}: arrays {p.layout} disagree with "
+                            f"{first.path}: {first.layout}")
+                cum = np.cumsum([0] + [p.rows if p else 0 for p in plans])
+                out = {k: np.empty((int(cum[-1]),) + shape, dtype)
+                       for k, (dtype, shape) in first.layout.items()}
+            rd = self.wire_reader()
+
+            def fill(i: int) -> bool:
+                dest = {k: a[cum[i]:cum[i + 1]] for k, a in out.items()}
+                if rd is None:
+                    return _fill_npz(plans[i], dest)
+                for k, rows in self._wire_rows(rd, i, out).items():
+                    dest[k][...] = rows
+                return True
+
+            todo = [i for i, p in enumerate(plans) if p is not None]
+            threads = _fill_width(len(todo))
+            direct, holes = 0, []
+            with obs.span("data.read") as sp, \
+                    ThreadPoolExecutor(threads, "shard-fill") as pool:
                 sp.set(bytes=sum(a.nbytes for a in out.values()))
+                reads = [pool.submit(self._read_shard, i, partial(fill, i),
+                                     "shard decode") for i in todo]
+                try:
+                    for i, r in zip(todo, reads):
+                        try:
+                            direct += r.result()
+                        except _Quarantine.ERRORS as e:
+                            bad.skip(plans[i].path, e)
+                            holes.append(i)
+                except BaseException:
+                    pool.shutdown(cancel_futures=True)
+                    raise
+            if holes:
+                out = _close_holes(out, cum, holes)
+            load_sp.set(bytes=sum(a.nbytes for a in out.values()),
+                        shards=self.n_shards, direct=direct,
+                        threads=threads)
             return out
 
     def _sidecar_sig(self) -> List[List]:

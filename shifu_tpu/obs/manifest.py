@@ -320,9 +320,17 @@ SPANS: Dict[str, str] = {
                         "cursor)"),
     # ---- the in-RAM NN train job's path (attrs are counts the code
     # already holds; none costs a device sync)
-    "data.load": "Shards.load_all: every shard decoded, then joined",
-    "data.shard_decode": "one shard read and decoded (shard, rows, bytes)",
-    "data.concat": "the decoded shards joined into one plane (bytes)",
+    "data.load": ("Shards.load_all: one plane allocated at its final "
+                  "size, every shard read into its row slice (bytes of "
+                  "the returned plane; shards; direct = shards whose "
+                  "every member went file -> slice in one copy, the "
+                  "others were decoded by np.load or quarantined; "
+                  "threads of the fill)"),
+    "data.alloc": ("every shard's sizes from its zip directory and npy "
+                   "headers, then one np.empty a key"),
+    "data.read": ("the fill: shards read into their slices on the "
+                  "thread pool and checked against their CRC-32s, from "
+                  "the first submit to the last result (bytes)"),
     "train.split": ("member_masks and the weight products: the "
                     "train/validation row weights of every member (rows)"),
     "nn.init": "mesh, params and optimizer state, their device_put",

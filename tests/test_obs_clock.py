@@ -1,7 +1,7 @@
 """The program's spans on the profiler's clock: a live span is also a
 ``shifu:`` annotation in a ``jax.profiler`` session's ``.xplane.pb``, the
 off path builds nothing, and the in-RAM NN train job is spanned from the
-shard decode to the epoch's fetch."""
+shard load to the epoch's fetch."""
 
 import glob
 import json
@@ -18,7 +18,7 @@ from shifu_tpu.obs import manifest, tracer
 pytestmark = pytest.mark.obs
 
 TRAIN_JOB_SPANS = (
-    "data.load", "data.shard_decode", "data.concat", "train.split",
+    "data.load", "data.alloc", "data.read", "train.split",
     "nn.init", "nn.h2d", "nn.repad", "nn.epoch", "nn.epoch.dispatch",
     "nn.epoch.fetch", "nn.epoch.best_copy", "nn.epoch.progress",
     "nn.epoch.checkpoint", "xla.build")
@@ -182,19 +182,18 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
     paths = [path(s) for s in spans]
     under_train = " < train < process < TRAIN"
     shards = Shards.open(os.path.join(prepared_set, "tmp", "NormalizedData"))
-    decodes = [s for s in spans if s["name"] == "data.shard_decode"]
-    assert len(decodes) == shards.n_shards
-    assert all(path(s) == "data.shard_decode < data.load < load_data < "
-               "process < TRAIN" for s in decodes)
-    assert [s["attrs"]["shard"] for s in decodes] == \
-        list(range(shards.n_shards))
+    (load,) = [s for s in spans if s["name"] == "data.load"]
+    (alloc,) = [s for s in spans if s["name"] == "data.alloc"]
+    (read,) = [s for s in spans if s["name"] == "data.read"]
+    assert path(load) == "data.load < load_data < process < TRAIN"
+    assert alloc["parent"] == read["parent"] == load["id"]
+    assert alloc["ts"] <= read["ts"]
     plane = shards.load_all()
-    assert sum(s["attrs"]["rows"] for s in decodes) == len(plane["y"])
-    (concat,) = [s for s in spans if s["name"] == "data.concat"]
-    assert path(concat) == "data.concat < data.load < load_data < " \
-        "process < TRAIN"
-    assert sum(s["attrs"]["bytes"] for s in decodes) == \
-        concat["attrs"]["bytes"] == sum(a.nbytes for a in plane.values())
+    assert load["attrs"]["shards"] == load["attrs"]["direct"] == \
+        shards.n_shards
+    assert 1 <= load["attrs"]["threads"] <= shards.n_shards
+    assert load["attrs"]["bytes"] == read["attrs"]["bytes"] == \
+        sum(a.nbytes for a in plane.values())
 
     for name in ("train.split", "nn.init", "nn.h2d", "nn.repad"):
         assert paths.count(name + (" < process < TRAIN"
