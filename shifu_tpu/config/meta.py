@@ -27,10 +27,13 @@ TREE_FAMILY = ("GBT", "RF", "DT")
 class Rule:
     """One key's schema: accepted kinds + constraints.
 
-    kind: 'int' | 'float' | 'bool' | 'str' | 'list' | 'intlist' | 'strlist'
+    kind: 'int' | 'float' | 'bool' | 'str' | 'list' | 'intlist' | 'strlist' |
+    'dict' (a nested group, checked by its consumer)
     lo/hi: numeric range (inclusive unless *_open); allowed: value set
     (case-insensitive for strings); algs: algorithms the key applies to
-    (None = all).
+    (None = all); native: a ``TENSORFLOW`` key the native path reads itself
+    (the other keys of that slot describe a TF-on-YARN topology and are
+    refused, :func:`tf_ignored_param_problems`).
     """
     kind: str
     lo: Optional[float] = None
@@ -39,6 +42,7 @@ class Rule:
     hi_open: bool = False
     allowed: Optional[Tuple[str, ...]] = None
     algs: Optional[Tuple[str, ...]] = None
+    native: bool = False
 
 
 _OPTIMIZERS = ("B", "Q", "R", "M", "ADAM", "SGD", "MOMENTUM", "NESTEROV",
@@ -136,6 +140,11 @@ TRAIN_PARAM_RULES: Dict[str, Rule] = {
     "NumTFWorkers": Rule("int", lo=1, algs=("TENSORFLOW",)),
     "TFWorkerMemory": Rule("int", lo=1, algs=("TENSORFLOW",)),
     "TFPSMemory": Rule("int", lo=1, algs=("TENSORFLOW",)),
+    # the slot's own use: ``Tower`` names a deep tower the native path
+    # trains itself (train/tower_trainer.py); ``TowerParams`` holds the
+    # tower's published config.json keys, checked by models/tower_sdar.py
+    "Tower": Rule("str", allowed=("sdar_moe",), algs=("TENSORFLOW",), native=True),
+    "TowerParams": Rule("dict", algs=("TENSORFLOW",), native=True),
     # WDL family
     "EmbedColumnNum": Rule("int", lo=1, algs=("WDL",)),
     "EmbedDim": Rule("int", lo=1, algs=("WDL",)),
@@ -214,6 +223,9 @@ def _check_value(key: str, v: Any, rule: Rule) -> List[str]:
                 tuple(a.lower() for a in rule.allowed):
             problems.append(f"{key} must be one of {list(rule.allowed)}, "
                             f"got {v!r}")
+    elif rule.kind == "dict":
+        if not isinstance(v, dict):
+            problems.append(f"{key} must be an object, got {v!r}")
     elif rule.kind in ("intlist", "strlist"):
         if not isinstance(v, (list, tuple)):
             problems.append(f"{key} must be a list, got {v!r}")
@@ -235,7 +247,7 @@ def _check_value(key: str, v: Any, rule: Rule) -> List[str]:
 
 
 TF_ONLY_PARAMS = tuple(k for k, r in TRAIN_PARAM_RULES.items()
-                       if r.algs == ("TENSORFLOW",))
+                       if r.algs == ("TENSORFLOW",) and not r.native)
 
 
 def tf_ignored_param_problems(train_conf) -> List[str]:

@@ -34,6 +34,9 @@ def load_any(path: str):
     if kind == "wdl":
         from .wdl import IndependentWDLModel
         return IndependentWDLModel.load(path)
+    if kind == "tower":
+        from .tower_sdar import IndependentTowerModel
+        return IndependentTowerModel.load(path)
     if kind == "svm":
         from .svm import IndependentSVMModel
         return IndependentSVMModel.load(path)

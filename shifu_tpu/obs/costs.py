@@ -46,8 +46,9 @@ from __future__ import annotations
 import inspect
 import logging
 import os
+import re
 import threading
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from . import tracer
 
@@ -354,6 +355,25 @@ def record_executable(name: str, lowered, compiled,
                      signature, flops, bya, _memory_numbers(compiled))
 
 
+_HLO_OP = re.compile(r'^\s*(?:ROOT )?%([\w.\-]+) = .*metadata=\{op_name="([^"]*)"')
+
+
+def op_scopes(hlo_text: str, scopes: Sequence[str]) -> Dict[str, List[str]]:
+    """scope -> the HLO instructions whose ``op_name`` passes through it
+    (``jax.named_scope`` puts the scope's name there).  A device trace names
+    its ops by instruction and carries no ``op_name``: with this map a
+    reader sums an executable's device time by scope.  The first scope of
+    ``scopes`` that an ``op_name`` contains takes the instruction."""
+    out: Dict[str, List[str]] = {s: [] for s in scopes}
+    for line in hlo_text.splitlines():
+        m = _HLO_OP.match(line)
+        if m:
+            hit = next((s for s in scopes if s in m.group(2)), None)
+            if hit is not None:
+                out[hit].append(m.group(1))
+    return out
+
+
 # ------------------------------------------------------------ costed_jit
 class CostedJit:
     """A named, cost-attributed jitted callable (see module docs).
@@ -412,6 +432,16 @@ class CostedJit:
             log.debug("costed_jit %r AOT dispatch failed; using plain "
                       "jit for this call", self.name, exc_info=True)
             return self._jitted(*args, **kwargs)
+
+    def hlo_text(self) -> Optional[str]:
+        """The optimized HLO of the executable built last (None before the
+        first costed call): what :func:`op_scopes` reads."""
+        if not self._compiled:
+            return None
+        try:
+            return list(self._compiled.values())[-1].as_text()
+        except Exception:
+            return None
 
     # parity with jax.jit's AOT surface, so call sites can still lower
     def lower(self, *args, **kwargs):

@@ -72,6 +72,15 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
     "train.trees": ("counter", "trees built (GBT/RF/DT)"),
     "train.trees_built": ("gauge", "final forest size of the last trainer"),
     "train.valid_err": ("gauge", "last validation error"),
+    # ---- tower trainer: MoE routing and masking, fetched with the epoch's loss
+    "tower.moe_pairs_max_expert": ("counter", "per epoch, the most (token, choice) "
+                                   "pairs one held expert of one layer took"),
+    "tower.moe_pairs_mean_expert": ("counter", "per epoch, the mean pairs a held "
+                                    "expert took"),
+    "tower.dropped_pairs": ("counter", "pairs routed to a held expert that no "
+                            "grouped product covered (must stay 0)"),
+    "tower.masked_positions": ("counter", "masked non-PAD positions trained on"),
+    "tower.positions": ("counter", "non-PAD positions of the training microbatches"),
     "train.host_syncs": ("counter", "device->host value-forcing fetches"),
     "train.tail_sweeps": ("counter", "disk-tail re-streams paid"),
     "train.tail_repairs": ("counter", "c2f speculation repairs"),
@@ -347,6 +356,16 @@ SPANS: Dict[str, str] = {
     "nn.epoch.best_copy": "device->host copy of improved members' params",
     "nn.epoch.progress": "the progress callback (progress file line)",
     "nn.epoch.checkpoint": "tmp-model and trainer-state checkpoints",
+    # the tower trainer (train/tower_trainer.py): one TRAIN job
+    "tower.tokenize": "bins -> token ids and the train/validation split (rows, ids)",
+    "tower.init": ("parameters and optimizer state made on the device or "
+                   "restored, the id plane put up (params, bytes)"),
+    "tower.epoch": "one epoch of the tower trainer (epoch)",
+    "tower.epoch.dispatch": ("the epoch's order and its step and validation "
+                             "programs launched (builds them in epoch 0)"),
+    "tower.epoch.fetch": "the fetch of the epoch's loss and counters that waits",
+    "tower.epoch.checkpoint": "the trainer-state checkpoint (bytes)",
+    "tower.save": "device->host copy of the parameters and the model file (bytes)",
     "xla.build": ("jax traced / lowered / built (compiled or loaded from "
                   "the compile cache) one program (stage, program, secs); "
                   "recorded when it ends"),

@@ -104,6 +104,9 @@ def ensemble_bins_dtype(models: Sequence) -> np.dtype:
         if name == "IndependentTreeModel":
             if m.spec.n_bins > 256:
                 return np.dtype(np.int32)
+        elif getattr(m, "max_bin_id", None) is not None:
+            if m.max_bin_id > 255:           # a model that states its own id space
+                return np.dtype(np.int32)
         elif getattr(m, "input_kind", "norm") == "both":
             cards = getattr(m.spec, "cat_cardinalities", None) or []
             if cards and max(cards) > 256:

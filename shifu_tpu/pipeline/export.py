@@ -22,6 +22,9 @@ class ExportProcessor(BasicProcessor):
 
     def process(self) -> int:
         t = (self.params.get("type") or "pmml").lower()
+        if t not in ("columnstats", "woemapping", "woe", "corr"):
+            from ..models.tower_sdar import refuse
+            refuse(self.model_config, "export")      # the model exports have no tower form
         os.makedirs(self.paths.export_dir, exist_ok=True)
         if t in ("pmml", "baggingpmml"):
             # pmml already walks EVERY bagged member (model0..B) — the

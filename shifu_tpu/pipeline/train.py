@@ -149,6 +149,11 @@ class TrainProcessor(BasicProcessor):
             # TENSORFLOW: the reference bridges to TF-on-YARN
             # (TrainModelProcessor.java:395-449); tpu-native IS the bridge —
             # the same net trains as the jitted NN path
+            if alg == Algorithm.TENSORFLOW and (mc.train.params or {}).get("Tower"):
+                # the slot's own use: an arbitrary deep tower, trained over
+                # the binned plane by its own trainer
+                from ..train.tower_trainer import run_tower_training
+                return run_tower_training(self)
             if alg == Algorithm.TENSORFLOW:
                 # the probe step enforces this too; the direct-API path
                 # (callers constructing TrainProcessor without probe)

@@ -191,6 +191,9 @@ class VarSelectProcessor(BasicProcessor):
             return
         fb, alg = vs.filterBy, self.model_config.train.algorithm.name
         from ..config.validator import ValidationError
+        if fb in (FilterBy.SE, FilterBy.ST):
+            from ..models.tower_sdar import refuse
+            refuse(self.model_config, "varselect -wrapper")   # sensitivity re-scores an MLP
         if fb in (FilterBy.SE, FilterBy.ST) and \
                 alg not in ("NN", "LR", "SVM", "TENSORFLOW"):
             raise ValidationError(

@@ -1,0 +1,288 @@
+"""Tower trainer — ``algorithm: TENSORFLOW`` with ``train#params.Tower``.
+
+Trains a :mod:`shifu_tpu.models.tower_sdar` tower over the binned plane
+(``tmp/CleanedData``, the plane the tree trainers read): rows tokenised once,
+microbatches of ``MiniBatchs`` rows, one jitted step a microbatch (loss and
+gradients with each layer recomputed in the backward pass, then the
+``train/optimizers.py`` update rule over every parameter), the epoch's loss
+and MoE counters accumulated on the device and fetched once an epoch.  The
+epoch hooks are the NN trainer's: a progress line, trainer-state checkpoints
+every ``CheckpointInterval`` epochs (``train/checkpoint.py``) and resume from
+the latest, bit-exactly: what an epoch does is a function of (seed, epoch,
+step) and the restored state alone.
+
+What the seed decides, restated by ``benchmark/reference/sdar_moe.py``: the
+order of an epoch's training rows is ``permutation(fold_in(fold_in(key,
+epoch), 0))``; step ``i`` draws its noise from ``fold_in(fold_in(key, epoch),
+1 + i)``: per block t ~ U(1e-3, 1], each position masked with probability t.
+"""
+
+from __future__ import annotations
+
+import logging
+import os
+import time
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from .. import faults, obs
+from ..models import tower_sdar as tower
+from ..obs.costs import op_scopes
+from . import checkpoint as ckpt
+from .optimizers import make_optimizer, resolve_precision
+
+log = logging.getLogger(__name__)
+
+DEFAULT_MICROBATCH = 16
+# the step's named scopes, most specific first: device ops carry them
+SCOPES = ("tower/attn", "tower/moe/route", "tower/moe/experts", "tower/head", "tower/opt")
+VALID_FOLD = 0x7FFFFFFF             # the validation noise's fold of the seed's key
+
+
+@dataclass
+class TowerResult:
+    params: Any
+    train_error: float
+    valid_error: float
+    epochs_run: int
+    history: List[Tuple[float, float]]
+
+
+def split_rows(n: int, valid_rate: float, seed: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(train rows, validation rows), both ascending: the first
+    ``round(n * valid_rate)`` of a seeded permutation validate."""
+    perm = np.random.default_rng(seed).permutation(n)
+    n_valid = int(round(n * valid_rate))
+    return np.sort(perm[n_valid:]), np.sort(perm[:n_valid])
+
+
+def noise(key, rows: int, spec: tower.TowerSpec):
+    """(t [rows, S], masked [rows, S]): per block t ~ U(T_MIN, 1], each
+    position of the block masked with probability t."""
+    kt, km = jax.random.split(key)
+    b = spec.block_length
+    t = jnp.repeat(jax.random.uniform(kt, (rows, spec.seq_len // b), jnp.float32,
+                                      tower.T_MIN, 1.0), b, axis=1)
+    return t, jax.random.uniform(km, (rows, spec.seq_len), jnp.float32) < t
+
+
+def _microbatches(rows: np.ndarray, mb: int) -> np.ndarray:
+    """[steps, mb] row indices, the last step padded with -1."""
+    steps = -(-len(rows) // mb)
+    out = np.full(steps * mb, -1, np.int32)
+    out[:len(rows)] = rows
+    return out.reshape(steps, mb)
+
+
+def _zero_acc(spec: tower.TowerSpec) -> Dict[str, jnp.ndarray]:
+    f32 = lambda *shape: jnp.zeros(shape, jnp.float32)
+    return {"loss_sum": f32(), "positions": f32(), "masked": f32(),
+            "valid_loss_sum": f32(), "valid_positions": f32(),
+            "pairs": f32(spec.num_hidden_layers, spec.experts_held), "dropped": f32()}
+
+
+def build_programs(spec: tower.TowerSpec, opt, mb: int):
+    """(step, valid_step): the two programs an epoch launches.  State and
+    accumulators are donated: 16 bytes a parameter, updated in place.  A
+    step takes its microbatch's row indices (-1 = padding), so the programs
+    depend on the plane's rows and the microbatch, not on how many steps an
+    epoch has."""
+
+    def gather(ids, w, rows):
+        keep = rows >= 0
+        rows = jnp.maximum(rows, 0)
+        return ids[rows], jnp.where(keep, w[rows], 0.0)
+
+    @partial(obs.costed_jit, "tower.step", donate_argnums=(0, 1, 2))
+    def tower_step(params, opt_state, acc, ids, w, rows, key, specials, epoch, i):
+        x0, row_w = gather(ids, w, rows)
+        t, masked = noise(jax.random.fold_in(jax.random.fold_in(key, epoch), 1 + i), mb, spec)
+        (_, aux), grads = jax.value_and_grad(tower.diffusion_loss, has_aux=True)(
+            params, spec, x0, t, masked, row_w, specials[2], specials[3])
+        with jax.named_scope("tower/opt"):
+            delta, opt_state = opt.update(grads, opt_state, params)
+            params = jax.tree_util.tree_map(jnp.add, params, delta)
+        acc = {**acc, "loss_sum": acc["loss_sum"] + aux["loss_sum"],
+               "positions": acc["positions"] + aux["positions"],
+               "masked": acc["masked"] + aux["masked"],
+               "pairs": acc["pairs"] + aux["pairs"],
+               "dropped": acc["dropped"] + jnp.sum(aux["dropped"])}
+        return params, opt_state, acc
+
+    @partial(obs.costed_jit, "tower.valid_step", donate_argnums=(1,))
+    def tower_valid_step(params, acc, ids, w, rows, key, specials, i):
+        x0, row_w = gather(ids, w, rows)
+        t, masked = noise(jax.random.fold_in(jax.random.fold_in(key, VALID_FOLD), 1 + i), mb, spec)
+        _, aux = tower.diffusion_loss(params, spec, x0, t, masked, row_w, specials[2], specials[3])
+        return {**acc, "valid_loss_sum": acc["valid_loss_sum"] + aux["loss_sum"],
+                "valid_positions": acc["valid_positions"] + aux["positions"]}
+
+    return tower_step, tower_valid_step
+
+
+def _nbytes(tree) -> int:
+    return int(sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree)))
+
+
+def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec: tower.TowerSpec,
+                settings, valid_rate: float,
+                progress: Optional[Callable[[int, float, float], None]] = None) -> TowerResult:
+    precision = resolve_precision(settings.precision)
+    if precision != "f32":
+        raise ValueError(f"a tower trains under shifu.train.precision=f32; got {precision!r}")
+    with obs.span("tower.tokenize", rows=len(y), ids=spec.n_ids):
+        ids = tower.tokenize(spec, bins, y)
+        train_rows, valid_rows = split_rows(len(y), valid_rate, settings.seed)
+    mb = min(settings.batch_size or DEFAULT_MICROBATCH, max(len(train_rows), 1))
+    valid_order = _microbatches(valid_rows, mb)
+    steps = -(-len(train_rows) // mb)
+
+    with obs.span("tower.init") as sp:
+        key = jax.random.PRNGKey(settings.seed)
+        opt = make_optimizer(settings.optimizer, settings.learning_rate, **settings.opt_kwargs)
+        init = obs.costed_jit(
+            "tower.init", lambda k: (lambda p: (p, opt.init(p)))(tower.init_params(k, spec)))
+        start_epoch, state = 0, None
+        if settings.resume and settings.checkpoint_dir:
+            # shapes only: the restored state never shares the chip with a fresh one
+            template = dict(zip(("params", "opt_state"), jax.eval_shape(init, key)))
+            restored = ckpt.restore_state(settings.checkpoint_dir, template,
+                                          expect_precision=precision)
+            if restored is not None:
+                start_epoch, state = restored
+                log.info("tower: resumed from the checkpoint of epoch %d", start_epoch)
+        params, opt_state = init(key) if state is None else \
+            jax.device_put((state["params"], state["opt_state"]))
+        del state
+        ids_d, w_d = jax.device_put((ids, np.asarray(w, np.float32)))
+        # the special ids follow the columns' bins: an argument, so that
+        # another table's job finds these programs in the compile cache
+        specials = jnp.asarray([spec.special(n) for n in tower.SPECIALS], jnp.int32)
+        tower_step, tower_valid_step = build_programs(spec, opt, mb)
+        n_par = tower.n_params(params)
+        sp.set(params=n_par, bytes=_nbytes(params) + _nbytes(opt_state))
+    log.info("tower %s: %d rows x %d positions (%d train, %d validation), %d parameters, "
+             "microbatches of %d rows, %d steps an epoch", spec.tower, len(y), spec.seq_len,
+             len(train_rows), len(valid_rows), n_par, mb, steps)
+
+    epochs_target = settings.epochs
+    if settings.resume_extra > 0:
+        epochs_target = start_epoch + settings.resume_extra
+    tr = va = float("nan")
+    history: List[Tuple[float, float]] = []
+    for epoch in range(start_epoch, epochs_target):
+        with obs.span("tower.epoch", epoch=epoch):
+            ep_t0 = time.perf_counter()
+            with obs.span("tower.epoch.dispatch"):
+                order_key = jax.random.fold_in(jax.random.fold_in(key, epoch), 0)
+                order = _microbatches(
+                    train_rows[np.asarray(jax.random.permutation(order_key, len(train_rows)))],
+                    mb)
+                acc = _zero_acc(spec)
+                ep = jnp.int32(epoch)
+                for i in range(steps):
+                    params, opt_state, acc = tower_step(params, opt_state, acc, ids_d, w_d,
+                                                        order[i], key, specials, ep, jnp.int32(i))
+                for i in range(len(valid_order)):
+                    acc = tower_valid_step(params, acc, ids_d, w_d, valid_order[i], key,
+                                           specials, jnp.int32(i))
+            with obs.span("tower.epoch.fetch"):
+                got = jax.device_get(acc)              # the epoch's one fetch
+            if epoch == start_epoch and obs.enabled():
+                # which of the step's device ops belong to which scope: a
+                # device trace names ops by HLO instruction only
+                hlo = tower_step.hlo_text()
+                if hlo:
+                    obs.event("op_scopes", program="tower_step",
+                              scopes=op_scopes(hlo, SCOPES))
+            tr = float(got["loss_sum"] / max(got["positions"], 1.0))
+            va = float(got["valid_loss_sum"] / got["valid_positions"]) \
+                if got["valid_positions"] > 0 else 0.0
+            history.append((tr, va))
+            if obs.enabled():
+                dt = time.perf_counter() - ep_t0
+                pairs = got["pairs"]
+                obs.counter("train.epochs").inc()
+                obs.histogram("train.epoch_s").observe(dt)
+                obs.gauge("train.valid_err").set(va)
+                obs.counter("tower.moe_pairs_max_expert").inc(float(pairs.max()))
+                obs.counter("tower.moe_pairs_mean_expert").inc(float(pairs.mean()))
+                obs.counter("tower.dropped_pairs").inc(float(got["dropped"]))
+                obs.counter("tower.masked_positions").inc(float(got["masked"]))
+                obs.counter("tower.positions").inc(float(got["positions"]))
+                obs.event("epoch", trainer="tower", epoch=epoch, train_err=round(tr, 6),
+                          valid_err=round(va, 6), rows=len(train_rows),
+                          rows_per_sec=round(len(train_rows) / max(dt, 1e-9), 1))
+            if got["dropped"] != 0:
+                raise RuntimeError(f"tower: {int(got['dropped'])} routed pairs were dropped "
+                                   f"in epoch {epoch + 1}")
+            if progress:
+                progress(epoch, tr, va)
+            if settings.checkpoint_dir and settings.checkpoint_every and \
+                    (epoch + 1) % settings.checkpoint_every == 0:
+                with obs.span("tower.epoch.checkpoint") as sp:
+                    path = ckpt.save_state(
+                        settings.checkpoint_dir, epoch + 1,
+                        {"params": params, "opt_state": opt_state},
+                        keep=1, precision=precision)
+                    sp.set(bytes=os.path.getsize(path))
+    return TowerResult(params=params, train_error=tr, valid_error=va,
+                       epochs_run=start_epoch + len(history), history=history)
+
+
+def run_tower_training(proc) -> int:
+    """Entry called by TrainProcessor for ``TENSORFLOW`` with ``Tower``."""
+    from ..config.errors import ErrorCode, ShifuError
+    from ..pipeline.train import settings_from_params
+    mc = proc.model_config
+    p = dict(mc.train.params or {})
+    if mc.train.baggingNum > 1 or mc.train.isCrossValidation or mc.is_multi_class():
+        raise ShifuError(ErrorCode.ERROR_MODELCONFIG_NOT_VALIDATION,
+                         "a tower trains one binary model: baggingNum 1, no k-fold, "
+                         "no multi-class")
+    shards = proc._open_shards(proc.paths.clean_dir)
+    with proc.phase("load_data"):
+        data = shards.load_all()
+    col_nums = list(shards.schema.get("columnNums", []))
+    by_num = {c.columnNum: c for c in proc.column_configs}
+    spec = tower.spec_from_params(
+        p.get("TowerParams"), col_nums, [by_num[cn].num_bins() for cn in col_nums],
+        [by_num[cn].columnName for cn in col_nums])
+    settings = settings_from_params(p, mc.train, defaults={"Propagation": "ADAM",
+                                                           "LearningRate": 1e-4})
+    settings.checkpoint_dir = proc.paths.checkpoint_dir
+    settings.resume = bool(proc.params.get("resume"))
+    settings.resume_extra = int(proc.params.get("refresh_extra") or 0)
+
+    if not settings.resume and os.path.isdir(settings.checkpoint_dir):
+        # a fresh job's torn successor must never resume an older job's state
+        for f in os.listdir(settings.checkpoint_dir):
+            os.remove(os.path.join(settings.checkpoint_dir, f))
+    with open(proc.paths.progress_path, "w") as pf:  # shifu-lint: disable=atomic-write
+        def progress(epoch, tr, va):
+            line = (f"Tower Epoch #{epoch + 1} Train Error: {tr:.6f} "
+                    f"Validation Error: {va:.6f}")
+            pf.write(line + "\n")
+            pf.flush()
+            faults.fire("train", "epoch", epoch + 1)
+            log.info(line)
+        with proc.phase("train"):
+            res = train_tower(data["bins"], data["y"], data["w"], spec, settings,
+                              mc.train.validSetRate, progress)
+
+    with proc.phase("save_models"), obs.span("tower.save") as sp:
+        os.makedirs(proc.paths.models_dir, exist_ok=True)
+        for f in os.listdir(proc.paths.models_dir):
+            if f.startswith("model"):
+                os.remove(os.path.join(proc.paths.models_dir, f))
+        path = proc.paths.model_path(0, "tower")
+        sp.set(bytes=tower.save_model(path, spec, jax.device_get(res.params)))
+    log.info("train tower done: %s, train error %.6f, validation error %.6f (%d epochs)",
+             path, res.train_error, res.valid_error, res.epochs_run)
+    return 0
