@@ -343,12 +343,13 @@ SPANS: Dict[str, str] = {
     "train.split": ("member_masks and the weight products: the "
                     "train/validation row weights of every member (rows)"),
     "nn.init": "mesh, params and optimizer state, their device_put",
-    "nn.h2d": ("row padding to the mesh and the first device_put of "
-               "x/y/weights (bytes); ends at dispatch, the copy is "
-               "waited for by whoever next needs it"),
-    "nn.repad": ("MiniBatchs: the plane gathered back to the host "
-                 "(bytes_down), padded to a batch multiple and put on "
-                 "the device again (bytes)"),
+    "nn.h2d": ("the plane's only upload: rows zero-padded on the host to "
+               "their final multiple (the minibatch when MiniBatchs is "
+               "set, else the mesh's data extent), then one device_put "
+               "each of x/y/weights (bytes of the padded plane; pad_rows "
+               "appended: 0 sends x as the loader's buffer, uncopied); "
+               "ends at dispatch, the copy is waited for by whoever next "
+               "needs it.  Nothing of the plane comes back to the host"),
     "nn.epoch": "one epoch of the in-RAM NN trainer (epoch)",
     "nn.epoch.dispatch": ("rng split and the step / epoch_steps and "
                           "eval_errors calls (builds them in epoch 0)"),

@@ -322,25 +322,27 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
         stacked = jax.device_put(stacked, sh_ens)
         opt_state = jax.device_put(opt_state, sh_ens)
 
-    def put_plane(x, y, train_w, valid_w, y_members, multiple):
-        """Pad the rows to ``multiple`` and put the plane on the mesh."""
-        x, y, train_w, valid_w, *ym = _pad_all(
-            x, y, train_w, valid_w, multiple, y_members)
-        y_members = ym[0] if ym else None
-        sh_members = NamedSharding(mesh, P("ensemble", "data"))
-        return (jax.device_put(x, NamedSharding(mesh, P("data", None))),
-                jax.device_put(y, NamedSharding(mesh, P("data"))),
-                jax.device_put(train_w, sh_members),
-                jax.device_put(valid_w, sh_members),
-                None if y_members is None
-                else jax.device_put(y_members, sh_members))
-
+    # the final row multiple is known before the upload: the minibatch
+    # (itself a multiple of the data extent) when MiniBatchs is set, else
+    # the data extent.  One pad on the host, one device_put; nothing of
+    # the plane comes back.
+    bs = settings.batch_size
+    if bs:
+        bs = max(bs - bs % data_size, data_size)
     with obs.span("nn.h2d") as sp:
-        # per-member targets (one-vs-all) fold through the same row
-        # padding as the weights
-        plane = put_plane(x, y, train_w, valid_w, y_members, data_size)
-        sp.set(bytes=_nbytes(plane))
-    xd, yd, twd, vwd, ymd = plane
+        # padded rows carry zero weight, so the tail is never dropped;
+        # per-member targets (one-vs-all) fold through the same padding
+        # (_pad_all returns them only when given: zip stops there)
+        sh_members = NamedSharding(mesh, P("ensemble", "data"))
+        plane = [jax.device_put(a, sh) for a, sh in zip(
+            _pad_all(x, y, train_w, valid_w, bs or data_size, y_members),
+            (NamedSharding(mesh, P("data", None)),
+             NamedSharding(mesh, P("data")),
+             sh_members, sh_members, sh_members))]
+        # shapes only: neither attr costs a sync
+        sp.set(bytes=sum(a.nbytes for a in plane),
+               pad_rows=plane[0].shape[0] - n)
+    xd, yd, twd, vwd, ymd = (*plane, None)[:5]
 
     # per-member hyper rows [B, 4]: lr_scale, l2, l1, dropout — uniform from
     # settings unless stacked grid trials supplied their own
@@ -421,18 +423,6 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
             return (per_row * mw).sum() / jnp.maximum(mw.sum(), 1e-9)
         ev = jax.vmap(one, in_axes=(0, 0, y_axis))
         return ev(stacked, tw, ys), ev(stacked, vw, ys)
-
-    bs = settings.batch_size
-    if bs:
-        bs = max(bs - bs % data_size, data_size)
-        # pad rows to a batch multiple so the tail is never dropped;
-        # padded rows carry zero weight (_gather_np: a plain np.asarray
-        # cannot read cross-host-sharded arrays under multiple controllers)
-        with obs.span("nn.repad", bytes_down=_nbytes(plane)) as sp:
-            plane = put_plane(*(None if a is None else _gather_np(a)
-                                for a in plane), bs)
-            sp.set(bytes=_nbytes(plane))
-        xd, yd, twd, vwd, ymd = plane
 
     stops = [WindowEarlyStop(settings.early_stop_window) for _ in range(bags)]
     best_valid = np.full(bags, np.inf)
@@ -593,11 +583,6 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
     return EnsembleResult(params=best_params, train_errors=best_train,
                           valid_errors=best_valid, epochs_run=epochs_run,
                           history=history)
-
-
-def _nbytes(arrays) -> int:
-    """Bytes of the arrays that are there (shapes only: no sync)."""
-    return sum(a.nbytes for a in arrays if a is not None)
 
 
 def _pad_all(x, y, train_w, valid_w, multiple, y_members=None):

@@ -85,8 +85,8 @@ checksum = float(sum(np.abs(layer[k]).sum()
 print(f"proc {pid}: MULTIHOST-TRAIN weights={checksum:.8f} "
       f"err={res.train_errors[0]:.6f}", flush=True)
 
-# minibatch path too: its re-pad block must gather (not np.asarray) the
-# cross-host-sharded arrays
+# minibatch path too: each controller pads its host copy to the batch
+# multiple before the plane's one device_put (no gather of the plane)
 res_mb = train_ensemble(x_global, y_all, tw, vw,
                         NNModelSpec(input_dim=D, hidden_nodes=[8],
                                     activations=["tanh"], loss="log"),

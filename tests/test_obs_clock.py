@@ -19,9 +19,12 @@ pytestmark = pytest.mark.obs
 
 TRAIN_JOB_SPANS = (
     "data.load", "data.alloc", "data.read", "train.split",
-    "nn.init", "nn.h2d", "nn.repad", "nn.epoch", "nn.epoch.dispatch",
+    "nn.init", "nn.h2d", "nn.epoch", "nn.epoch.dispatch",
     "nn.epoch.fetch", "nn.epoch.best_copy", "nn.epoch.progress",
     "nn.epoch.checkpoint", "xla.build")
+# went with the code they timed: the per-shard decode and the concatenate
+# (PR 26), the plane's trip down and second upload (PR 28)
+RETIRED_SPANS = ("data.shard_decode", "data.concat", "nn.repad")
 
 
 @pytest.fixture
@@ -165,14 +168,14 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
     from shifu_tpu.config import ModelConfig
     from shifu_tpu.data.shards import Shards
 
-    epochs = 3
+    epochs, batch = 3, 512
     mc_path = os.path.join(prepared_set, "ModelConfig.json")
     mc = ModelConfig.load(mc_path)
     mc.train.algorithm = "NN"
     mc.train.numTrainEpochs = epochs
     mc.train.params = {"NumHiddenNodes": [8], "ActivationFunc": ["relu"],
                        "Propagation": "ADAM", "LearningRate": 0.01,
-                       "MiniBatchs": 512}
+                       "MiniBatchs": batch}
     mc.save(mc_path)
     obs.set_enabled(None)               # the flag is what turns it on
     assert main(["-Dshifu.train.streaming=off", "--dir", prepared_set,
@@ -195,14 +198,20 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
     assert load["attrs"]["bytes"] == read["attrs"]["bytes"] == \
         sum(a.nbytes for a in plane.values())
 
-    for name in ("train.split", "nn.init", "nn.h2d", "nn.repad"):
+    for name in ("train.split", "nn.init", "nn.h2d"):
         assert paths.count(name + (" < process < TRAIN"
                                    if name == "train.split"
                                    else under_train)) == 1, name
+    assert not {s["name"] for s in spans} & set(RETIRED_SPANS)
+    # the plane goes up once, padded to the minibatch multiple on the host
     (h2d,) = [s for s in spans if s["name"] == "nn.h2d"]
-    (repad,) = [s for s in spans if s["name"] == "nn.repad"]
-    assert repad["attrs"]["bytes_down"] == h2d["attrs"]["bytes"] > 0
-    assert repad["attrs"]["bytes"] >= repad["attrs"]["bytes_down"]
+    rows = len(plane["y"])
+    pad = -rows % batch
+    assert pad > 0                      # this set's row count is ragged
+    assert h2d["attrs"]["pad_rows"] == pad
+    # x and y, and one member's train and validation weights, all f32
+    assert h2d["attrs"]["bytes"] == \
+        4 * (rows + pad) * (plane["x"].shape[1] + 3)
 
     ep = [s for s in spans if s["name"] == "nn.epoch"]
     assert [s["attrs"]["epoch"] for s in ep] == list(range(epochs))
@@ -226,3 +235,8 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
 def test_new_span_is_declared(name):
     assert manifest.is_declared_span(name), name
     assert name in manifest.SPANS and manifest.SPANS[name].strip()
+
+
+@pytest.mark.parametrize("name", RETIRED_SPANS)
+def test_retired_span_is_not_declared(name):
+    assert not manifest.is_declared_span(name), name
