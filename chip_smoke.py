@@ -343,7 +343,7 @@ def phase_pipeline(truth: dict, n_dev: int) -> dict:
     assert tail["tail_sweeps"] > 0, "the run stayed resident: no disk tail"
     assert tail["pallas_hist_launches"] > 0
 
-    # (c) NN at bench_nn's widths; empty -D values clear (b)'s overrides
+    # (c) NN; empty -D values clear (b)'s overrides
     set_train(mdir, "NN", {"NumHiddenLayers": 2,
                            "NumHiddenNodes": NN_HIDDEN,
                            "ActivationFunc": ["relu", "relu"],

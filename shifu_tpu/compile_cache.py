@@ -1,6 +1,6 @@
 """Where XLA's persistent compilation cache lives.
 
-One rule, applied by every entry point (``cli._dispatch``, ``bench.py``,
+One rule, applied by every entry point (``cli._dispatch``,
 ``chip_smoke.py``, the elastic controllers) before anything compiles:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set from outside: nothing is touched —

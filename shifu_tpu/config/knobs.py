@@ -283,31 +283,6 @@ _DECLS: Tuple[Knob, ...] = (
        "home dir holding conf/shifuconfig global properties"),
     _k("SHIFU_HOME", "env", "str", "",
        "fallback for SHIFU_TPU_HOME (reference launcher compat)"),
-    # ---- bench harness
-    _k("SHIFU_BENCH_TAIL_FLOOR", "env", "float", "",
-       "bench --plane tail throughput floor (rows*trees/s)"),
-    _k("SHIFU_BENCH_SERVE_FLOOR", "env", "float", "",
-       "bench --plane serve sustained-QPS floor"),
-    _k("SHIFU_BENCH_SERVE_P99_SLOP_MS", "env", "float", "",
-       "bench serve p99-vs-deadline slop allowance"),
-    _k("SHIFU_BENCH_E2E_ROWS", "env", "int", "",
-       "bench --plane e2e generated row count"),
-    _k("SHIFU_BENCH_INGEST_ROWS", "env", "int", "2000000",
-       "bench --plane ingest generated row count (serial vs pooled legs)"),
-    _k("SHIFU_BENCH_REFRESH_ROWS", "env", "int", "200000",
-       "bench --plane refresh base row count (drift stream adds 1/4)"),
-    _k("SHIFU_BENCH_WDL_TABLE_ROWS", "env", "int", "",
-       "bench wdl_shard: per-table cardinality for the oversized-table "
-       "scenario (default fits the replicated baseline)"),
-    _k("SHIFU_BENCH_SERVE_RAW_FLOOR", "env", "float", "0.8",
-       "bench serve: raw-record QPS floor as a fraction of the "
-       "pre-binned rate (the fused transform must stay nearly free)"),
-    _k("SHIFU_BENCH_FLEET_SCALING", "env", "float", "0.8",
-       "bench --plane fleet: 2-replica aggregate-QPS scaling floor "
-       "(qps_2r / (2 * qps_1r))"),
-    _k("SHIFU_BENCH_OVERLOAD_FLOOR", "env", "float", "0.8",
-       "bench --plane overload: goodput floor at 2x offered load as a "
-       "fraction of the measured saturation QPS"),
 )
 
 KNOBS: Dict[str, Knob] = {k.name: k for k in _DECLS}

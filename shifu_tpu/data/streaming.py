@@ -214,8 +214,8 @@ class ShardStream:
         self._spill_off = False         # sticky: aborted marker / IO error
         self._spill_rd = None           # validated SpillReader
         self.bytes_read = 0             # host-side total across sweeps
-                                        # (always on — bench/guard tests
-                                        # read it without telemetry)
+                                        # (always on — guard tests read
+                                        # it without telemetry)
 
     # ------------------------------------------------------ spill plumbing
     def _spill_dir(self) -> str:

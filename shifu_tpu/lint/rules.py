@@ -502,9 +502,7 @@ class TelemetryGuardRule(Rule):
            "loops — hoist the instrument handle before the loop, or "
            "guard the block with obs.enabled() / a hoisted obs_on "
            "bool; the name lookup takes the registry lock per "
-           "iteration even when telemetry is off (bench.py is exempt: "
-           "its publishing loops run once per measured plane with "
-           "telemetry force-enabled)")
+           "iteration even when telemetry is off")
     interests = (ast.Call,)
 
     _FACTORIES = ("counter", "gauge", "histogram")
@@ -512,8 +510,6 @@ class TelemetryGuardRule(Rule):
     _GUARDS = ("enabled(", "obs_on", "telemetry_on")
 
     def visit(self, node: ast.Call, parents, ctx) -> None:
-        if ctx.rel_path.endswith("bench.py"):
-            return
         func = node.func
         if not (isinstance(func, ast.Attribute)
                 and func.attr in self._FACTORIES
@@ -615,21 +611,18 @@ class SpanManifestRule(Rule):
             return
         if not node.args:
             return
-        manifest = _obs_manifest()
         arg = node.args[0]
         if isinstance(arg, ast.JoinedStr):
             head = fstring_head(arg) or ""
-            if not any(head.startswith(p)
-                       for p in manifest.SPAN_PREFIXES):
-                self.report(ctx, node,
-                            f"f-string span name {head + '...'!r} has no "
-                            "declared prefix in "
-                            "obs.manifest.SPAN_PREFIXES")
+            self.report(ctx, node,
+                        f"f-string span name {head + '...'!r}: span "
+                        "names are literals declared in "
+                        "obs.manifest.SPANS")
             return
         name = str_const(arg)
         if name is None:
             return                      # step-root spans named by variable
-        if not manifest.is_declared_span(name):
+        if not _obs_manifest().is_declared_span(name):
             self.report(ctx, node,
                         f"span {name!r} not declared in "
                         "obs.manifest.SPANS")

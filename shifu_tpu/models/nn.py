@@ -193,30 +193,6 @@ def weighted_loss(params, spec: NNModelSpec, x, y, w, *,
     return loss
 
 
-# --------------------------------------------------------------- training
-def make_train_step(spec: NNModelSpec, params, optimizer: str = "adam",
-                    learning_rate: float = 0.1, l2: float = 0.0, l1: float = 0.0,
-                    dropout_rate: float = 0.0, **opt_kwargs):
-    """Single-model jitted train step: ``(params, opt_state, x, y, w[, rng])
-    -> (params, opt_state, loss)``.  Gradient aggregation across a sharded
-    batch is XLA's psum — the NNMaster accumulate step
-    (``NNMaster.java:240-249``) with no master."""
-    from ..train.optimizers import make_optimizer
-
-    opt = make_optimizer(optimizer, learning_rate, **opt_kwargs)
-    opt_state = opt.init(params)
-
-    def step(params, opt_state, x, y, w, rng=None):
-        loss, grads = jax.value_and_grad(weighted_loss)(
-            params, spec, x, y, w, l2=l2, l1=l1,
-            dropout_rate=dropout_rate, rng=rng)
-        delta, opt_state = opt.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, d: p + d, params, delta)
-        return params, opt_state, loss
-
-    return jax.jit(step, donate_argnums=(0, 1)), opt_state
-
-
 # ------------------------------------------------------------- save/load
 def save_model(path: str, spec: NNModelSpec, params) -> None:
     """Self-contained .nn file: npz of weight arrays + the spec json.

@@ -71,7 +71,7 @@ from .tracer import (SCHEMA_VERSION, enabled, set_enabled,    # noqa: F401
                      span, event, flush, record_span, pending_records,
                      live_spans, reset_for_tests)
 from .manifest import (MANIFEST, PREFIXES, SPANS,             # noqa: F401
-                       SPAN_PREFIXES, is_declared, is_declared_span)
+                       is_declared, is_declared_span)
 from .slo import (SLOTracker, LogBins, LOG_BINS,              # noqa: F401
                   quantile_from_counts, slo_objectives,
                   BrownoutGovernor)
@@ -103,8 +103,7 @@ __all__ = [
     "counter", "gauge", "histogram", "sample_device_memory",
     "ensure_compile_listener", "snapshot", "get_registry",
     # manifest
-    "MANIFEST", "PREFIXES", "SPANS", "SPAN_PREFIXES", "is_declared",
-    "is_declared_span",
+    "MANIFEST", "PREFIXES", "SPANS", "is_declared", "is_declared_span",
     # SLO plane
     "SLOTracker", "LogBins", "LOG_BINS", "quantile_from_counts",
     "slo_objectives", "BrownoutGovernor",

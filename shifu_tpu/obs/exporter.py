@@ -2,8 +2,8 @@
 
 One export format for every consumer: an external scraper (Prometheus
 file-sd / node-exporter textfile collector) reads
-``<modelset>/telemetry/metrics.prom``, anything programmatic (our bench,
-the monitor, dashboards) reads the sibling ``metrics.json``; both are
+``<modelset>/telemetry/metrics.prom``, anything programmatic (the
+monitor, dashboards) reads the sibling ``metrics.json``; both are
 rendered from the SAME registry snapshot so they can never disagree.
 
 Naming is schema-versioned: every metric name is prefixed
@@ -11,8 +11,7 @@ Naming is schema-versioned: every metric name is prefixed
 underscores: ``ingest.bytes_read`` -> ``shifu_tpu_ingest_bytes_read``),
 counters get the conventional ``_total`` suffix, and every exposition
 carries ``shifu_tpu_telemetry_schema_version`` so a scraper can detect a
-layout change instead of silently mis-joining series (the same contract
-as the bench/obs schema handshake).
+layout change instead of silently mis-joining series.
 
 Histograms export as summaries: ``_count`` + ``_sum`` (counters),
 ``{quantile="0.5"}`` / ``{quantile="0.99"}`` sample lines (the registry

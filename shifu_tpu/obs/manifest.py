@@ -6,17 +6,18 @@ metric declared here (or start with a declared dynamic-family prefix).
 A lint-style test (``tests/test_obs_plane.py``) greps the source tree
 and enforces it, because the registry's create-on-first-use convenience
 has a failure mode that is otherwise silent: a typo'd name at one call
-site quietly creates a NEW metric, the dashboards / bench joins keep
+site quietly creates a NEW metric, the dashboards / report joins keep
 reading the old (now frozen) one, and nothing errors anywhere.
 
 Declaring a metric: ``MANIFEST[name] = (type, help)``.  Families whose
-member names are data-dependent (per-eval-set AUC, bench extras) declare
+member names are data-dependent (per-eval-set AUC) declare
 a prefix in ``PREFIXES`` instead — f-string call sites must start with
 one of them.
 
-SPAN names get the same treatment (``SPANS`` / ``SPAN_PREFIXES``): the
-timeline/report joins key on span-name literals, so a typo'd span name
-would silently vanish from every report.  Root spans named after the
+SPAN names get the same treatment (``SPANS``; no dynamic families: an
+f-string span name is a finding): the timeline/report joins key on
+span-name literals, so a typo'd span name would silently vanish from
+every report.  Root spans named after the
 step (``obs.span(self.profile_name, ...)``) are variables, not
 literals, and ride outside the lint.
 """
@@ -306,7 +307,6 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
 
 # dynamic families: f-string names must start with one of these
 PREFIXES: Tuple[str, ...] = (
-    "bench.",        # per-plane bench gauges mirror BENCH_r0N extras
     "eval.",         # eval.<set>.auc / eval.<set>.pr_auc per eval set
 )
 
@@ -372,18 +372,13 @@ SPANS: Dict[str, str] = {
                   "recorded when it ends"),
 }
 
-# span families whose names embed data (the bench's per-plane spans)
-SPAN_PREFIXES: Tuple[str, ...] = (
-    "bench.",
-)
-
 
 def is_declared(name: str) -> bool:
     return name in MANIFEST or any(name.startswith(p) for p in PREFIXES)
 
 
 def is_declared_span(name: str) -> bool:
-    return name in SPANS or any(name.startswith(p) for p in SPAN_PREFIXES)
+    return name in SPANS
 
 
 def declared_type(name: str) -> str:

@@ -40,7 +40,7 @@ from . import tracer
 from .health import classify, health_dir_for, read_health
 
 # `monitor --once --json` exit code when any process is stalled/stale —
-# distinct from generic failure (1) and the bench/schema mismatch (2)
+# distinct from generic failure (1) and a usage error (2)
 EXIT_UNHEALTHY = 3
 
 _STATE_FLAGS = {"live": "", "stalled": "  << STALLED (no progress)",

@@ -1,8 +1,8 @@
 """Self-contained elastic-training demo controller — one process of an
 N-controller quorum-gated NN job over a shared control-plane directory.
 
-``bench.py --plane multihost`` and ``tests/test_multihost.py`` both
-launch this module as a subprocess per controller::
+``tests/test_multihost.py`` launches this module as a subprocess per
+controller::
 
     python -m shifu_tpu.parallel.elastic_demo --out DIR --proc I --nproc N
 
@@ -30,9 +30,9 @@ import time
 
 
 def _force_small_cpu() -> None:
-    """Under ``JAX_PLATFORMS=cpu`` (what the tests and a CPU-rig bench
-    export), pin the demo to 2 virtual CPU devices, replacing any
-    inherited count (the test suite exports 8).  Any other platform is
+    """Under ``JAX_PLATFORMS=cpu`` (what the tests export), pin the demo
+    to 2 virtual CPU devices, replacing any inherited count (the test
+    suite exports 8).  Any other platform is
     taken as given — the launcher decides which devices a controller
     owns (``parallel.mesh.refuse_children_on_chip``)."""
     if os.environ.get("JAX_PLATFORMS") != "cpu":

@@ -87,13 +87,12 @@ def shard_chunk_rows(mesh, *arrays):
 # ---------------------------------------------------- one process per chip
 def refuse_children_on_chip(what: str) -> None:
     """Guard for the modes that start one JAX process per worker
-    (``serve --replicas``, ``bench --plane fleet|multihost``).  A chip
-    belongs to one process at a time: the second process to touch it
-    fails or hangs, and a child pushed onto the CPU instead would be a
-    CPU process counted as a chip replica.  Until those modes drive every
-    chip from one process (ROADMAP D6) they run on a CPU backend only;
-    on a ``tpu`` backend this raises a CODED error before anything is
-    spawned."""
+    (``serve --replicas``).  A chip belongs to one process at a time:
+    the second process to touch it fails or hangs, and a child pushed
+    onto the CPU instead would be a CPU process counted as a chip
+    replica.  Until those modes drive every chip from one process
+    (ROADMAP D6) they run on a CPU backend only; on a ``tpu`` backend
+    this raises a CODED error before anything is spawned."""
     import jax
 
     if jax.default_backend() == "tpu":

@@ -45,10 +45,6 @@ header) decomposes each sampled request into queue-wait / deadline-wait
 :mod:`batcher`), and every completion feeds the live SLO plane
 (:mod:`shifu_tpu.obs.slo`: sliding-window quantiles, burn-rate alerts
 against ``-Dshifu.serve.sloP99Ms`` / ``-Dshifu.serve.sloAvailability``).
-
-Bench: ``bench.py --plane serve`` (sustained QPS, p50/p99 at several
-offered loads, bucket occupancy / padding waste, zero-recompile guard,
-1%-sampled traced pass + latency-decomposition extras).
 """
 
 from .batcher import MicroBatcher, Ticket                     # noqa: F401

@@ -225,8 +225,8 @@ class MicroBatcher:
         self.default_deadline_s = configured_deadline_s()
         self._drain_rate = 0.0            # rows/s EWMA across launches
         self._last_launch_t: Optional[float] = None
-        # telemetry-independent accounting (the bench reads this; the
-        # same numbers mirror into obs counters when telemetry is on)
+        # telemetry-independent accounting (the same numbers mirror
+        # into obs counters when telemetry is on)
         self.stats: Dict[str, float] = {
             "requests": 0, "rows": 0, "batches": 0, "rows_padded": 0,
             "flush_full": 0, "flush_deadline": 0, "errors": 0,

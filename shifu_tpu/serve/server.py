@@ -8,7 +8,7 @@ micro-batcher worker and the per-process heartbeat
 serves scoring requests:
 
 - in-process: :meth:`ServeServer.score` (closed-loop) /
-  :meth:`ServeServer.submit` (async ticket) — what the bench drives;
+  :meth:`ServeServer.submit` (async ticket) — what embedded callers drive;
 - over HTTP (stdlib, zero new deps): ``POST /score`` with
   ``{"rows": [[...]], "bins": [[...]]}`` -> ``{"scores": [...]}``, or
   RAW records ``{"records": [{field: value, ...}]}`` when the modelset

@@ -161,7 +161,7 @@ class ForestResult:
     disk_passes: int = 0                 # streamed mode: cold stream sweeps taken
     tail_sweeps: int = 0                 # streamed mode: disk-tail re-streams
                                          # (the super-batch schedule's guard
-                                         # metric; bench extras read it)
+                                         # metric)
     bytes_read: int = 0                  # streamed mode: bytes this train
                                          # run pulled off disk (host-side
                                          # stream accounting, telemetry-

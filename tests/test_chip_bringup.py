@@ -1,7 +1,7 @@
 """Contracts the first chip run put in place (PR 21), checkable on the CPU:
-where the compile cache lives, that multi-process modes refuse on a chip
-host instead of hanging, and that a bench plane that raises fails the
-benchmark.  The chip itself is exercised by ``chip_smoke.py``."""
+where the compile cache lives, and that multi-process modes refuse on a
+chip host instead of hanging.  The chip itself is exercised by
+``chip_smoke.py``."""
 
 import os
 import subprocess
@@ -65,15 +65,12 @@ def on_a_chip_host(monkeypatch):
 
 def test_fleet_and_multihost_refuse_on_chip(on_a_chip_host, tmp_path,
                                             capsys):
-    from shifu_tpu.bench import bench_fleet, bench_multihost
     from shifu_tpu.cli import main
     from shifu_tpu.config.errors import ErrorCode, ShifuError
     from shifu_tpu.serve.router import run_fleet
-    for start in (lambda: run_fleet(str(tmp_path), replicas=2, port=0),
-                  bench_multihost, bench_fleet):
-        with pytest.raises(ShifuError) as ei:
-            start()
-        assert ei.value.error_code is ErrorCode.ERROR_ONE_PROCESS_PER_CHIP
+    with pytest.raises(ShifuError) as ei:
+        run_fleet(str(tmp_path), replicas=2, port=0)
+    assert ei.value.error_code is ErrorCode.ERROR_ONE_PROCESS_PER_CHIP
     # the CLI surface: coded message, exit 1, no traceback
     assert main(["--dir", str(tmp_path), "serve", "--replicas", "2",
                  "--port", "0"]) == 1
@@ -85,18 +82,3 @@ def test_children_allowed_on_cpu_backend():
     assert jax.default_backend() == "cpu"
     refuse_children_on_chip("anything")        # no raise
 
-
-# ------------------------------------------------------- bench re-raises
-def test_bench_plane_that_raises_fails_the_benchmark(monkeypatch):
-    """A plane's exception is the benchmark's exception — it used to
-    land as a ``*_error`` extra beside exit 0."""
-    from shifu_tpu import bench
-    monkeypatch.setattr(bench, "bench_nn", lambda collect=None: 1000.0)
-    monkeypatch.setattr(bench, "bench_nn_mixed",
-                        lambda collect=None: 1000.0)
-
-    def boom():
-        raise RuntimeError("gbt plane exploded")
-    monkeypatch.setattr(bench, "bench_gbt", boom)
-    with pytest.raises(RuntimeError, match="gbt plane exploded"):
-        bench.run_benchmark(plane="all")

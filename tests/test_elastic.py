@@ -375,7 +375,13 @@ def test_initialize_distributed_succeeds_after_transient(monkeypatch):
 
 
 # ----------------------------------------------------- monitor QUORUM LOST
-def _write_health(d, proc, age_s, state="running"):
+# a record a `python -m shifu_tpu.cli monitor` subprocess must read as
+# live: its declared interval outlasts the child's cold start (and its
+# 120 s timeout), where the default 0.5 s is stale after one second
+CLI_INTERVAL_S = 120.0
+
+
+def _write_health(d, proc, age_s, state="running", interval_s=0.5):
     hd = os.path.join(d, "telemetry", "health")
     os.makedirs(hd, exist_ok=True)
     now = time.time()
@@ -383,7 +389,7 @@ def _write_health(d, proc, age_s, state="running"):
     with open(path, "w") as f:
         json.dump({"proc": proc, "step": "TRAIN", "state": state,
                    "ts": now - age_s, "last_progress_ts": now - age_s,
-                   "interval_s": 0.5, "rows": 100}, f)
+                   "interval_s": interval_s, "rows": 100}, f)
     # age the mtime WITH the embedded ts: a genuinely dead process left
     # both behind (a mismatched pair reads as clock skew and the
     # aggregate's offset normalization would "revive" the record)
@@ -414,7 +420,7 @@ def test_monitor_quorum_lost_cli_subprocess(tmp_path):
     """ACCEPTANCE (satellite): `shifu-tpu monitor --aggregate` flags
     QUORUM LOST and exits 3 when live members fall below quorumFrac."""
     d0, d1 = str(tmp_path / "p0"), str(tmp_path / "p1")
-    _write_health(d0, "ctrl-0", 0.0)
+    _write_health(d0, "ctrl-0", 0.0, interval_s=CLI_INTERVAL_S)
     _write_health(d1, "ctrl-1", 60.0)           # dead without a final beat
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -426,7 +432,7 @@ def test_monitor_quorum_lost_cli_subprocess(tmp_path):
     assert p.returncode == monitor_mod.EXIT_UNHEALTHY, p.stdout + p.stderr
     assert "QUORUM LOST" in p.stdout
     # healthy pair: flag off, exit 0
-    _write_health(d1, "ctrl-1", 0.0)
+    _write_health(d1, "ctrl-1", 0.0, interval_s=CLI_INTERVAL_S)
     p = subprocess.run(
         [sys.executable, "-m", "shifu_tpu.cli", "monitor", "--once",
          "--aggregate", d0, d1],

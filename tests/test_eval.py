@@ -92,7 +92,7 @@ def test_device_sweep_small_and_degenerate():
 
 def test_score_device_matches_host_scorer():
     """score_device must agree with score() and feed sweep_device without
-    leaving HBM (the bench/eval resident plane)."""
+    leaving HBM (the device-resident eval plane)."""
     import jax
     import jax.numpy as jnp
     from shifu_tpu.models.nn import (IndependentNNModel, NNModelSpec,

@@ -297,19 +297,6 @@ def test_report_renders_ingest_stall_fraction(tmp_path):
         obs.reset_for_tests()
 
 
-def test_bench_tail_plane_schema():
-    """`--plane tail` quick mode exists and the bench/obs schema handshake
-    still holds past the v2 (ingest.*) bump — v3 added the varsel.*
-    instrumentation; the ingest counters this suite pins remain."""
-    from shifu_tpu import obs
-    from shifu_tpu.bench import BENCH_TELEMETRY_SCHEMA
-    assert BENCH_TELEMETRY_SCHEMA == obs.SCHEMA_VERSION >= 2
-    import shifu_tpu.bench as bench_mod
-    assert callable(bench_mod.bench_gbt_streamed_tail)
-    with pytest.raises(ValueError):
-        bench_mod.run_benchmark(plane="nope")
-
-
 def test_tail_super_batch_disk_pass_telemetry_guard(tmp_path, monkeypatch):
     """Round-9 regression guard, telemetry-backed: under the super-batch
     tail schedule, passes per tree must stay within the acceptance bound
@@ -374,31 +361,3 @@ def test_report_renders_tail_sweep_line(tmp_path):
         assert "ingest stall fraction" in text
     finally:
         obs.reset_for_tests()
-
-
-def test_bench_cli_tail_help_and_schema_exit(monkeypatch):
-    """CI smoke for the tail plane CLI: --help lists it, and a bench/obs
-    schema-version mismatch exits NONZERO (code 2) instead of tracing
-    out — the guard CI keys off."""
-    import importlib.util
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--help"],
-        capture_output=True, text=True, timeout=120)
-    assert out.returncode == 0
-    assert "tail" in out.stdout
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_cli", os.path.join(repo, "bench.py"))
-    bench_cli = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_cli)
-    import shifu_tpu.bench as bench_mod
-    monkeypatch.setattr(bench_mod, "BENCH_TELEMETRY_SCHEMA",
-                        bench_mod.BENCH_TELEMETRY_SCHEMA + 1)
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--plane", "tail"])
-    with pytest.raises(SystemExit) as ei:
-        bench_cli.main()
-    assert ei.value.code == 2

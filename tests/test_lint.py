@@ -272,6 +272,8 @@ def test_manifest_rules_flag_and_clean_twins(tmp_path):
             obs.gauge("train.epoch_s").set(1.0)            # wrong type
             with obs.span("serve.requst"):                 # typo
                 pass
+            with obs.span(f"serve.{f.__name__}"):          # no families
+                pass
             faults.fire("norm", "shardz", 1)               # typo
     """
     found = _lint_snippet(tmp_path, bad,
@@ -279,7 +281,7 @@ def test_manifest_rules_flag_and_clean_twins(tmp_path):
                                  "fault-site"])
     assert sorted(_rules_hit(found)) == ["fault-site", "metric-manifest",
                                          "span-manifest"]
-    assert len(found) == 4
+    assert len(found) == 5
 
     clean = """
         from shifu_tpu import obs, faults
@@ -287,7 +289,7 @@ def test_manifest_rules_flag_and_clean_twins(tmp_path):
         def f(name):
             obs.counter("ingest.windows_emitted").inc()
             obs.histogram("train.epoch_s").observe(1.0)
-            obs.gauge(f"bench.{name}").set(1.0)       # declared prefix
+            obs.gauge(f"eval.{name}.auc").set(1.0)    # declared prefix
             with obs.span("serve.request"):
                 pass
             with obs.span(name):                      # variable: exempt

@@ -1,7 +1,7 @@
 """Tests for the ``shifu_tpu/obs`` telemetry subsystem: span
 nesting/ordering, JSONL schema round-trip, registry aggregation (host-side
 only — recording from inside ``jit`` must fail), zero-output no-op mode,
-the disabled-path overhead guard, and the bench/obs schema handshake."""
+and the disabled-path overhead guard."""
 
 import json
 import logging
@@ -306,55 +306,6 @@ def test_disabled_costed_jit_is_bare_jit(telemetry_off):
          f"{t_plain:.4f}s bare jit")
     assert costs.cost_snapshot() == []
     assert obs.pending_records() == []
-
-
-def test_bench_schema_matches_obs():
-    """bench.py must fail loudly when its emitted schema version and the
-    obs schema diverge — this pin is the loud failure's test double.
-    v3 added the varsel_* extras (streamed mask-batched sensitivity
-    plane); v4 the disk-tail super-batch round (tail_* extras +
-    train.tail_sweeps / tail_repairs counters); v5 the observability
-    plane v2 (tid on span records, drift.* gauges, health heartbeats,
-    OpenMetrics snapshots, bench --compare); v6 the device
-    cost-attribution plane (cost records per executable, *_mfu /
-    *_achieved_bw extras, xla.recompiles sentinel, --compare auto
-    mode): the version must be current AND the planes registered, so a
-    schema bump cannot land without the emissions being
-    re-validated."""
-    from shifu_tpu.bench import (BENCH_TELEMETRY_SCHEMA, _mfu_extras,
-                                 bench_gbt_streamed_tail, bench_varsel,
-                                 is_tracked_throughput,
-                                 resolve_compare_paths, run_compare)
-    assert BENCH_TELEMETRY_SCHEMA == obs.SCHEMA_VERSION
-    assert BENCH_TELEMETRY_SCHEMA >= 6          # cost-attribution era
-    assert callable(bench_varsel)
-    assert callable(bench_gbt_streamed_tail)
-    assert callable(run_compare)                # the BENCH_r0N reader
-    # v5 surfaces exist and share the schema constant
-    from shifu_tpu.obs import drift, exporter, health, timeline
-    assert callable(timeline.to_trace_events)
-    assert callable(exporter.render_openmetrics)
-    assert callable(health.start_heartbeat)
-    assert callable(drift.start_drift_monitor)
-    # v6 surfaces: the cost plane + its bench emissions
-    from shifu_tpu.obs import costs, utilization
-    assert callable(costs.costed_jit)
-    assert callable(costs.record_executable)
-    assert callable(utilization.render_utilization)
-    assert callable(_mfu_extras)
-    assert callable(resolve_compare_paths)      # --compare auto mode
-    # the compare gates the v6 utilization extras, not just throughputs
-    assert is_tracked_throughput("nn_train_mfu")
-    assert is_tracked_throughput("wdl_train_achieved_bw")
-    assert not is_tracked_throughput("nn_train_mfu_error")
-
-
-def test_bench_refuses_schema_mismatch(monkeypatch):
-    import shifu_tpu.bench as bench_mod
-    monkeypatch.setattr(bench_mod, "BENCH_TELEMETRY_SCHEMA",
-                        obs.SCHEMA_VERSION + 1)
-    with pytest.raises(RuntimeError, match="disagrees"):
-        bench_mod.run_benchmark()
 
 
 # ----------------------------------------------------------------- logging

@@ -4,8 +4,7 @@ shape-churn recompile sentinel (counter + warn-once — the acceptance
 test), lazy module-scope wrapping, analytic Pallas models, cost records
 in the flush/trace, the utilization/roofline report (incl. the
 deterministic-render golden), padding-waste accounting, timeline cost
-annotation + torn-trace hardening, `monitor --once --json`, and
-`bench.py --compare` auto-mode."""
+annotation + torn-trace hardening, and `monitor --once --json`."""
 
 import json
 import logging
@@ -500,65 +499,3 @@ def test_monitor_json_cli_exit_zero_empty(tmp_path):
     assert p.returncode == 0, p.stderr
     doc = json.loads(p.stdout)
     assert doc["kind"] == "monitor" and doc["procs"] == []
-
-
-# --------------------------------------------------- compare auto-mode
-def test_compare_auto_mode_resolution(tmp_path):
-    """Satellite: `--compare` with no arguments picks the two newest
-    BENCH_r*.json (round order); fewer than two is a clear error."""
-    from shifu_tpu.bench import resolve_compare_paths
-
-    # explicit pair passes through untouched
-    assert resolve_compare_paths(["a.json", "b.json"]) == ("a.json",
-                                                           "b.json")
-    with pytest.raises(ValueError, match="exactly two"):
-        resolve_compare_paths(["only.json"])
-    # auto mode against a synthetic root
-    for n in ("BENCH_r01.json", "BENCH_r02.json", "BENCH_r10.json"):
-        with open(tmp_path / n, "w") as f:
-            json.dump({"metric": "m", "value": 1.0}, f)
-    old, new = resolve_compare_paths([], root=str(tmp_path))
-    assert os.path.basename(old) == "BENCH_r02.json"
-    assert os.path.basename(new) == "BENCH_r10.json"
-    (tmp_path / "BENCH_r02.json").unlink()
-    (tmp_path / "BENCH_r10.json").unlink()
-    with pytest.raises(ValueError, match="at least two BENCH_r"):
-        resolve_compare_paths([], root=str(tmp_path))
-
-
-def test_compare_auto_mode_cli(tmp_path):
-    """The repo root holds no BENCH_r*.json (chip numbers live in
-    PERF_LEDGER.jsonl): auto mode there is the clean coded error, not a
-    traceback."""
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--compare"],
-        capture_output=True, text=True, env=env, cwd=str(tmp_path),
-        timeout=120)
-    assert p.returncode == 2, p.stdout + p.stderr
-    assert "at least two BENCH_r" in p.stderr
-    assert "Traceback" not in p.stderr
-
-
-# ------------------------------------------------------- bench mfu fold
-def test_mfu_extras_fold(monkeypatch):
-    from shifu_tpu.bench import _mfu_extras
-    monkeypatch.setenv("SHIFU_TPU_PEAK_FLOPS", "1e12")
-    monkeypatch.setenv("SHIFU_TPU_PEAK_BW", "1e11")
-    extras = {}
-    col = {"flops_per_window": 2e9, "bytes_per_window": 1e9,
-           "rows_per_window": 1000}
-    _mfu_extras("nn_train", 10_000.0, col, extras)   # window wall = 0.1s
-    assert extras["nn_train_achieved_flops"] == pytest.approx(2e10)
-    assert extras["nn_train_mfu"] == pytest.approx(0.02)
-    assert extras["nn_train_achieved_bw"] == pytest.approx(1e10)
-    assert extras["nn_train_bw_frac_of_peak"] == pytest.approx(0.1)
-    assert "peaks_provenance" in extras
-    # no rows collected (cost analysis failed): no extras, no crash
-    before = dict(extras)
-    _mfu_extras("wdl_train", 10_000.0, {}, extras)
-    assert extras == before

@@ -2,9 +2,8 @@
 ``monitor`` (incl. the SIGSTOP-staleness integration test), timeline
 export (Chrome trace_event schema, ingest track), OpenMetrics/JSON
 snapshots, the streaming drift monitor (incremental == batch PSI), the
-``obs:heartbeat`` fault site, ``bench.py --compare`` regression
-tracking, graceful ``analysis --telemetry`` on missing/torn traces, and
-the metric-name manifest lint."""
+``obs:heartbeat`` fault site, graceful ``analysis --telemetry`` on
+missing/torn traces, and the metric-name manifest lint."""
 
 import json
 import os
@@ -607,90 +606,6 @@ def test_analysis_telemetry_missing_empty_torn(tmp_path, capsys):
     assert "no telemetry recorded" in out and "torn line" in out
 
 
-# ------------------------------------------------------ bench --compare
-def _bench_payload(path, scale: float, wrapped: bool) -> str:
-    """A bench payload on disk in either shape the compare reads: the raw
-    JSON line ``bench.py`` prints, or a driver wrapper around it."""
-    doc = {"metric": "nn_train_throughput", "value": 1000.0 * scale,
-           "unit": "rows/sec", "vs_baseline": 2.0,
-           "extra": {"gbt_train_throughput_resident": 500.0 * scale,
-                     "gbt_train_throughput_resident_vs_baseline": 1.2,
-                     "rf_train_throughput": 400.0,
-                     "stats_throughput": 800.0 * (2.0 - scale),
-                     "serve_low_p99_ms": 3.0,
-                     "resume_first_tree_s": 1.5,
-                     "streamed_bench_shape": {"tail": "not a metric"}}}
-    with open(path, "w") as f:
-        json.dump({"n": 1, "rc": 0, "parsed": doc} if wrapped else doc, f)
-    return str(path)
-
-
-def test_bench_compare_recorded_payloads(tmp_path, capsys):
-    """Recorded payloads are the compare's native input: a raw line and
-    a driver wrapper must parse, print a table, and agree with a hand
-    computation."""
-    from shifu_tpu.bench import (bench_metrics, compare_bench,
-                                 load_bench_file, run_compare)
-    po = _bench_payload(tmp_path / "BENCH_r04.json", 1.0, wrapped=True)
-    pn = _bench_payload(tmp_path / "BENCH_r05.json", 0.8, wrapped=False)
-    old, new = load_bench_file(po), load_bench_file(pn)
-    om, nm = bench_metrics(old), bench_metrics(new)
-    assert "nn_train_throughput" in om and om["nn_train_throughput"] > 0
-    rows, regressed = compare_bench(old, new, threshold=0.9)
-    hand = [n for n in om
-            if n in nm and ("throughput" in n or n.endswith("_per_sec"))
-            and not n.endswith("_vs_baseline")
-            and nm[n] < 0.9 * om[n]]
-    assert hand and sorted(regressed) == sorted(hand)
-    rc = run_compare(po, pn, threshold=0.9)
-    out = capsys.readouterr().out
-    assert rc == 2
-    assert "nn_train_throughput" in out and "ratio" in out
-
-
-def test_bench_compare_flags_regression(tmp_path, capsys):
-    from shifu_tpu.bench import run_compare
-    old = {"metric": "nn_train_throughput", "value": 100.0,
-           "extra": {"gbt_train_throughput_resident": 50.0,
-                     "resume_first_tree_s": 1.0}}
-    new = {"metric": "nn_train_throughput", "value": 95.0,
-           "extra": {"gbt_train_throughput_resident": 20.0,   # 0.4x: bad
-                     "resume_first_tree_s": 99.0}}            # untracked
-    po, pn = str(tmp_path / "old.json"), str(tmp_path / "new.json")
-    with open(po, "w") as f:
-        json.dump(old, f)
-    with open(pn, "w") as f:
-        json.dump({"n": 9, "parsed": new}, f)   # wrapper shape
-    assert run_compare(po, pn, threshold=0.9) == 2
-    out = capsys.readouterr().out
-    assert "REGRESSED" in out
-    assert "gbt_train_throughput_resident" in out
-    # headline at 0.95x passes the 0.9 threshold; wall-clock extras
-    # never regress the compare
-    assert out.count("REGRESSED") == 2       # table row + summary line
-    assert run_compare(po, po, threshold=0.9) == 0
-
-
-def test_bench_compare_cli_exit_codes(tmp_path):
-    """The shipped entry point: `python bench.py --compare` (no
-    benchmark run, no jax traffic) exits 0/2 per the threshold."""
-    env = _subprocess_env()
-    good = _bench_payload(tmp_path / "good.json", 1.0, wrapped=True)
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--compare", good, good],
-        capture_output=True, text=True, env=env, cwd=REPO, timeout=120)
-    assert p.returncode == 0, p.stderr
-    assert "no tracked throughput regressions" in p.stdout
-    bad = _bench_payload(tmp_path / "bad.json", 0.5, wrapped=False)
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--compare", good, bad, "--threshold", "0.9"],
-        capture_output=True, text=True, env=env, cwd=REPO, timeout=120)
-    assert p.returncode == 2, p.stdout + p.stderr
-    assert "REGRESSED" in p.stdout
-
-
 # ----------------------------------------------------- manifest lint
 # The grep-based metric/span scans that lived here through round 12 are
 # now first-class AST rules in shifu_tpu/lint (metric-manifest,
@@ -726,9 +641,9 @@ def test_every_span_name_literal_is_declared_in_manifest():
     """Satellite lint: the timeline tracks / report sections / tests
     join on span-name literals, so a typo'd span name silently vanishes
     from every report — every obs.span("...") / obs.record_span("...")
-    literal must resolve against obs.manifest.SPANS (or a declared
-    SPAN_PREFIXES family).  Step-root spans named by variable
-    (obs.span(self.profile_name, ...)) ride outside the lint."""
+    literal must resolve against obs.manifest.SPANS.  Step-root spans
+    named by variable (obs.span(self.profile_name, ...)) ride outside
+    the lint."""
     from shifu_tpu.obs import manifest
     problems = _manifest_findings("span-manifest")
     assert not problems, "\n".join(f.render() for f in problems)
@@ -738,7 +653,7 @@ def test_every_span_name_literal_is_declared_in_manifest():
         assert help_, name
     assert "serve.request" in manifest.SPANS
     assert "serve.batch" in manifest.SPANS
-    assert manifest.is_declared_span("bench.serve")
+    assert not manifest.is_declared_span("bench.serve")    # no span families
     assert not manifest.is_declared_span("serve.requst")   # the typo case
 
 

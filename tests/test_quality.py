@@ -391,13 +391,19 @@ def _q_extras(degraded=False, auc=0.9, psi=0.01, joined=100, gens=None):
             "joined": joined, "generations": gens or {"0": auc}}
 
 
-def _write_serve_health(d, proc, quality=None, age_s=0.0):
+# a record a `monitor` CLI child must read as live: its interval outlasts
+# the child's cold start, where the default 0.5 s is stale (age > 2 x
+# interval) before the CLI reads it
+CLI_INTERVAL_S = 120.0
+
+
+def _write_serve_health(d, proc, quality=None, age_s=0.0, interval_s=0.5):
     hd = os.path.join(d, "telemetry", "health")
     os.makedirs(hd, exist_ok=True)
     now = time.time()
     rec = {"proc": proc, "step": "SERVE", "state": "running",
            "ts": now - age_s, "last_progress_ts": now - age_s,
-           "interval_s": 0.5, "rows": 10}
+           "interval_s": interval_s, "rows": 10}
     if quality is not None:
         rec["quality"] = quality
     path = os.path.join(hd, f"{proc}.json")
@@ -459,8 +465,9 @@ def test_monitor_aggregate_quality_cli_subprocess(tmp_path):
     merges per-process quality extras, flags the degraded fleet and
     exits 3; a healthy fleet exits 0."""
     d0, d1 = str(tmp_path / "p0"), str(tmp_path / "p1")
-    _write_serve_health(d0, "serve-0", quality=_q_extras(auc=0.92))
-    _write_serve_health(d1, "serve-1",
+    _write_serve_health(d0, "serve-0", quality=_q_extras(auc=0.92),
+                        interval_s=CLI_INTERVAL_S)
+    _write_serve_health(d1, "serve-1", interval_s=CLI_INTERVAL_S,
                         quality=_q_extras(degraded=True, auc=0.61))
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -473,39 +480,14 @@ def test_monitor_aggregate_quality_cli_subprocess(tmp_path):
     assert "QUALITY DEGRADED" in p.stdout
     assert "fleet quality (2 proc(s))" in p.stdout
     # the fleet recovers: flag off, exit 0
-    _write_serve_health(d1, "serve-1", quality=_q_extras(auc=0.9))
+    _write_serve_health(d1, "serve-1", quality=_q_extras(auc=0.9),
+                        interval_s=CLI_INTERVAL_S)
     p = subprocess.run(
         [sys.executable, "-m", "shifu_tpu.cli", "monitor", "--once",
          "--aggregate", d0, d1],
         capture_output=True, text=True, env=env, cwd=REPO, timeout=120)
     assert p.returncode == 0, p.stdout + p.stderr
     assert "QUALITY DEGRADED" not in p.stdout
-
-
-# ------------------------------------------------------- bench compare
-def test_bench_compare_tracks_detect_s_and_qps_frac():
-    from shifu_tpu.bench import (compare_bench, is_tracked_latency,
-                                 is_tracked_throughput)
-    assert is_tracked_latency("quality_label_flip_detect_s")
-    assert is_tracked_throughput("serve_scorelog_qps_frac")
-    old = {"metric": "x", "value": 1.0,
-           "extra": {"quality_label_flip_detect_s": 2.0,
-                     "serve_scorelog_qps_frac": 1.0}}
-    # detect time is LOWER-is-better: 2.0s -> 5.0s regresses
-    new = {"metric": "x", "value": 1.0,
-           "extra": {"quality_label_flip_detect_s": 5.0,
-                     "serve_scorelog_qps_frac": 0.99}}
-    _, regressed = compare_bench(old, new, threshold=0.9)
-    assert regressed == ["quality_label_flip_detect_s"]
-    # the scorelog overhead guard: the on/off QPS ratio falling below
-    # threshold x old is a tracked throughput regression
-    slow = {"metric": "x", "value": 1.0,
-            "extra": {"quality_label_flip_detect_s": 2.0,
-                      "serve_scorelog_qps_frac": 0.5}}
-    _, regressed = compare_bench(old, slow, threshold=0.9)
-    assert regressed == ["serve_scorelog_qps_frac"]
-    _, regressed = compare_bench(old, old, threshold=0.9)
-    assert regressed == []
 
 
 # ------------------------------------------------- refresh quality trigger

@@ -622,62 +622,7 @@ def test_scorer_stacked_groups_rebuild_when_models_change():
     assert swapped.tobytes() != first.tobytes()
 
 
-# --------------------------------------------- bench compare (satellite)
-def test_compare_latency_class_lower_is_better(tmp_path, capsys):
-    """A serve p99 regression exits 2 like a throughput regression;
-    a latency IMPROVEMENT never flags."""
-    from shifu_tpu.bench import (compare_bench, is_tracked_latency,
-                                 is_tracked_throughput, run_compare)
-    assert is_tracked_latency("serve_low_p99_ms")
-    assert is_tracked_latency("serve_closed_p50_ms")
-    assert not is_tracked_latency("serve_qps_sustained")
-    assert not is_tracked_throughput("serve_low_p99_ms")
-    assert is_tracked_throughput("serve_qps_sustained")
-    assert not is_tracked_throughput("serve_low_qps_offered")
-    # raw-serving + fleet extras: QPS-class metrics (and the scaling
-    # fraction) gate as throughput; the kill-drill p99 as latency
-    assert is_tracked_throughput("serve_raw_qps_frac")
-    assert is_tracked_throughput("serve_fleet_2r_qps")
-    assert is_tracked_throughput("serve_fleet_scaling_frac")
-    assert is_tracked_latency("serve_fleet_kill_p99_ms")
-    assert not is_tracked_throughput("serve_fleet_kill_p99_ms")
-    old = {"metric": "serve_qps_sustained", "value": 100000.0,
-           "extra": {"serve_low_p99_ms": 3.0, "serve_mid_p50_ms": 1.0,
-                     "serve_deadline_ms": 2.0}}
-    new = {"metric": "serve_qps_sustained", "value": 100000.0,
-           "extra": {"serve_low_p99_ms": 9.0,     # 3x worse: regression
-                     "serve_mid_p50_ms": 0.5,     # improvement: fine
-                     "serve_deadline_ms": 2.0}}   # untracked
-    rows, regressed = compare_bench(old, new, threshold=0.9)
-    assert regressed == ["serve_low_p99_ms"]
-    # at exactly old/threshold the latency metric does NOT regress
-    edge = {"metric": "serve_qps_sustained", "value": 100000.0,
-            "extra": {"serve_low_p99_ms": 3.0 / 0.9,
-                      "serve_mid_p50_ms": 1.0, "serve_deadline_ms": 2.0}}
-    _, r2 = compare_bench(old, edge, threshold=0.9)
-    assert r2 == []
-    po, pn = str(tmp_path / "old.json"), str(tmp_path / "new.json")
-    with open(po, "w") as f:
-        json.dump(old, f)
-    with open(pn, "w") as f:
-        json.dump(new, f)
-    assert run_compare(po, pn, threshold=0.9) == 2
-    out = capsys.readouterr().out
-    assert "serve_low_p99_ms" in out and "REGRESSED" in out
-    assert run_compare(po, po, threshold=0.9) == 0
-
-
 # ----------------------------------------------------------- CLI surface
-def test_bench_help_lists_serve_plane():
-    import subprocess
-    import sys
-    out = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"), "--help"],
-        capture_output=True, text=True, timeout=60)
-    assert out.returncode == 0 and "serve" in out.stdout
-
-
 def test_cli_serve_selfcheck_on_trained_modelset(prepared_set, capsys):
     """`shifu-tpu serve --selfcheck` loads the trained ensemble from
     <dir>/models, warms the buckets, scores synthetic rows in-process

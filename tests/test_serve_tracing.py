@@ -1,13 +1,11 @@
 """Per-request tracing suite (serve plane, tier-1-fast): trace-id
 propagation end to end, the latency decomposition summing to measured
 e2e, batch spans linking member ids (fan-in causality), the zero-cost
-guards for sampling off, the ``X-Shifu-Trace`` HTTP header, the
-``shifu-serve`` timeline track, and the bench decomposition helper /
-compare classes."""
+guards for sampling off, the ``X-Shifu-Trace`` HTTP header, and the
+``shifu-serve`` timeline track."""
 
 import json
 import os
-import time
 
 import numpy as np
 import pytest
@@ -305,74 +303,46 @@ def test_timeline_routes_serve_spans_to_own_track(tmp_path):
 
 
 # ---------------------------------------------------- zero-cost guard
-def test_serve_rate_zero_overhead_within_noise():
+def test_serve_rate_zero_overhead_within_noise(monkeypatch):
     """CI guard (the PR 1 convention extended to the serve path): with
-    sampling OFF, the submit->pump->complete hot path under telemetry ON
-    must run within noise of the same loop with telemetry fully
-    disabled — rate 0 short-circuits before any tracing work (one float
-    compare), so the only delta is the pre-existing counter path."""
-    scorer = _warm_scorer(buckets=(1, 4))
-    rng = np.random.default_rng(13)
-    x = rng.normal(size=(4, 8)).astype(np.float32)
+    sampling OFF and telemetry ON, the submit->pump->complete hot path
+    never enters the tracing branch — rate 0 short-circuits on one float
+    compare before the sampler's RNG is drawn, no trace state is built
+    and no batch folds a decomposition.  Counted, not timed: a wall-clock
+    ratio on a CPU shared with other test workers is not a verdict."""
+    from shifu_tpu.serve import batcher as batcher_mod
+    calls = {"rng": 0, "req_trace": 0, "emit": 0}
 
-    def loop():
+    def counted(key, fn):
+        def wrapper(*a, **k):
+            calls[key] += 1
+            return fn(*a, **k)
+        return wrapper
+
+    monkeypatch.setattr(batcher_mod, "_ReqTrace",
+                        counted("req_trace", batcher_mod._ReqTrace))
+    monkeypatch.setattr(
+        MicroBatcher, "_emit_trace_spans",
+        counted("emit", MicroBatcher._emit_trace_spans))
+    scorer = _warm_scorer(buckets=(1, 4))
+    x = np.random.default_rng(13).normal(size=(4, 8)).astype(np.float32)
+
+    def loop(rate):
         b = MicroBatcher(lambda: scorer, max_delay_s=0.0,
-                         trace_sample_rate=0.0)
+                         trace_sample_rate=rate)
+        b._trace_rng.random = counted("rng", b._trace_rng.random)
         tickets = [b.submit_burst(x) for _ in range(50)]
         b.drain()
         for t in tickets:
             t.wait(10.0)
+        return tickets
 
-    def best(setup):
-        out = []
-        for _ in range(5):
-            setup()
-            t0 = time.perf_counter()
-            loop()
-            out.append(time.perf_counter() - t0)
-        return min(out)
-
-    loop()                                  # warm dispatch paths
-    t_off = best(lambda: obs.set_enabled(False))
-    t_on = best(lambda: obs.set_enabled(True))
-    obs.set_enabled(None)
-    assert t_on <= t_off * 1.5 + 1e-3, \
-        (f"rate-0 serve path overhead too high with telemetry on: "
-         f"{t_on:.4f}s vs {t_off:.4f}s disabled")
-
-
-# ------------------------------------------------------ bench surfaces
-def test_bench_trace_decomposition_helper():
-    from shifu_tpu.bench import _trace_decomposition
-    spans = [{"kind": "span", "name": "serve.request",
-              "attrs": {"e2e_s": 0.010, "queue_wait_s": 0.006,
-                        "device_s": 0.002, "pad_s": 0.001}},
-             {"kind": "span", "name": "serve.request",
-              "attrs": {"e2e_s": 0.020, "queue_wait_s": 0.008,
-                        "device_s": 0.010, "pad_s": 0.000}}]
-    fr = _trace_decomposition(spans)
-    assert fr["serve_queue_frac"] == pytest.approx(0.5)
-    assert fr["serve_device_frac"] == pytest.approx(0.35)
-    assert fr["serve_pad_frac"] == pytest.approx(0.05)
-    assert _trace_decomposition([]) == {}
-    # zero/missing e2e records are skipped, not divide-by-zeroed
-    assert _trace_decomposition([{"attrs": {"e2e_s": 0}}]) == {}
-
-
-def test_compare_tracks_decomposition_fracs():
-    """Satellite: queue/pad fracs ride the lower-is-better class next
-    to the latency percentiles; device_frac stays informational."""
-    from shifu_tpu.bench import compare_bench, is_tracked_latency
-    assert is_tracked_latency("serve_queue_frac")
-    assert is_tracked_latency("serve_pad_frac")
-    assert not is_tracked_latency("serve_device_frac")
-    assert not is_tracked_latency("serve_trace_sample_rate")
-    old = {"metric": "serve_qps_sustained", "value": 1e6,
-           "extra": {"serve_queue_frac": 0.5, "serve_pad_frac": 0.01,
-                     "serve_device_frac": 0.4}}
-    new = {"metric": "serve_qps_sustained", "value": 1e6,
-           "extra": {"serve_queue_frac": 0.9,    # waiting longer: bad
-                     "serve_pad_frac": 0.01,
-                     "serve_device_frac": 0.05}}  # untracked
-    _, regressed = compare_bench(old, new, threshold=0.9)
-    assert regressed == ["serve_queue_frac"]
+    obs.set_enabled(True)
+    tickets = loop(0.0)
+    assert calls == {"rng": 0, "req_trace": 0, "emit": 0}
+    assert all(t.trace is None for t in tickets)
+    assert _request_spans() == [] and _batch_spans() == []
+    # the counters do see the branch: the same loop, every request sampled
+    loop(1.0)
+    assert calls["rng"] == 50 and calls["req_trace"] == 50
+    assert calls["emit"] >= 1 and len(_request_spans()) == 50
