@@ -11,11 +11,14 @@ import zlib
 from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from dataclasses import dataclass, field
-from typing import (Callable, Dict, Iterator, List, NamedTuple, Optional,
-                    Sequence, Tuple, TypeVar)
+from typing import (TYPE_CHECKING, Callable, Dict, Iterator, List, Mapping,
+                    NamedTuple, Optional, Sequence, Tuple, TypeVar)
 
 import numpy as np
 from numpy.lib import format as npf
+
+if TYPE_CHECKING:
+    from .staging import RowLayout
 
 log = logging.getLogger(__name__)
 
@@ -129,11 +132,27 @@ def _plan_npz(path: str) -> _ShardPlan:
     return _ShardPlan(path, int(rows.pop()), members)
 
 
-def _fill_npz(plan: _ShardPlan, dest: Dict[str, np.ndarray]) -> bool:
-    """Read one npz shard into ``dest`` (its row slices of the plane).
-    Stored members go file -> slice in one copy, checked against the
-    directory's CRC-32 as ``zipfile`` would; the others are decoded by
-    ``np.load`` and assigned.  True when every member went straight."""
+class _HostRows:
+    """A key's rows of one shard in the host plane, as a destination of
+    the fill: written where they stay.  The other destination is
+    ``staging._StagedRows``, pieces on their way to the device."""
+
+    def __init__(self, rows: np.ndarray):
+        self.rows = rows
+
+    def pieces(self) -> Iterator[np.ndarray]:
+        yield self.rows
+
+    def write(self, src: np.ndarray) -> None:
+        self.rows[...] = src
+
+
+def _fill_npz(plan: _ShardPlan, dest: Dict[str, "_HostRows"]) -> bool:
+    """Read one npz shard into ``dest`` (its rows of the plane, key by
+    key).  Stored members go file -> destination in one copy, checked
+    against the directory's CRC-32 as ``zipfile`` would; the others are
+    decoded by ``np.load`` and written.  True when every member went
+    straight."""
     decode = [k for k, m in plan.members.items() if m.start is None]
     with open(plan.path, "rb") as f:
         for k, m in plan.members.items():
@@ -141,20 +160,21 @@ def _fill_npz(plan: _ShardPlan, dest: Dict[str, np.ndarray]) -> bool:
                 continue
             f.seek(m.start)
             crc = zlib.crc32(f.read(m.head))
-            view = dest[k].reshape(-1).view(np.uint8)
-            for a in range(0, len(view), _FILL_PIECE):
-                piece = view[a:a + _FILL_PIECE]
-                if f.readinto(piece) != len(piece):
-                    raise zipfile.BadZipFile(
-                        f"{plan.path}: member {k} ends early")
-                crc = zlib.crc32(piece, crc)
+            for rows in dest[k].pieces():
+                view = rows.reshape(-1).view(np.uint8)
+                for a in range(0, len(view), _FILL_PIECE):
+                    piece = view[a:a + _FILL_PIECE]
+                    if f.readinto(piece) != len(piece):
+                        raise zipfile.BadZipFile(
+                            f"{plan.path}: member {k} ends early")
+                    crc = zlib.crc32(piece, crc)
             if crc != m.crc:
                 raise zipfile.BadZipFile(
                     f"{plan.path}: bad CRC-32 for member {k}")
     if decode:
         with np.load(plan.path) as z:
             for k in decode:
-                dest[k][...] = z[k]
+                dest[k].write(z[k])
     return not decode
 
 
@@ -362,13 +382,20 @@ class Shards:
                 plans.append(None)
         return plans
 
-    def load_all(self) -> Dict[str, np.ndarray]:
+    def load_all(self, on_device: Optional[Mapping[str, "RowLayout"]] = None
+                 ) -> Dict[str, np.ndarray]:
         """The whole set as one resident plane: each key's array is
         allocated once at its final size and every shard is read straight
         into its row slice, several shards at a time.  Quarantine as in
         :meth:`iter_shards`; the bytes are in the returned arrays when
-        this returns."""
+        this returns.
+
+        A key that ``on_device`` names comes back as a ``jax.Array`` in the
+        layout given, zero rows appended: its consumer wants it on the
+        device only, so its rows go file -> staging piece -> device
+        (``data/staging.py``) and no host array of its size is made."""
         from .. import obs
+        on_device = on_device or {}
         with obs.span("data.load") as load_sp:
             with obs.span("data.alloc"):
                 bad = _Quarantine(self.n_shards, strict=self.is_wire)
@@ -383,24 +410,35 @@ class Shards:
                             f"{p.path}: arrays {p.layout} disagree with "
                             f"{first.path}: {first.layout}")
                 cum = np.cumsum([0] + [p.rows if p else 0 for p in plans])
+                todo = [i for i, p in enumerate(plans) if p is not None]
+                threads = _fill_width(len(todo))
                 out = {k: np.empty((int(cum[-1]),) + shape, dtype)
-                       for k, (dtype, shape) in first.layout.items()}
+                       for k, (dtype, shape) in first.layout.items()
+                       if k not in on_device}
+                staged = {}
+                if on_device:
+                    from .staging import DevicePlane
+                    staged = {k: DevicePlane(int(cum[-1]), shape, dtype,
+                                             on_device[k], threads)
+                              for k, (dtype, shape) in first.layout.items()
+                              if k in on_device}
             rd = self.wire_reader()
 
             def fill(i: int) -> bool:
-                dest = {k: a[cum[i]:cum[i + 1]] for k, a in out.items()}
+                lo, hi = int(cum[i]), int(cum[i + 1])
+                dest = {k: staged[k].dest(lo, hi, read_sp) if k in staged
+                        else _HostRows(out[k][lo:hi]) for k in first.layout}
                 if rd is None:
                     return _fill_npz(plans[i], dest)
-                for k, rows in self._wire_rows(rd, i, out).items():
-                    dest[k][...] = rows
+                for k, rows in self._wire_rows(rd, i, dest).items():
+                    dest[k].write(rows)
                 return True
 
-            todo = [i for i, p in enumerate(plans) if p is not None]
-            threads = _fill_width(len(todo))
             direct, holes = 0, []
-            with obs.span("data.read") as sp, \
+            with obs.span("data.read") as read_sp, \
                     ThreadPoolExecutor(threads, "shard-fill") as pool:
-                sp.set(bytes=sum(a.nbytes for a in out.values()))
+                read_sp.set(bytes=sum(a.nbytes for a in (*out.values(),
+                                                         *staged.values())))
                 reads = [pool.submit(self._read_shard, i, partial(fill, i),
                                      "shard decode") for i in todo]
                 try:
@@ -413,12 +451,23 @@ class Shards:
                 except BaseException:
                     pool.shutdown(cancel_futures=True)
                     raise
+            out.update((k, p.array()) for k, p in staged.items())
             if holes:
-                out = _close_holes(out, cum, holes)
+                # rare: the rows placed so far come down again and the
+                # consumer gets a host array, as if it had not asked
+                out = _close_holes(
+                    {k: np.array(out[k])[:int(cum[-1])]
+                     if k in staged else out[k] for k in first.layout},
+                    cum, holes)
             load_sp.set(bytes=sum(a.nbytes for a in out.values()),
                         shards=self.n_shards, direct=direct,
-                        threads=threads)
-            return out
+                        threads=threads,
+                        staged_bytes=sum(p.staged_bytes
+                                         for p in staged.values()),
+                        staging_bytes=sum(p.staging.nbytes
+                                          for p in staged.values()),
+                        pieces=sum(p.pieces for p in staged.values()))
+            return {k: out[k] for k in first.layout}
 
     def _sidecar_sig(self) -> List[List]:
         return [[os.path.basename(f), os.path.getsize(f)]

@@ -332,24 +332,39 @@ SPANS: Dict[str, str] = {
     "data.load": ("Shards.load_all: one plane allocated at its final "
                   "size, every shard read into its row slice (bytes of "
                   "the returned plane; shards; direct = shards whose "
-                  "every member went file -> slice in one copy, the "
-                  "others were decoded by np.load or quarantined; "
-                  "threads of the fill)"),
+                  "every member went file -> destination in one copy, "
+                  "the others were decoded by np.load or quarantined; "
+                  "threads of the fill).  A key its consumer asked for "
+                  "on the device goes file -> staging piece -> device "
+                  "inside this span, upload and placement included, and "
+                  "is there when the span ends (staged_bytes that went "
+                  "that way: 0 says no key did; staging_bytes allocated "
+                  "for it, two pieces a thread whatever the plane's "
+                  "size; pieces sent)"),
     "data.alloc": ("every shard's sizes from its zip directory and npy "
-                   "headers, then one np.empty a key"),
-    "data.read": ("the fill: shards read into their slices on the "
-                  "thread pool and checked against their CRC-32s, from "
-                  "the first submit to the last result (bytes)"),
+                   "headers, then one np.empty a host key; a key bound "
+                   "for the device gets its zero-filled device plane "
+                   "and its staging pieces here"),
+    "data.read": ("the fill: shards read into their slices, or into "
+                  "staging pieces that are sent on, on the thread pool "
+                  "and checked against their CRC-32s, from the first "
+                  "submit to the last result (bytes read)"),
+    "data.put": ("a fill thread waiting for the device before it writes "
+                 "a staging piece again: until the placement that read "
+                 "the piece is done (a child of data.read, opened on "
+                 "the fill's thread)"),
     "train.split": ("member_masks and the weight products: the "
                     "train/validation row weights of every member (rows)"),
     "nn.init": "mesh, params and optimizer state, their device_put",
-    "nn.h2d": ("the plane's only upload: rows zero-padded on the host to "
+    "nn.h2d": ("the trainer's uploads: rows zero-padded on the host to "
                "their final multiple (the minibatch when MiniBatchs is "
                "set, else the mesh's data extent), then one device_put "
-               "each of x/y/weights (bytes of the padded plane; pad_rows "
-               "appended: 0 sends x as the loader's buffer, uncopied); "
-               "ends at dispatch, the copy is waited for by whoever next "
-               "needs it.  Nothing of the plane comes back to the host"),
+               "each of y/weights, and of x when it comes as a host "
+               "array; an x the loader built on the device (data.load's "
+               "staged_bytes) is taken as it is (bytes sent here: "
+               "without such an x; pad_rows appended); ends at "
+               "dispatch, the copy is waited for by whoever next needs "
+               "it.  Nothing of the plane comes back to the host"),
     "nn.epoch": "one epoch of the in-RAM NN trainer (epoch)",
     "nn.epoch.dispatch": ("rng split and the step / epoch_steps and "
                           "eval_errors calls (builds them in epoch 0)"),

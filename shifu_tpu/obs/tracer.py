@@ -286,8 +286,15 @@ class Span:
         self._t0 = 0.0
         self._ann = None
 
+    def under(self, parent) -> "Span":
+        """Name ``parent`` (a span, live on another thread) as this span's
+        parent: the stack that links spans is a thread's own."""
+        self.parent = parent.id
+        return self
+
     def __enter__(self) -> "Span":
-        self.parent = _collector.current_parent()
+        if self.parent is None:
+            self.parent = _collector.current_parent()
         _collector.stack.append(self.id)
         self._ts = time.time()
         _collector.span_opened(self.id, self.name, self._ts)
@@ -334,6 +341,9 @@ class _NullSpan:
         return False
 
     def set(self, **attrs: Any) -> "_NullSpan":
+        return self
+
+    def under(self, parent) -> "_NullSpan":
         return self
 
 

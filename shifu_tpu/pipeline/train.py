@@ -24,7 +24,8 @@ from ..config.validator import ModelStep
 from ..data.shards import Shards
 from ..models import nn as nn_model
 from ..train import grid_search
-from ..train.nn_trainer import TrainSettings, train_ensemble
+from ..train.nn_trainer import (TrainSettings, plane_layout,
+                                train_ensemble)
 from ..train.sampling import member_masks
 from .processor import BasicProcessor
 
@@ -230,10 +231,27 @@ class TrainProcessor(BasicProcessor):
         if self._use_streaming(shards, shards.schema):
             return self._train_nn_streamed(alg, shards, n_classes=K,
                                            ova=ova)
+        params = dict(mc.train.params or {})
+        trials = self._trials(params)
+        is_gs = len(trials) > 1
+        kfold = mc.train.numKFold if mc.train.isCrossValidation else -1
+        bags = 1 if is_gs else max(1, mc.train.baggingNum)
+        kernel_svm = alg == Algorithm.SVM and str(params.get(
+            "Kernel", "linear")).lower() != "linear"
+        shuffle = bool(self.params.get("shuffle"))
+        on_device = None
+        if not (is_gs or shuffle or kernel_svm):
+            # one run, and nothing here selects rows of x (the split and
+            # the bags are row weights): the trainer is x's only consumer
+            # and wants it on the device, so the loader builds it there
+            members = (kfold if kfold > 1 else bags) * (K if ova else 1)
+            _, _, x_layout = plane_layout(
+                settings_from_params(params, mc.train), members)
+            on_device = {"x": x_layout}
         with self.phase("load_data"):
-            data = shards.load_all()
+            data = shards.load_all(on_device)
         x, y, w = data["x"], data["y"], data["w"]
-        if self.params.get("shuffle"):
+        if shuffle:
             # reference `train -shuffle` re-randomizes row order before
             # training (MapReduceShuffle re-run)
             perm = np.random.default_rng(0).permutation(len(y))
@@ -241,22 +259,15 @@ class TrainProcessor(BasicProcessor):
         schema = shards.schema
         column_nums = schema.get("columnNums", [])
         feature_names = schema.get("outputNames", [])
-        n, d = x.shape
+        n, d = len(y), x.shape[1]
         log.info("train %s: %d rows x %d features", alg.name, n, d)
 
-        if alg == Algorithm.SVM and str((mc.train.params or {}).get(
-                "Kernel", "linear")).lower() != "linear":
+        if kernel_svm:
             # nonlinear kernels leave the shared NN machinery: the
             # reference's libsvm C-SVC becomes an MXU kernel-matrix dual
             # solve (train/svm_trainer.py)
             return self._train_kernel_svm(x, y, w, column_nums,
                                           feature_names)
-
-        params = dict(mc.train.params or {})
-        trials = self._trials(params)
-        is_gs = len(trials) > 1
-        kfold = mc.train.numKFold if mc.train.isCrossValidation else -1
-        bags = 1 if is_gs else max(1, mc.train.baggingNum)
 
         os.makedirs(self.paths.tmp_models_dir, exist_ok=True)
         progress_path = self.paths.progress_path

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import obs
+from ..data.staging import RowLayout
 from ..models import nn as nn_model
 from ..parallel import mesh as meshlib
 from .early_stop import WindowEarlyStop
@@ -242,6 +243,24 @@ def _restore_tracking(state, best_valid, best_train, best_params,
         s.since_best = int(n)
 
 
+def plane_layout(settings: TrainSettings, bags: int, mesh=None):
+    """(mesh, minibatch rows, layout of x) of a resident job of ``bags``
+    members.  x is sharded by rows over the mesh's data axis and ends in
+    zero rows up to the minibatch (cut to a multiple of the data extent)
+    when ``MiniBatchs`` is set, else up to the data extent: the one rule
+    for the loader that builds x on the device (``Shards.load_all``'s
+    ``on_device``) and for the trainer that pads everything else."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    if mesh is None:
+        mesh = meshlib.device_mesh(n_ensemble=bags)
+    data_size = mesh.shape["data"]
+    bs = settings.batch_size
+    if bs:
+        bs = max(bs - bs % data_size, data_size)
+    return mesh, bs, RowLayout(NamedSharding(mesh, P("data", None)),
+                               bs or data_size)
+
+
 def train_ensemble(x: np.ndarray, y: np.ndarray,
                    train_w: np.ndarray, valid_w: np.ndarray,
                    spec: nn_model.NNModelSpec,
@@ -289,14 +308,16 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
     ``member_hypers`` gives each member its OWN scalar hypers ([B] arrays
     under keys ``lr_scale``/``l2``/``l1``/``dropout``) — how same-shape
     grid-search trials train as ONE compiled run instead of the reference's
-    queue of jobs (``gs/GridSearch.java:62``)."""
+    queue of jobs (``gs/GridSearch.java:62``).
+
+    ``x`` may be on the device already, in :func:`plane_layout`'s layout
+    (the resident loader put it there piece by piece): then only the
+    small arrays go up here."""
     bags = train_w.shape[0]
-    n = x.shape[0]
+    n = y.shape[0]
     from jax.sharding import NamedSharding, PartitionSpec as P
     with obs.span("nn.init", members=bags):
-        if mesh is None:
-            mesh = meshlib.device_mesh(n_ensemble=bags)
-        data_size = mesh.shape["data"]
+        mesh, bs, x_layout = plane_layout(settings, bags, mesh)
         key = jax.random.PRNGKey(settings.seed)
         if init_params_list is None:
             keys = jax.random.split(key, bags)
@@ -322,25 +343,25 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
         stacked = jax.device_put(stacked, sh_ens)
         opt_state = jax.device_put(opt_state, sh_ens)
 
-    # the final row multiple is known before the upload: the minibatch
-    # (itself a multiple of the data extent) when MiniBatchs is set, else
-    # the data extent.  One pad on the host, one device_put; nothing of
-    # the plane comes back.
-    bs = settings.batch_size
-    if bs:
-        bs = max(bs - bs % data_size, data_size)
+    on_device = isinstance(x, jax.Array)
+    if on_device and x.shape[0] != n + meshlib.pad_rows(n, x_layout.multiple):
+        # laid out for another job than this one: the host path
+        x, on_device = np.asarray(x)[:n], False
     with obs.span("nn.h2d") as sp:
+        # the final row multiple is known before the upload: one pad on
+        # the host, one device_put; nothing of the plane comes back.
         # padded rows carry zero weight, so the tail is never dropped;
         # per-member targets (one-vs-all) fold through the same padding
         # (_pad_all returns them only when given: zip stops there)
         sh_members = NamedSharding(mesh, P("ensemble", "data"))
         plane = [jax.device_put(a, sh) for a, sh in zip(
-            _pad_all(x, y, train_w, valid_w, bs or data_size, y_members),
-            (NamedSharding(mesh, P("data", None)),
-             NamedSharding(mesh, P("data")),
+            _pad_all(x, y, train_w, valid_w, x_layout.multiple, y_members),
+            (x_layout.sharding, NamedSharding(mesh, P("data")),
              sh_members, sh_members, sh_members))]
-        # shapes only: neither attr costs a sync
-        sp.set(bytes=sum(a.nbytes for a in plane),
+        # shapes only: neither attr costs a sync.  x that came on the
+        # device is not among the bytes sent here
+        sp.set(bytes=sum(a.nbytes for a in (plane[1:] if on_device
+                                            else plane)),
                pad_rows=plane[0].shape[0] - n)
     xd, yd, twd, vwd, ymd = (*plane, None)[:5]
 
@@ -586,9 +607,12 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
 
 
 def _pad_all(x, y, train_w, valid_w, multiple, y_members=None):
-    extra = meshlib.pad_rows(x.shape[0], multiple)
+    """Zero rows appended up to ``multiple``; an ``x`` on the device has
+    them already."""
+    extra = meshlib.pad_rows(y.shape[0], multiple)
     if extra:
-        x = np.concatenate([x, np.zeros((extra, x.shape[1]), x.dtype)])
+        if not isinstance(x, jax.Array):
+            x = np.concatenate([x, np.zeros((extra, x.shape[1]), x.dtype)])
         y = np.concatenate([y, np.zeros(extra, y.dtype)])
         zpad = np.zeros((train_w.shape[0], extra), train_w.dtype)
         train_w = np.concatenate([train_w, zpad], axis=1)

@@ -18,7 +18,7 @@ from shifu_tpu.obs import manifest, tracer
 pytestmark = pytest.mark.obs
 
 TRAIN_JOB_SPANS = (
-    "data.load", "data.alloc", "data.read", "train.split",
+    "data.load", "data.alloc", "data.read", "data.put", "train.split",
     "nn.init", "nn.h2d", "nn.epoch", "nn.epoch.dispatch",
     "nn.epoch.fetch", "nn.epoch.best_copy", "nn.epoch.progress",
     "nn.epoch.checkpoint", "xla.build")
@@ -195,23 +195,30 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
     assert load["attrs"]["shards"] == load["attrs"]["direct"] == \
         shards.n_shards
     assert 1 <= load["attrs"]["threads"] <= shards.n_shards
-    assert load["attrs"]["bytes"] == read["attrs"]["bytes"] == \
-        sum(a.nbytes for a in plane.values())
+    rows = len(plane["y"])
+    pad = -rows % batch
+    assert pad > 0                      # this set's row count is ragged
+    # x went file -> staging piece -> device, its zero rows with it; the
+    # fill threads' waits for the device hang under the read
+    assert read["attrs"]["bytes"] == sum(a.nbytes for a in plane.values())
+    assert load["attrs"]["bytes"] == read["attrs"]["bytes"] + \
+        pad * plane["x"][0].nbytes
+    assert load["attrs"]["staged_bytes"] == plane["x"].nbytes
+    assert 0 < load["attrs"]["staging_bytes"]
+    assert load["attrs"]["pieces"] >= shards.n_shards
+    puts = [s for s in spans if s["name"] == "data.put"]
+    assert puts and all(s["parent"] == read["id"] for s in puts)
 
     for name in ("train.split", "nn.init", "nn.h2d"):
         assert paths.count(name + (" < process < TRAIN"
                                    if name == "train.split"
                                    else under_train)) == 1, name
     assert not {s["name"] for s in spans} & set(RETIRED_SPANS)
-    # the plane goes up once, padded to the minibatch multiple on the host
+    # x is on the device when the trainer gets it: nn.h2d sends y and one
+    # member's train and validation weights, all f32, padded on the host
     (h2d,) = [s for s in spans if s["name"] == "nn.h2d"]
-    rows = len(plane["y"])
-    pad = -rows % batch
-    assert pad > 0                      # this set's row count is ragged
     assert h2d["attrs"]["pad_rows"] == pad
-    # x and y, and one member's train and validation weights, all f32
-    assert h2d["attrs"]["bytes"] == \
-        4 * (rows + pad) * (plane["x"].shape[1] + 3)
+    assert h2d["attrs"]["bytes"] == 4 * (rows + pad) * 3
 
     ep = [s for s in spans if s["name"] == "nn.epoch"]
     assert [s["attrs"]["epoch"] for s in ep] == list(range(epochs))
