@@ -394,7 +394,7 @@ def _dispatch(argv: Optional[List[str]] = None) -> int:
                                                   "monitor_aggregate",
                                                   None))
     if cmd == "serve":
-        from .models.tower_sdar import refuse_dir
+        from .models.towers import refuse_dir
         refuse_dir(args.dir, "serve")
         if getattr(args, "serve_replicas", 1) > 1:
             from .serve.router import run_fleet
@@ -422,7 +422,7 @@ def _dispatch(argv: Optional[List[str]] = None) -> int:
         from .pipeline.encode import EncodeProcessor
         return EncodeProcessor(args.dir, params=vars(args)).run()
     if cmd == "combo":
-        from .models.tower_sdar import refuse_dir
+        from .models.towers import refuse_dir
         from .pipeline.combo import run_combo
         refuse_dir(args.dir, "combo")
         return run_combo(args.dir, args.action, args.algs,

@@ -142,8 +142,9 @@ TRAIN_PARAM_RULES: Dict[str, Rule] = {
     "TFPSMemory": Rule("int", lo=1, algs=("TENSORFLOW",)),
     # the slot's own use: ``Tower`` names a deep tower the native path
     # trains itself (train/tower_trainer.py); ``TowerParams`` holds the
-    # tower's published config.json keys, checked by models/tower_sdar.py
-    "Tower": Rule("str", allowed=("sdar_moe",), algs=("TENSORFLOW",), native=True),
+    # tower's published config.json keys, checked by the tower's own module
+    # (models/towers.py TOWERS: tower_sdar.py, tower_nemotron_h.py)
+    "Tower": Rule("str", allowed=("sdar_moe", "nemotron_h"), algs=("TENSORFLOW",), native=True),
     "TowerParams": Rule("dict", algs=("TENSORFLOW",), native=True),
     # WDL family
     "EmbedColumnNum": Rule("int", lo=1, algs=("WDL",)),

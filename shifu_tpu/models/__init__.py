@@ -35,7 +35,7 @@ def load_any(path: str):
         from .wdl import IndependentWDLModel
         return IndependentWDLModel.load(path)
     if kind == "tower":
-        from .tower_sdar import IndependentTowerModel
+        from .towers import IndependentTowerModel
         return IndependentTowerModel.load(path)
     if kind == "svm":
         from .svm import IndependentSVMModel

@@ -15,14 +15,15 @@ ICLR 2025): the input is ``[x_t ; x_0]`` under :func:`block_mask`, the loss
 the 1/t-weighted cross-entropy of the masked positions.  Scoring is one
 denoising step of the last block: the tag's token masked, every feature
 clean, score = p(tag = 1) over the tag's two ids.
+
+The tokeniser, the ``.tower`` file, ``eval``'s scorer and the refusals are
+every tower's: :mod:`shifu_tpu.models.towers`.
 """
 
 from __future__ import annotations
 
-import json
 import math
-import os
-from dataclasses import asdict, dataclass, field
+from dataclasses import dataclass, field
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
@@ -32,8 +33,11 @@ import jax.numpy as jnp
 
 from ..config.errors import ErrorCode, ShifuError
 from ..ops import moe
+from .towers import RowTokens, load_model  # noqa: F401  (load_model: benchmark/drivers/train_tower.py)
 
-SPECIALS = ("TAG0", "TAG1", "MASK", "PAD")
+# the step's named scopes, most specific first: device ops carry them
+SCOPES = ("tower/attn", "tower/moe/route", "tower/moe/experts", "tower/head", "tower/opt")
+OBS_COUNTERS = {"masked": "tower.masked_positions"}
 T_MIN = 1e-3                        # per block t ~ U(T_MIN, 1]
 ATTN_ROWS = 4                       # rows whose f32 scores are alive at once
 _NEG = float(np.finfo(np.float32).min)
@@ -57,7 +61,7 @@ _INERT = ("intermediate_size", "max_window_layers")
 
 
 @dataclass
-class TowerSpec:
+class TowerSpec(RowTokens):
     hidden_size: int
     num_hidden_layers: int
     num_attention_heads: int
@@ -79,39 +83,6 @@ class TowerSpec:
     feature_names: List[str] = field(default_factory=list)
     tower: str = "sdar_moe"
     kind: str = "tower"
-
-    # ------------------------------------------------------------ tokens
-    @property
-    def n_features(self) -> int:
-        return len(self.column_bins)
-
-    @property
-    def feature_len(self) -> int:
-        """The feature tokens, padded to whole blocks."""
-        b = self.block_length
-        return -(-self.n_features // b) * b
-
-    @property
-    def seq_len(self) -> int:
-        return self.feature_len + self.block_length
-
-    @property
-    def n_ids(self) -> int:
-        return int(sum(b + 1 for b in self.column_bins)) + len(SPECIALS)
-
-    def special(self, name: str) -> int:
-        return self.n_ids - len(SPECIALS) + SPECIALS.index(name)
-
-    def offsets(self) -> np.ndarray:
-        sizes = np.asarray(self.column_bins, np.int64) + 1
-        return np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
-
-    def to_json(self) -> str:
-        return json.dumps(asdict(self))
-
-    @classmethod
-    def from_json(cls, text: str) -> "TowerSpec":
-        return cls(**json.loads(text))
 
 
 def spec_from_params(tower_params: Dict[str, Any], column_nums: List[int],
@@ -146,37 +117,10 @@ def spec_from_params(tower_params: Dict[str, Any], column_nums: List[int],
         problems.append("num_attention_heads must be a multiple of num_key_value_heads")
     if spec.head_dim % 2:
         problems.append("head_dim must be even (rotate-half)")
-    if spec.n_ids > spec.vocab_size:
-        # more ids than the slice holds is an error, never a clamp
-        problems.append(f"the plane's columns need {spec.n_ids} token ids "
-                        f"({spec.n_features} columns' bins + {len(SPECIALS)}), the "
-                        f"vocabulary slice holds {spec.vocab_size}")
-    if spec.seq_len > spec.max_position_embeddings:
-        problems.append(f"a row is {spec.seq_len} positions, max_position_embeddings "
-                        f"{spec.max_position_embeddings}")
+    problems += spec.token_problems()
     if problems:
         raise ShifuError(ErrorCode.ERROR_MODELCONFIG_NOT_VALIDATION, "; ".join(problems))
     return spec
-
-
-def tokenize(spec: TowerSpec, bins: np.ndarray, y: np.ndarray) -> np.ndarray:
-    """[n, C] bins + [n] targets -> [n, S] int32 ids."""
-    bins = np.asarray(bins)
-    n, c = bins.shape
-    if c != spec.n_features:
-        raise ValueError(f"the plane has {c} columns, the tower {spec.n_features}")
-    over = bins.max(axis=0, initial=0) > np.asarray(spec.column_bins)
-    if over.any():
-        j = int(np.flatnonzero(over)[0])
-        raise ShifuError(ErrorCode.ERROR_MODELCONFIG_NOT_VALIDATION,
-                         f"column {spec.column_nums[j] if spec.column_nums else j} holds bin "
-                         f"{int(bins[:, j].max())}, ColumnConfig gives it "
-                         f"{spec.column_bins[j]} value bins and the missing bin")
-    ids = np.full((n, spec.seq_len), spec.special("PAD"), np.int32)
-    ids[:, :c] = bins.astype(np.int32) + spec.offsets()[None, :]
-    ids[:, spec.feature_len] = np.where(np.asarray(y) > 0.5, spec.special("TAG1"),
-                                        spec.special("TAG0"))
-    return ids
 
 
 # ----------------------------------------------------------------- the masks
@@ -218,10 +162,6 @@ def init_params(key, spec: TowerSpec) -> Dict[str, Any]:
                   q_norm=jnp.ones((n, hd), jnp.float32), k_norm=jnp.ones((n, hd), jnp.float32))
     return {"embed": normal(keys[-2], (spec.vocab_size, d)), "layers": layers,
             "final_norm": jnp.ones((d,), jnp.float32), "head": normal(keys[-1], (d, spec.vocab_size))}
-
-
-def n_params(params) -> int:
-    return int(sum(np.prod(a.shape) for a in jax.tree_util.tree_leaves(params)))
 
 
 # ------------------------------------------------------------------- forward
@@ -350,6 +290,30 @@ def diffusion_loss(params, spec: TowerSpec, x0, t, masked, row_w, mask_id, pad_i
     return total / jnp.maximum(count, 1.0), aux
 
 
+def noise(key, rows: int, spec: TowerSpec):
+    """(t [rows, S], masked [rows, S]): per block t ~ U(T_MIN, 1], each
+    position of the block masked with probability t."""
+    kt, km = jax.random.split(key)
+    b = spec.block_length
+    t = jnp.repeat(jax.random.uniform(kt, (rows, spec.seq_len // b), jnp.float32,
+                                      T_MIN, 1.0), b, axis=1)
+    return t, jax.random.uniform(km, (rows, spec.seq_len), jnp.float32) < t
+
+
+def train_loss(params, spec: TowerSpec, x0, row_w, key, specials):
+    """The trainer's loss of one microbatch: :func:`diffusion_loss` under the
+    noise drawn from the step's key.  ``specials``: the ids of
+    :data:`.towers.SPECIALS`, as values."""
+    t, masked = noise(key, x0.shape[0], spec)
+    return diffusion_loss(params, spec, x0, t, masked, row_w, specials[2], specials[3])
+
+
+def counter_shapes(spec: TowerSpec) -> Dict[str, tuple]:
+    """``aux``'s counters beside ``loss_sum`` and ``positions``."""
+    return {"masked": (), "pairs": (spec.num_hidden_layers, spec.experts_held),
+            "dropped": (spec.num_hidden_layers,)}
+
+
 def tag_logits(params, spec: TowerSpec, feature_ids, tag0_id, mask_id):
     """One denoising step of the last block.  feature_ids [n, feature_len]
     clean -> [n, 2] logits of (TAG0, TAG1) at the tag's position."""
@@ -360,90 +324,3 @@ def tag_logits(params, spec: TowerSpec, feature_ids, tag0_id, mask_id):
     with jax.named_scope("tower/head"):
         two = jax.lax.dynamic_slice_in_dim(params["head"], tag0_id, 2, axis=1)
         return (h[:, spec.feature_len] @ two).astype(jnp.float32)
-
-
-# ------------------------------------------------------------- the model file
-def _flat(params) -> Dict[str, np.ndarray]:
-    out = {k: params[k] for k in ("embed", "final_norm", "head")}
-    out.update({"layers." + k: v for k, v in params["layers"].items()})
-    return out
-
-
-def save_model(path: str, spec: TowerSpec, params) -> int:
-    """Self-contained ``.tower`` file: an uncompressed npz of the f32 arrays +
-    the spec json, written beside the path and renamed into place (a
-    gigabyte-sized file is never buffered whole).  Returns its bytes."""
-    arrays = {k: np.asarray(v, np.float32) for k, v in _flat(params).items()}
-    arrays["__spec__"] = np.frombuffer(spec.to_json().encode(), dtype=np.uint8)
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    from ..ioutil import atomic_open
-    with atomic_open(path, "wb") as f:           # a temp file, renamed into place
-        np.savez(f, **arrays)  # shifu-lint: disable=atomic-write
-    return os.path.getsize(path)
-
-
-def load_model(path: str) -> Tuple[TowerSpec, Dict[str, Any]]:
-    data = np.load(path)
-    spec = TowerSpec.from_json(bytes(data["__spec__"]).decode())
-    params: Dict[str, Any] = {"layers": {}}
-    for name in data.files:
-        if name.startswith("layers."):
-            params["layers"][name[len("layers."):]] = data[name]
-        elif name != "__spec__":
-            params[name] = data[name]
-    return spec, params
-
-
-class IndependentTowerModel:
-    """Scores binned rows with a saved tower (``input_kind = 'bins'``)."""
-
-    input_kind = "bins"
-    SCORE_ROWS = 16                  # rows a scoring program takes
-
-    def __init__(self, spec: TowerSpec, params):
-        self.spec = spec
-        self.params = jax.device_put(params)
-        self._fwd = jax.jit(lambda p, ids, tag0, mask: tag_logits(p, spec, ids, tag0, mask))
-
-    @classmethod
-    def load(cls, path: str) -> "IndependentTowerModel":
-        return cls(*load_model(path))
-
-    @property
-    def max_bin_id(self) -> int:
-        """The largest bin id a column can carry (its missing bin): what
-        ``ops/tree_quant.ensemble_bins_dtype`` sizes the bins input by."""
-        return max(self.spec.column_bins, default=0)
-
-    def compute(self, bins) -> np.ndarray:
-        """[n, C] bins -> [n, 1] p(tag = 1) = sigmoid(logit_TAG1 - logit_TAG0)."""
-        spec = self.spec
-        ids = tokenize(spec, np.asarray(bins), np.zeros(len(bins)))[:, :spec.feature_len]
-        out = np.empty((len(ids), 1), np.float32)
-        tag0, mask = jnp.int32(spec.special("TAG0")), jnp.int32(spec.special("MASK"))
-        for a in range(0, len(ids), self.SCORE_ROWS):
-            part = ids[a: a + self.SCORE_ROWS]
-            pad = np.concatenate([part, np.repeat(part[-1:], self.SCORE_ROWS - len(part), 0)])
-            two = np.asarray(self._fwd(self.params, jnp.asarray(pad), tag0, mask))[:len(part)]
-            out[a: a + len(part), 0] = 1.0 / (1.0 + np.exp(-(two[:, 1] - two[:, 0]).astype(np.float64)))
-        return out
-
-
-def refuse_dir(model_set_dir: str, what: str) -> None:
-    """:func:`refuse` for an entry point that has only the directory."""
-    path = os.path.join(model_set_dir, "ModelConfig.json")
-    if os.path.isfile(path):
-        from ..config import ModelConfig
-        refuse(ModelConfig.load(path), what)
-
-
-def refuse(model_config, what: str) -> None:
-    """``export``, ``serve``, ``combo`` and ``varselect -wrapper`` have no
-    tower path yet: one coded error each, before anything is loaded."""
-    from ..config.model_config import Algorithm
-    tr = model_config.train
-    if tr.algorithm == Algorithm.TENSORFLOW and (tr.params or {}).get("Tower"):
-        raise ShifuError(ErrorCode.ERROR_UNSUPPORT_ALG,
-                         f"`{what}` cannot take a tower (train#params.Tower = "
-                         f"{tr.params['Tower']!r}): towers train and are scored by "
-                         "`eval`; use an NN, tree or WDL model set here")

@@ -80,8 +80,13 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
                                     "expert took"),
     "tower.dropped_pairs": ("counter", "pairs routed to a held expert that no "
                             "grouped product covered (must stay 0)"),
-    "tower.masked_positions": ("counter", "masked non-PAD positions trained on"),
-    "tower.positions": ("counter", "non-PAD positions of the training microbatches"),
+    "tower.masked_positions": ("counter", "masked non-PAD positions trained on (sdar_moe)"),
+    "tower.positions": ("counter", "positions of the training microbatches that carry a "
+                        "target (sdar_moe: the non-PAD ones)"),
+    "tower.mtp_loss_sum": ("counter", "the MTP module's cross-entropy summed over its "
+                           "targets, unscaled (nemotron_h)"),
+    "tower.ssm_chunks": ("counter", "chunks the Mamba-2 layers' scans ran: rows x layers "
+                         "x ceil(positions / chunk_size) (nemotron_h)"),
     "train.host_syncs": ("counter", "device->host value-forcing fetches"),
     "train.tail_sweeps": ("counter", "disk-tail re-streams paid"),
     "train.tail_repairs": ("counter", "c2f speculation repairs"),

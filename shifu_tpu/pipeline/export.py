@@ -23,7 +23,7 @@ class ExportProcessor(BasicProcessor):
     def process(self) -> int:
         t = (self.params.get("type") or "pmml").lower()
         if t not in ("columnstats", "woemapping", "woe", "corr"):
-            from ..models.tower_sdar import refuse
+            from ..models.towers import refuse
             refuse(self.model_config, "export")      # the model exports have no tower form
         os.makedirs(self.paths.export_dir, exist_ok=True)
         if t in ("pmml", "baggingpmml"):

@@ -192,7 +192,7 @@ class VarSelectProcessor(BasicProcessor):
         fb, alg = vs.filterBy, self.model_config.train.algorithm.name
         from ..config.validator import ValidationError
         if fb in (FilterBy.SE, FilterBy.ST):
-            from ..models.tower_sdar import refuse
+            from ..models.towers import refuse
             refuse(self.model_config, "varselect -wrapper")   # sensitivity re-scores an MLP
         if fb in (FilterBy.SE, FilterBy.ST) and \
                 alg not in ("NN", "LR", "SVM", "TENSORFLOW"):

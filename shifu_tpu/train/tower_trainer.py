@@ -1,6 +1,8 @@
 """Tower trainer — ``algorithm: TENSORFLOW`` with ``train#params.Tower``.
 
-Trains a :mod:`shifu_tpu.models.tower_sdar` tower over the binned plane
+Trains the tower ``train#params.Tower`` names (:mod:`shifu_tpu.models.towers`:
+its module gives the spec, the initial parameters, the loss with its counters,
+the scopes and the scorer) over the binned plane
 (``tmp/CleanedData``, the plane the tree trainers read): rows tokenised once,
 microbatches of ``MiniBatchs`` rows, one jitted step a microbatch (loss and
 gradients with each layer recomputed in the backward pass, then the
@@ -11,10 +13,12 @@ every ``CheckpointInterval`` epochs (``train/checkpoint.py``) and resume from
 the latest, bit-exactly: what an epoch does is a function of (seed, epoch,
 step) and the restored state alone.
 
-What the seed decides, restated by ``benchmark/reference/sdar_moe.py``: the
-order of an epoch's training rows is ``permutation(fold_in(fold_in(key,
-epoch), 0))``; step ``i`` draws its noise from ``fold_in(fold_in(key, epoch),
-1 + i)``: per block t ~ U(1e-3, 1], each position masked with probability t.
+What the seed decides, restated by the towers' references under
+``benchmark/reference/``: the order of an epoch's training rows is
+``permutation(fold_in(fold_in(key, epoch), 0))``; step ``i``'s loss gets the
+key ``fold_in(fold_in(key, epoch), 1 + i)`` (``sdar_moe`` draws its noise from
+it; ``nemotron_h`` draws nothing), a validation step ``fold_in(fold_in(key,
+VALID_FOLD), 1 + i)``.
 """
 
 from __future__ import annotations
@@ -32,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import faults, obs
-from ..models import tower_sdar as tower
+from ..models import towers
 from ..obs.costs import op_scopes
 from . import checkpoint as ckpt
 from .optimizers import make_optimizer, resolve_precision
@@ -40,8 +44,6 @@ from .optimizers import make_optimizer, resolve_precision
 log = logging.getLogger(__name__)
 
 DEFAULT_MICROBATCH = 16
-# the step's named scopes, most specific first: device ops carry them
-SCOPES = ("tower/attn", "tower/moe/route", "tower/moe/experts", "tower/head", "tower/opt")
 VALID_FOLD = 0x7FFFFFFF             # the validation noise's fold of the seed's key
 
 
@@ -62,16 +64,6 @@ def split_rows(n: int, valid_rate: float, seed: int) -> Tuple[np.ndarray, np.nda
     return np.sort(perm[n_valid:]), np.sort(perm[:n_valid])
 
 
-def noise(key, rows: int, spec: tower.TowerSpec):
-    """(t [rows, S], masked [rows, S]): per block t ~ U(T_MIN, 1], each
-    position of the block masked with probability t."""
-    kt, km = jax.random.split(key)
-    b = spec.block_length
-    t = jnp.repeat(jax.random.uniform(kt, (rows, spec.seq_len // b), jnp.float32,
-                                      tower.T_MIN, 1.0), b, axis=1)
-    return t, jax.random.uniform(km, (rows, spec.seq_len), jnp.float32) < t
-
-
 def _microbatches(rows: np.ndarray, mb: int) -> np.ndarray:
     """[steps, mb] row indices, the last step padded with -1."""
     steps = -(-len(rows) // mb)
@@ -80,19 +72,24 @@ def _microbatches(rows: np.ndarray, mb: int) -> np.ndarray:
     return out.reshape(steps, mb)
 
 
-def _zero_acc(spec: tower.TowerSpec) -> Dict[str, jnp.ndarray]:
+def _zero_acc(spec) -> Dict[str, jnp.ndarray]:
+    """The epoch's accumulators: the loss's sums, training and validation,
+    and the tower's own counters."""
+    tower = towers.module(spec.tower)
     f32 = lambda *shape: jnp.zeros(shape, jnp.float32)
-    return {"loss_sum": f32(), "positions": f32(), "masked": f32(),
-            "valid_loss_sum": f32(), "valid_positions": f32(),
-            "pairs": f32(spec.num_hidden_layers, spec.experts_held), "dropped": f32()}
+    return {"loss_sum": f32(), "positions": f32(), "valid_loss_sum": f32(),
+            "valid_positions": f32(),
+            **{k: f32(*shape) for k, shape in tower.counter_shapes(spec).items()}}
 
 
-def build_programs(spec: tower.TowerSpec, opt, mb: int):
+def build_programs(spec, opt, mb: int):
     """(step, valid_step): the two programs an epoch launches.  State and
     accumulators are donated: 16 bytes a parameter, updated in place.  A
     step takes its microbatch's row indices (-1 = padding), so the programs
     depend on the plane's rows and the microbatch, not on how many steps an
     epoch has."""
+    tower = towers.module(spec.tower)
+    counters = tuple(tower.counter_shapes(spec))
 
     def gather(ids, w, rows):
         keep = rows >= 0
@@ -102,24 +99,20 @@ def build_programs(spec: tower.TowerSpec, opt, mb: int):
     @partial(obs.costed_jit, "tower.step", donate_argnums=(0, 1, 2))
     def tower_step(params, opt_state, acc, ids, w, rows, key, specials, epoch, i):
         x0, row_w = gather(ids, w, rows)
-        t, masked = noise(jax.random.fold_in(jax.random.fold_in(key, epoch), 1 + i), mb, spec)
-        (_, aux), grads = jax.value_and_grad(tower.diffusion_loss, has_aux=True)(
-            params, spec, x0, t, masked, row_w, specials[2], specials[3])
+        step_key = jax.random.fold_in(jax.random.fold_in(key, epoch), 1 + i)
+        (_, aux), grads = jax.value_and_grad(tower.train_loss, has_aux=True)(
+            params, spec, x0, row_w, step_key, specials)
         with jax.named_scope("tower/opt"):
             delta, opt_state = opt.update(grads, opt_state, params)
             params = jax.tree_util.tree_map(jnp.add, params, delta)
-        acc = {**acc, "loss_sum": acc["loss_sum"] + aux["loss_sum"],
-               "positions": acc["positions"] + aux["positions"],
-               "masked": acc["masked"] + aux["masked"],
-               "pairs": acc["pairs"] + aux["pairs"],
-               "dropped": acc["dropped"] + jnp.sum(aux["dropped"])}
+        acc = {**acc, **{k: acc[k] + aux[k] for k in ("loss_sum", "positions") + counters}}
         return params, opt_state, acc
 
     @partial(obs.costed_jit, "tower.valid_step", donate_argnums=(1,))
     def tower_valid_step(params, acc, ids, w, rows, key, specials, i):
         x0, row_w = gather(ids, w, rows)
-        t, masked = noise(jax.random.fold_in(jax.random.fold_in(key, VALID_FOLD), 1 + i), mb, spec)
-        _, aux = tower.diffusion_loss(params, spec, x0, t, masked, row_w, specials[2], specials[3])
+        step_key = jax.random.fold_in(jax.random.fold_in(key, VALID_FOLD), 1 + i)
+        _, aux = tower.train_loss(params, spec, x0, row_w, step_key, specials)
         return {**acc, "valid_loss_sum": acc["valid_loss_sum"] + aux["loss_sum"],
                 "valid_positions": acc["valid_positions"] + aux["positions"]}
 
@@ -130,14 +123,15 @@ def _nbytes(tree) -> int:
     return int(sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree)))
 
 
-def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec: tower.TowerSpec,
-                settings, valid_rate: float,
+def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
+                valid_rate: float,
                 progress: Optional[Callable[[int, float, float], None]] = None) -> TowerResult:
+    tower = towers.module(spec.tower)
     precision = resolve_precision(settings.precision)
     if precision != "f32":
         raise ValueError(f"a tower trains under shifu.train.precision=f32; got {precision!r}")
     with obs.span("tower.tokenize", rows=len(y), ids=spec.n_ids):
-        ids = tower.tokenize(spec, bins, y)
+        ids = towers.tokenize(spec, bins, y)
         train_rows, valid_rows = split_rows(len(y), valid_rate, settings.seed)
     mb = min(settings.batch_size or DEFAULT_MICROBATCH, max(len(train_rows), 1))
     valid_order = _microbatches(valid_rows, mb)
@@ -163,9 +157,9 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec: tower.Towe
         ids_d, w_d = jax.device_put((ids, np.asarray(w, np.float32)))
         # the special ids follow the columns' bins: an argument, so that
         # another table's job finds these programs in the compile cache
-        specials = jnp.asarray([spec.special(n) for n in tower.SPECIALS], jnp.int32)
+        specials = jnp.asarray([spec.special(n) for n in towers.SPECIALS], jnp.int32)
         tower_step, tower_valid_step = build_programs(spec, opt, mb)
-        n_par = tower.n_params(params)
+        n_par = towers.n_params(params)
         sp.set(params=n_par, bytes=_nbytes(params) + _nbytes(opt_state))
     log.info("tower %s: %d rows x %d positions (%d train, %d validation), %d parameters, "
              "microbatches of %d rows, %d steps an epoch", spec.tower, len(y), spec.seq_len,
@@ -200,7 +194,7 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec: tower.Towe
                 hlo = tower_step.hlo_text()
                 if hlo:
                     obs.event("op_scopes", program="tower_step",
-                              scopes=op_scopes(hlo, SCOPES))
+                              scopes=op_scopes(hlo, tower.SCOPES))
             tr = float(got["loss_sum"] / max(got["positions"], 1.0))
             va = float(got["valid_loss_sum"] / got["valid_positions"]) \
                 if got["valid_positions"] > 0 else 0.0
@@ -213,14 +207,15 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec: tower.Towe
                 obs.gauge("train.valid_err").set(va)
                 obs.counter("tower.moe_pairs_max_expert").inc(float(pairs.max()))
                 obs.counter("tower.moe_pairs_mean_expert").inc(float(pairs.mean()))
-                obs.counter("tower.dropped_pairs").inc(float(got["dropped"]))
-                obs.counter("tower.masked_positions").inc(float(got["masked"]))
+                obs.counter("tower.dropped_pairs").inc(float(got["dropped"].sum()))
                 obs.counter("tower.positions").inc(float(got["positions"]))
+                for k, name in tower.OBS_COUNTERS.items():
+                    obs.counter(name).inc(float(got[k]))
                 obs.event("epoch", trainer="tower", epoch=epoch, train_err=round(tr, 6),
                           valid_err=round(va, 6), rows=len(train_rows),
                           rows_per_sec=round(len(train_rows) / max(dt, 1e-9), 1))
-            if got["dropped"] != 0:
-                raise RuntimeError(f"tower: {int(got['dropped'])} routed pairs were dropped "
+            if got["dropped"].any():
+                raise RuntimeError(f"tower: {int(got['dropped'].sum())} routed pairs were dropped "
                                    f"in epoch {epoch + 1}")
             if progress:
                 progress(epoch, tr, va)
@@ -251,7 +246,7 @@ def run_tower_training(proc) -> int:
         data = shards.load_all()
     col_nums = list(shards.schema.get("columnNums", []))
     by_num = {c.columnNum: c for c in proc.column_configs}
-    spec = tower.spec_from_params(
+    spec = towers.module(str(p["Tower"])).spec_from_params(
         p.get("TowerParams"), col_nums, [by_num[cn].num_bins() for cn in col_nums],
         [by_num[cn].columnName for cn in col_nums])
     settings = settings_from_params(p, mc.train, defaults={"Propagation": "ADAM",
@@ -282,7 +277,7 @@ def run_tower_training(proc) -> int:
             if f.startswith("model"):
                 os.remove(os.path.join(proc.paths.models_dir, f))
         path = proc.paths.model_path(0, "tower")
-        sp.set(bytes=tower.save_model(path, spec, jax.device_get(res.params)))
+        sp.set(bytes=towers.save_model(path, spec, jax.device_get(res.params)))
     log.info("train tower done: %s, train error %.6f, validation error %.6f (%d epochs)",
              path, res.train_error, res.valid_error, res.epochs_run)
     return 0

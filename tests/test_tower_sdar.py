@@ -18,8 +18,8 @@ from shifu_tpu import faults, obs
 from shifu_tpu.config import ModelConfig, environment
 from shifu_tpu.config.errors import ShifuError
 from shifu_tpu.models import tower_sdar as tw
+from shifu_tpu.models import towers
 from shifu_tpu.ops import moe
-from shifu_tpu.train import tower_trainer as tt
 
 COL_BINS = [10, 11, 9, 12, 10, 11, 10, 10]          # 91 ids + 4 specials = 95 <= 97
 TOY = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
@@ -69,8 +69,8 @@ def case():
         spec = _spec(rank)
         params = _params(spec)
         bins, y = _rows()
-        ids = tw.tokenize(spec, bins, y)
-        t, masked = tt.noise(jax.random.PRNGKey(5), len(y), spec)
+        ids = towers.tokenize(spec, bins, y)
+        t, masked = tw.noise(jax.random.PRNGKey(5), len(y), spec)
         row_w = jnp.ones(len(y), jnp.float32)
         fn = jax.jit(jax.value_and_grad(
             lambda p: tw.diffusion_loss(p, spec, jnp.asarray(ids), t, masked, row_w,
@@ -88,14 +88,14 @@ def case():
 def test_tokeniser_ids_match_the_reference_and_its_layout():
     spec = _spec()
     bins, y = _rows(5)
-    ids = tw.tokenize(spec, bins, y)
+    ids = towers.tokenize(spec, bins, y)
     assert ids.shape == (5, 12) and ids.dtype == np.int32
     assert (ids == ref.rows_to_ids(bins, y, COL_BINS, 4)).all()
     off = np.concatenate([[0], np.cumsum(np.asarray(COL_BINS) + 1)[:-1]])
     assert (ids[:, :8] == bins + off).all()
     assert (ids[:, 8] == np.where(y > 0.5, spec.special("TAG1"), spec.special("TAG0"))).all()
     assert (ids[:, 9:] == spec.special("PAD")).all()
-    assert [spec.special(n) for n in tw.SPECIALS] == [91, 92, 93, 94] and spec.n_ids == 95
+    assert [spec.special(n) for n in towers.SPECIALS] == [91, 92, 93, 94] and spec.n_ids == 95
 
 
 def test_more_ids_than_the_slice_holds_is_an_error_never_a_clamp():
@@ -105,14 +105,14 @@ def test_more_ids_than_the_slice_holds_is_an_error_never_a_clamp():
     bins, y = _rows(3)
     bins[1, 2] = COL_BINS[2] + 1                    # past the column's missing bin
     with pytest.raises(ShifuError, match="holds bin 10"):
-        tw.tokenize(spec, bins, y)
+        towers.tokenize(spec, bins, y)
 
 
 def test_feature_tokens_pad_to_whole_blocks():
     spec = tw.spec_from_params({**TOY, "max_position_embeddings": 16}, list(range(6)),
                                COL_BINS[:6], [f"c{i}" for i in range(6)])
     assert (spec.feature_len, spec.seq_len) == (8, 12)
-    ids = tw.tokenize(spec, _rows(2)[0][:, :6], np.array([1.0, 0.0]))
+    ids = towers.tokenize(spec, _rows(2)[0][:, :6], np.array([1.0, 0.0]))
     assert (ids[:, 6:8] == spec.special("PAD")).all() and ids[0, 8] == spec.special("TAG1")
 
 
@@ -172,7 +172,7 @@ def test_gradient_matches_the_reference(case, rank, leaf):
 
 def test_eval_score_is_one_denoising_step_of_the_tag_block(case):
     c = case[1]
-    got = tw.IndependentTowerModel(c["spec"], c["params"]).compute(c["bins"])[:, 0]
+    got = towers.IndependentTowerModel(c["spec"], c["params"]).compute(c["bins"])[:, 0]
     d = ref.tag_logit_difference(_np(c["params"]), c["bins"], TOY, c["spec"].expert_lo, COL_BINS, 4)
     np.testing.assert_allclose(got, 1.0 / (1.0 + np.exp(-d)), atol=1e-6)
 
@@ -304,7 +304,7 @@ def test_op_scopes_reads_named_scopes_from_the_compiled_program():
         with jax.named_scope("tower/opt"):
             return jnp.sum(jnp.exp(c))
     x = jnp.ones((8, 8), jnp.float32)
-    table = op_scopes(jax.jit(jax.grad(f)).lower(x, x).compile().as_text(), tt.SCOPES)
+    table = op_scopes(jax.jit(jax.grad(f)).lower(x, x).compile().as_text(), tw.SCOPES)
     assert table["tower/attn"] and table["tower/opt"] and not table["tower/head"]
     assert not set(table["tower/attn"]) & set(table["tower/opt"])
 
@@ -332,7 +332,7 @@ def _clean():
 
 
 def _load(mdir):
-    return tw.load_model(os.path.join(mdir, "models", "model0.tower"))
+    return towers.load_model(os.path.join(mdir, "models", "model0.tower"))
 
 
 def _progress(mdir):
