@@ -422,8 +422,7 @@ def causal_loss(params, spec: TowerSpec, ids, row_w):
     chunks = -(-(s - 1) // spec.chunk_size) * spec.hybrid_override_pattern.count("M")
     aux = {"loss_sum": total * (s - 1), "positions": rows * (s - 1),
            "mtp_loss_sum": mtp * (s - 2), "ssm_chunks": jnp.sum(row_w > 0).astype(jnp.float32) * chunks,
-           "pairs": jnp.stack([c["pairs"] for c in found]),
-           "dropped": jnp.stack([c["dropped"] for c in found])}
+           **{k: jnp.stack([c[k] for c in found]) for k in ("pairs", "rows", "dropped")}}
     return total / jnp.maximum(rows, 1.0), aux
 
 
@@ -435,7 +434,7 @@ def train_loss(params, spec: TowerSpec, ids, row_w, key, specials):
 def counter_shapes(spec: TowerSpec) -> Dict[str, tuple]:
     """``aux``'s counters beside ``loss_sum`` and ``positions``."""
     return {"mtp_loss_sum": (), "ssm_chunks": (), "pairs": (spec.moe_layers, spec.experts_held),
-            "dropped": (spec.moe_layers,)}
+            "rows": (spec.moe_layers,), "dropped": (spec.moe_layers,)}
 
 
 def tag_logits(params, spec: TowerSpec, feature_ids, tag0_id, mask_id):

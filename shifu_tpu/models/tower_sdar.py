@@ -311,7 +311,7 @@ def train_loss(params, spec: TowerSpec, x0, row_w, key, specials):
 def counter_shapes(spec: TowerSpec) -> Dict[str, tuple]:
     """``aux``'s counters beside ``loss_sum`` and ``positions``."""
     return {"masked": (), "pairs": (spec.num_hidden_layers, spec.experts_held),
-            "dropped": (spec.num_hidden_layers,)}
+            "rows": (spec.num_hidden_layers,), "dropped": (spec.num_hidden_layers,)}
 
 
 def tag_logits(params, spec: TowerSpec, feature_ids, tag0_id, mask_id):

@@ -78,6 +78,9 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
                                    "pairs one held expert of one layer took"),
     "tower.moe_pairs_mean_expert": ("counter", "per epoch, the mean pairs a held "
                                     "expert took"),
+    "tower.moe_rows_computed": ("counter", "buffer rows the held experts' chunk walks ran "
+                                "(chunks run x a chunk's rows, all layers); routed pairs over it = "
+                                "the walk's occupancy"),
     "tower.dropped_pairs": ("counter", "pairs routed to a held expert that no "
                             "grouped product covered (must stay 0)"),
     "tower.masked_positions": ("counter", "masked non-PAD positions trained on (sdar_moe)"),

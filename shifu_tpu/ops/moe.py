@@ -4,27 +4,38 @@ The rank is told which experts it holds (``lo .. lo + held``), routes every
 token over ALL experts (:func:`route`: a softmax router, or sigmoid scores
 with a selection bias; top-k, renormalised), and computes its own experts'
 part of the result (:func:`held_experts_ffn`: SwiGLU experts, or two matrices
-around relu^2 — one sort, one set of grouped products, one backward): the (token, choice) pairs whose expert is
-held are sorted by expert, pushed through grouped matrix products
-(``jax.lax.ragged_dot``: one row group an expert, the TPU's grouped-matmul
-kernel, which skips the rows past the groups) and gathered back to their
-tokens with their router weights.  What the absent experts would add is left
-out — there is no stand-in for the other ranks or their exchange.
+around relu^2 — one sort, one walk, one backward).  What the absent experts
+would add is left out — there is no stand-in for the other ranks or their
+exchange.
 
-No pair is ever dropped: the pair buffer has one slot for every (token,
-choice) that can be routed here — a token's choices are distinct experts, so
-at most ``min(k, held)`` of them — so the worst imbalance — every token on one
-expert, every choice held — still fits.  ``dropped`` in the counters is measured pair by pair
-(:func:`covered_pairs`): a pair counts as covered when the buffer row it is
-read back from lies inside the row group of its own expert, which is what a
-tighter buffer or clipped group sizes would break.
+The walk.  The (token, choice) pairs are sorted by expert, the absent
+experts' last, and the sorted list is walked in chunks of T rows (T = the
+token count, rounded up to :data:`ROW_TILE`): a chunk gathers its pairs' tokens, pushes them through grouped
+matrix products (``jax.lax.ragged_dot``: one row group an expert, the TPU's
+grouped-matmul kernel) with the group sizes cut to the chunk, weighs each row
+with its router weight and scatter-adds it onto its token.  The trip count is
+``ceil(pairs routed here / T)``, read on the device from the group sizes: the
+work follows the pairs the router sent, not the worst case, and only ``[T, k]``
+scalars ever exist in (token, choice) layout.  The backward is the same walk
+(``custom_vjp``: autodiff would hand the cotangents back in the operands'
+dtype, and cannot transpose a loop whose length is a device value); weight
+gradients add up in the loop's carry.  ``rows`` in the counters is chunks run
+x their rows: pairs over rows is how full the walk's chunks were.
+
+No pair is ever dropped and there is no capacity: a token's choices are
+distinct experts, so at most ``min(k, held)`` of them are routed here, and the
+sorted list has ``min(k, held)`` chunks — the worst imbalance, every token on
+one expert and every choice held, runs them all.  ``dropped`` in the counters
+is measured pair by pair (:func:`covered_pairs`): a pair counts as covered
+when the expert whose row group its buffer row lies in — by its chunk's group
+sizes, which is all the kernel sees — is the expert the router chose, which is
+what a tighter buffer or clipped group sizes would break.
 
 Precision: matmul operands are rounded once to :func:`mxu_operand_dtype`
 (bfloat16 on the TPU — what its default precision does to f32 operands anyway,
-at half the bytes through the gathers; the input's own dtype elsewhere), every product
-accumulates and leaves in f32, forward and backward; the backward is written
-out (``custom_vjp``) because autodiff would hand the cotangents back in the
-operands' dtype.
+at half the bytes through the gathers; the input's own dtype elsewhere), every
+product accumulates and leaves in f32, forward and backward, and the router
+weights and the sums over a token's pairs are f32.
 """
 
 from __future__ import annotations
@@ -40,6 +51,13 @@ from jax.lax import RaggedDotDimensionNumbers
 _CONTRACT_PAIRS = RaggedDotDimensionNumbers(
     dot_dimension_numbers=(([0], [0]), ([], [])),
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+# a chunk's rows are the token count rounded up to this: the grouped-matmul
+# kernel tiles its rows by the largest power of two that divides them, and at
+# 3,448 rows (8 x 431: nemotron_h's MTP layer) one product took 6.4 ms where
+# 3,456 rows take 0.5 (PERF.md section 6, PR 32)
+ROW_TILE = 128
 
 
 def mxu_operand_dtype(like):
@@ -105,57 +123,104 @@ def _relu2_bwd(a, dh):
 ACTS = {"swiglu": (_swiglu, _swiglu_bwd), "relu2": (_relu2, _relu2_bwd)}
 
 
-def _gather_pairs(rows, slot, is_held):
-    """rows [P, D] in sorted-pair order -> [T, k, D] by (token, choice); the
-    slots of absent experts (rows past the groups: whatever the kernel left)
-    read as 0."""
-    t, k = is_held.shape
-    return jnp.where(is_held[..., None], rows[slot].reshape(t, k, -1), 0.0)
+def chunk_sizes(group_sizes: jnp.ndarray, n_chunks: int, rows: int) -> jnp.ndarray:
+    """[n_chunks, held]: how many rows of each expert's group lie in each
+    chunk of ``rows`` buffer rows — the group sizes a chunk's grouped products
+    are given.  Chunks past the groups read all 0."""
+    ends = jnp.cumsum(group_sizes)
+    r0 = (jnp.arange(n_chunks, dtype=ends.dtype) * rows)[:, None]
+    return (jnp.clip(ends - r0, 0, rows)
+            - jnp.clip(ends - group_sizes - r0, 0, rows)).astype(jnp.int32)
+
+
+def _chunks_run(sizes, rows):
+    """Chunks that hold a routed pair: the walks' trip count, a device value."""
+    return (jnp.sum(sizes) + rows - 1) // rows
+
+
+def _chunk(c, k, sizes, order, w_flat):
+    """Chunk ``c`` of the sorted pair list: (first row, pair ids [C], their
+    tokens, the chunk's group sizes, which rows a group filled, the rows'
+    router weights with the unfilled rows' at 0)."""
+    n_rows = order.shape[0] // sizes.shape[0]
+    r0 = c * n_rows
+    rows = jax.lax.dynamic_slice(order, (r0,), (n_rows,))
+    gs = sizes[c]
+    filled = jnp.arange(n_rows) < jnp.sum(gs)
+    return r0, rows, rows // k, gs, filled, jnp.where(filled, w_flat[rows], 0.0)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _ffn(k, dt, act, x, weights, w_gate_up, w_down, group_sizes, order, slot, is_held):
-    return _ffn_fwd(k, dt, act, x, weights, w_gate_up, w_down, group_sizes, order, slot, is_held)[0]
+def _ffn(k, dt, act, x, w_flat, w_gate_up, w_down, sizes, order):
+    return _ffn_fwd(k, dt, act, x, w_flat, w_gate_up, w_down, sizes, order)[0]
 
 
-def _ffn_fwd(k, dt, act, x, weights, w_gate_up, w_down, group_sizes, order, slot, is_held):
-    xs = x.astype(dt)[order // k]                                   # [P, D]
-    gu = _grouped(xs, w_gate_up.astype(dt), group_sizes)            # [P, 2F] (or [P, F]) f32
-    ys = _grouped(ACTS[act][0](gu).astype(dt), w_down.astype(dt), group_sizes)   # [P, D] f32
-    y_pairs = _gather_pairs(ys, slot, is_held)                      # [T, k, D]
-    y = jnp.sum(y_pairs * weights[..., None], axis=1)
-    return y, (xs, gu, y_pairs, weights, w_gate_up, w_down, group_sizes, order, slot, is_held)
+def _ffn_fwd(k, dt, act, x, w_flat, w_gate_up, w_down, sizes, order):
+    n_rows = order.shape[0] // sizes.shape[0]                       # of a chunk
+    xd, wgu, wd = x.astype(dt), w_gate_up.astype(dt), w_down.astype(dt)   # once, not a chunk
+
+    def body(c, carry):
+        y, gu_all = carry
+        r0, _, tok, gs, filled, wr = _chunk(c, k, sizes, order, w_flat)
+        gu = _grouped(xd[tok], wgu, gs)                             # [C, 2F] (or [C, F]) f32
+        ys = _grouped(ACTS[act][0](gu).astype(dt), wd, gs)          # [C, D] f32
+        # rows past the groups hold whatever the kernel left: they add 0
+        y = y.at[tok].add(jnp.where(filled[:, None], ys, 0.0) * wr[:, None])
+        return y, jax.lax.dynamic_update_slice(gu_all, gu, (r0, 0))
+
+    y, gu_all = jax.lax.fori_loop(
+        0, _chunks_run(sizes, n_rows), body,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros((order.shape[0], w_gate_up.shape[2]), jnp.float32)))
+    return y, (x, gu_all, w_flat, w_gate_up, w_down, sizes, order)
 
 
 def _ffn_bwd(k, dt, act, res, dy):
-    xs, gu, y_pairs, weights, w_gate_up, w_down, group_sizes, order, slot, is_held = res
-    d_weights = jnp.sum(y_pairs * dy[:, None, :], axis=-1)
-    w_sorted = weights.reshape(-1)[order][:, None]                  # this pair's weight
-    dys = dy.astype(dt)[order // k]                                 # [P, D]; the weight goes on after
-    h = ACTS[act][0](gu)
-    d_w_down = _grouped_outer((h * w_sorted).astype(dt), dys, group_sizes)
-    dh = w_sorted * _grouped(dys, jnp.swapaxes(w_down, 1, 2).astype(dt), group_sizes)
-    dgu = ACTS[act][1](gu, dh).astype(dt)
-    d_w_gate_up = _grouped_outer(xs, dgu, group_sizes)
-    dxs = _grouped(dgu, jnp.swapaxes(w_gate_up, 1, 2).astype(dt), group_sizes)
-    dx = jnp.sum(_gather_pairs(dxs, slot, is_held), axis=1)
-    return (dx.astype(dy.dtype), d_weights, d_w_gate_up.astype(w_gate_up.dtype),
-            d_w_down.astype(w_down.dtype), None, None, None, None)
+    x, gu_all, w_flat, w_gate_up, w_down, sizes, order = res
+    n_rows = order.shape[0] // sizes.shape[0]
+    xd, dyd = x.astype(dt), dy.astype(dt)
+    wd_t, wgu_t = jnp.swapaxes(w_down, 1, 2).astype(dt), jnp.swapaxes(w_gate_up, 1, 2).astype(dt)
+
+    def body(c, carry):
+        dx, d_w, d_wgu, d_wd = carry
+        r0, rows, tok, gs, filled, wr = _chunk(c, k, sizes, order, w_flat)
+        wr = wr[:, None]
+        gu = jax.lax.dynamic_slice(gu_all, (r0, 0), (n_rows, gu_all.shape[1]))
+        h = ACTS[act][0](gu)
+        dys = dyd[tok]                                              # the weight goes on after
+        dh = _grouped(dys, wd_t, gs)                                # [C, F] f32
+        # the pair's output . dy, as h . (W_down dy): no third product, no f32 rows of dy
+        d_w = d_w.at[rows].set(jnp.where(filled, jnp.sum(h * dh, axis=-1), 0.0),
+                               unique_indices=True)
+        d_wd = d_wd + _grouped_outer((h * wr).astype(dt), dys, gs)
+        dgu = ACTS[act][1](gu, wr * dh).astype(dt)
+        d_wgu = d_wgu + _grouped_outer(xd[tok], dgu, gs)
+        dxs = _grouped(dgu, wgu_t, gs)
+        return dx.at[tok].add(jnp.where(filled[:, None], dxs, 0.0)), d_w, d_wgu, d_wd
+
+    f32 = lambda like: jnp.zeros(like.shape, jnp.float32)
+    dx, d_w, d_wgu, d_wd = jax.lax.fori_loop(
+        0, _chunks_run(sizes, n_rows), body, (f32(x), f32(w_flat), f32(w_gate_up), f32(w_down)))
+    return (dx.astype(dy.dtype), d_w, d_wgu.astype(w_gate_up.dtype), d_wd.astype(w_down.dtype),
+            None, None)
 
 
 _ffn.defvjp(_ffn_fwd, _ffn_bwd)
 
 
-def covered_pairs(slot, local, is_held, group_sizes) -> jnp.ndarray:
+def covered_pairs(chosen, sizes) -> jnp.ndarray:
     """How many of the pairs routed to a held expert the grouped products
-    really computed with that expert's weights: the pair's buffer row
-    (``slot``, from the sort) must lie inside its expert's row group (from
-    ``group_sizes``, which is all the kernel sees).  slot/local/is_held
-    [T, k]; group_sizes [held]."""
-    ends = jnp.cumsum(group_sizes)
-    e = jnp.clip(local, 0, group_sizes.shape[0] - 1)
-    inside = (slot >= (ends - group_sizes)[e]) & (slot < ends[e])
-    return jnp.sum(is_held & inside)
+    really compute with that expert's weights: the expert whose group the
+    pair's buffer row lies in — by its chunk's group sizes, which is all the
+    kernel sees — must be the expert the router chose.  chosen [P]: the
+    chosen expert of the pair in each buffer row, from the first held one
+    (an absent expert: any value outside ``0 .. held``); sizes [n_chunks,
+    held] (:func:`chunk_sizes`)."""
+    n_chunks, held = sizes.shape
+    at = jnp.arange(chosen.shape[0] // n_chunks)
+    # groups that end at or before the row; held = past the chunk's groups: no pair's expert
+    lands_in = jnp.sum(at[None, :, None] >= jnp.cumsum(sizes, axis=1)[:, None, :], axis=-1)
+    return jnp.sum((lands_in < held) & (lands_in == chosen.reshape(n_chunks, -1)))
 
 
 def held_experts_ffn(x: jnp.ndarray, weights: jnp.ndarray, experts: jnp.ndarray,
@@ -167,27 +232,30 @@ def held_experts_ffn(x: jnp.ndarray, weights: jnp.ndarray, experts: jnp.ndarray,
     [held, D, 2F] (gate then up; [held, D, F] under ``act="relu2"``: one
     matrix, no gate), ``w_down`` [held, F, D]; the rank holds experts ``lo ..
     lo + held``.  Returns (y [T, D] f32, counters): ``pairs`` [held] pairs
-    per held expert, ``dropped`` pairs routed here less :func:`covered_pairs`."""
+    per held expert, ``rows`` buffer rows the walk computed (chunks run x
+    a chunk's rows), ``dropped`` pairs routed here less :func:`covered_pairs`."""
     n_tok, k = experts.shape
     held = w_gate_up.shape[0]
+    n_chunks = min(k, held)
+    n_rows = -(-n_tok // ROW_TILE) * ROW_TILE                 # of a chunk
     dt = mxu_operand_dtype(x)
     local = experts - lo
     is_held = (local >= 0) & (local < held)
     key = jnp.where(is_held, local, held).reshape(-1)         # absent experts sort last
-    order = jnp.argsort(key, stable=True)                     # pair ids, by expert
-    if held < k:
-        # a token's choices are distinct: at most ``held`` of its k are routed
-        # here, and they sort first, so the buffer needs no more rows
-        order = order[:n_tok * held]
-    slot = jnp.zeros(n_tok * k, order.dtype).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype)).reshape(n_tok, k)
-    # absent experts' pairs all read row 0 (and are masked): the gathers back
-    # to (token, choice) then touch only the rows the groups filled
-    slot = jnp.where(is_held, slot, 0)
-    group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    y = _ffn(k, dt, act, x, jnp.where(is_held, weights, 0.0), w_gate_up, w_down, group_sizes,
-             order, slot, is_held)
+    # the pairs by expert.  A token's choices are distinct: at most ``held`` of
+    # its k are routed here, and they sort first, so the buffer needs no more rows
+    chosen, order = jax.lax.sort_key_val(key, jnp.arange(key.shape[0], dtype=jnp.int32))
+    # rows added by the rounding belong to no pair: ids past the last one, each
+    # its own, which a gather clamps and a scatter leaves out
+    pad = n_chunks * (n_rows - n_tok)
+    chosen = jnp.pad(chosen[:n_chunks * n_tok], (0, pad), constant_values=held)
+    order = jnp.concatenate([order[:n_chunks * n_tok],
+                             key.shape[0] + jnp.arange(pad, dtype=order.dtype)])
+    group_sizes = jnp.diff(jnp.searchsorted(chosen, jnp.arange(held + 1))).astype(jnp.int32)
+    sizes = chunk_sizes(group_sizes, n_chunks, n_rows)
+    y = _ffn(k, dt, act, x, jnp.where(is_held, weights, 0.0).reshape(-1), w_gate_up, w_down,
+             sizes, order)
     counters = {"pairs": group_sizes,
-                "dropped": (jnp.sum(is_held) - covered_pairs(slot, local, is_held, group_sizes)
-                            ).astype(jnp.int32)}
+                "rows": (_chunks_run(sizes, n_rows) * n_rows).astype(jnp.int32),
+                "dropped": (jnp.sum(is_held) - covered_pairs(chosen, sizes)).astype(jnp.int32)}
     return y, counters

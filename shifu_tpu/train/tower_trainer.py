@@ -207,6 +207,7 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
                 obs.gauge("train.valid_err").set(va)
                 obs.counter("tower.moe_pairs_max_expert").inc(float(pairs.max()))
                 obs.counter("tower.moe_pairs_mean_expert").inc(float(pairs.mean()))
+                obs.counter("tower.moe_rows_computed").inc(float(got["rows"].sum()))
                 obs.counter("tower.dropped_pairs").inc(float(got["dropped"].sum()))
                 obs.counter("tower.positions").inc(float(got["positions"]))
                 for k, name in tower.OBS_COUNTERS.items():

@@ -8,6 +8,7 @@ an MTP module ``*E``; 97 ids, 9 positions), on the CPU.
 
 import os
 import shutil
+import sys
 
 import numpy as np
 import pytest
@@ -24,6 +25,9 @@ from shifu_tpu.models import towers
 from shifu_tpu.ops import moe
 from shifu_tpu.train import tower_trainer as tt
 from shifu_tpu.train.optimizers import make_optimizer
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "helpers"))
+import moe_loads  # noqa: E402
 
 COL_BINS = [10, 11, 9, 12, 10, 11, 10, 10]          # 91 ids + 4 specials = 95 <= 97
 TOY = dict(model_type="nemotron_h", hidden_size=64, num_hidden_layers=3,
@@ -345,9 +349,11 @@ def test_top_22_routing_selects_by_score_plus_bias_and_weighs_by_score():
     assert not np.asarray(gb).any() and np.asarray(gw).any()
 
 
-def test_no_pair_dropped_when_every_token_chooses_every_held_expert():
+def test_no_pair_dropped_when_every_token_chooses_every_held_expert(monkeypatch):
     """k = 6 over 16 experts with 4 held, all four among every token's
-    choices: the pair buffer (tokens x min(k, held) rows) is full."""
+    choices: every chunk of the walk runs, here four of 64 rows (48 tokens
+    rounded up to a row tile of 32), three of them full."""
+    monkeypatch.setattr(moe, "ROW_TILE", 32)
     rng = np.random.default_rng(15)
     x = jnp.asarray(rng.normal(size=(48, 16)), jnp.float32)
     bias = np.zeros(16, np.float32)
@@ -359,6 +365,7 @@ def test_no_pair_dropped_when_every_token_chooses_every_held_expert():
     assert (np.sort(np.asarray(experts), 1)[:, :4] == np.arange(4)).all()
     y, counters = moe.held_experts_ffn(x, weights, experts, w_up, w_down, 0, act="relu2")
     assert np.asarray(counters["pairs"]).tolist() == [48] * 4 and int(counters["dropped"]) == 0
+    assert int(counters["rows"]) == 3 * 64
     eye = jnp.eye(16, dtype=jnp.float32)
     zero = jnp.zeros((16, 1), jnp.float32)
     want = ref.moe_mixer({"router": router, "bias": jnp.asarray(bias), "w_lat1": eye, "w_lat2": eye,
@@ -369,6 +376,42 @@ def test_no_pair_dropped_when_every_token_chooses_every_held_expert():
     g = jax.grad(lambda a: jnp.sum(moe.held_experts_ffn(x, weights, experts, a, w_down, 0,
                                                         act="relu2")[0] ** 2))(w_up)
     assert np.isfinite(np.asarray(g)).all() and np.asarray(g).any()
+
+
+@pytest.mark.parametrize("load", moe_loads.LOADS)
+def test_chunk_walk_matches_the_reference_mixer_and_its_gradients(load, monkeypatch):
+    """16 tokens, top-6 of 16 with a selection bias, experts 0 .. 3 held,
+    relu^2: a buffer of four chunks of 16 rows (k > held; no rounding up to
+    the kernel's row tile at this size).  Output and the gradients of x, the
+    router and both matrices against the plain mixer's autodiff; the walk
+    runs the chunks the routed pairs fill, no more."""
+    monkeypatch.setattr(moe, "ROW_TILE", 1)
+    rng = np.random.default_rng(17)
+    x = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
+    eye, zero = jnp.eye(32, dtype=jnp.float32), jnp.zeros((32, 1), jnp.float32)
+    p = {"router": jnp.asarray(moe_loads.router_to(x, moe_loads.picks(load, 6, 16), 16)),
+         "w_up": jnp.asarray(0.3 * rng.normal(size=(4, 32, 12)), jnp.float32),
+         "w_down": jnp.asarray(0.3 * rng.normal(size=(4, 12, 32)), jnp.float32)}
+    fixed = {"bias": jnp.asarray(0.05 * rng.normal(size=16), jnp.float32), "w_lat1": eye,
+             "w_lat2": eye, "ws_up": zero, "ws_down": zero.T}
+    cfg = dict(num_experts_per_tok=6, routed_scaling_factor=2.0)
+    g = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
+
+    def mine(x, p):
+        weights, experts = moe.route(x, p["router"], 6, True, bias=fixed["bias"], scale=2.0)
+        y, counters = moe.held_experts_ffn(x, weights, experts, p["w_up"], p["w_down"], 0,
+                                           act="relu2")
+        return jnp.sum(y * g), (y, counters)
+    theirs = lambda x, p: ref.moe_mixer({**p, **fixed}, x, cfg, 0)
+    (_, (y, counters)), got = jax.value_and_grad(mine, argnums=(0, 1), has_aux=True)(x, p)
+    want = jax.grad(lambda x, p: jnp.sum(theirs(x, p) * g), argnums=(0, 1))(x, p)
+    n_here = moe_loads.routed(load, 6)
+    assert int(counters["pairs"].sum()) == n_here and int(counters["dropped"]) == 0
+    assert int(counters["rows"]) == -(-n_here // 16) * 16
+    np.testing.assert_allclose(y, theirs(x, p), atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5 * max(float(jnp.abs(b).max()), 1.0))
+        assert n_here or not np.asarray(a).any()
 
 
 # ---------------------------------------------------------- scores, the file

@@ -6,6 +6,7 @@ weights, toy size (hidden 64, 4/2 heads of 16, 8 experts top-2 of width 32 —
 
 import os
 import shutil
+import sys
 
 import numpy as np
 import pytest
@@ -20,6 +21,9 @@ from shifu_tpu.config.errors import ShifuError
 from shifu_tpu.models import tower_sdar as tw
 from shifu_tpu.models import towers
 from shifu_tpu.ops import moe
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "helpers"))
+import moe_loads  # noqa: E402
 
 COL_BINS = [10, 11, 9, 12, 10, 11, 10, 10]          # 91 ids + 4 specials = 95 <= 97
 TOY = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
@@ -232,19 +236,46 @@ def test_no_pair_dropped_when_every_token_picks_one_expert():
 def test_dropped_counts_the_pairs_outside_their_experts_group():
     """The counter is measured: group sizes clipped to a capacity (what a
     dispatch that drops does) leave the later experts' rows outside their
-    own groups."""
+    own groups.  Four tokens, two choices, four held: two chunks of 4 rows."""
     local = jnp.asarray([[0, 1], [0, 2], [0, 1], [1, 4]])          # 4 = an absent expert
-    is_held = local < 4
-    key = jnp.where(is_held, local, 4).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    slot = jnp.zeros_like(order).at[order].set(jnp.arange(8)).reshape(4, 2)
-    sizes = jnp.bincount(key, length=5)[:4]
+    chosen = jnp.sort(jnp.where(local < 4, local, 4).reshape(-1))  # the buffer's rows, by expert
+    sizes = jnp.bincount(chosen, length=5)[:4]
     assert sizes.tolist() == [3, 3, 1, 0]
-    assert int(moe.covered_pairs(slot, local, is_held, sizes)) == 7
+    assert moe.chunk_sizes(sizes, 2, 4).tolist() == [[3, 1, 0, 0], [0, 2, 1, 0]]
+    assert int(moe.covered_pairs(chosen, moe.chunk_sizes(sizes, 2, 4))) == 7
     # expert 0 capped at 2 rows: its third pair falls into expert 1's group,
     # and every later group starts one row early
     capped = sizes.at[0].set(2)
-    assert int(moe.covered_pairs(slot, local, is_held, capped)) == 2 + 2 + 0
+    assert int(moe.covered_pairs(chosen, moe.chunk_sizes(capped, 2, 4))) == 2 + 2 + 0
+
+
+@pytest.mark.parametrize("load", moe_loads.LOADS)
+def test_chunk_walk_matches_the_reference_layer_and_its_gradients(load, monkeypatch):
+    """16 tokens, top-2 of 8, experts 0 .. 3 held: a buffer of two chunks of
+    16 rows (no rounding up to the kernel's row tile at this size).  Output
+    and the gradients of x, the router and both matrices against the plain
+    layer's autodiff; the walk runs the chunks the routed pairs fill, no more."""
+    monkeypatch.setattr(moe, "ROW_TILE", 1)
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
+    p = {"router": jnp.asarray(moe_loads.router_to(x, moe_loads.picks(load, 2, 8), 8)),
+         "w_gate_up": jnp.asarray(0.3 * rng.normal(size=(4, 32, 24)), jnp.float32),
+         "w_down": jnp.asarray(0.3 * rng.normal(size=(4, 12, 32)), jnp.float32)}
+    g = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
+
+    def mine(x, p):
+        weights, experts = moe.route(x, p["router"], 2)
+        y, counters = moe.held_experts_ffn(x, weights, experts, p["w_gate_up"], p["w_down"], lo=0)
+        return jnp.sum(y * g), (y, counters)
+    (_, (y, counters)), got = jax.value_and_grad(mine, argnums=(0, 1), has_aux=True)(x, p)
+    want = jax.grad(lambda x, p: jnp.sum(ref.moe_layer(p, x, TOY, 0) * g), argnums=(0, 1))(x, p)
+    n_here = moe_loads.routed(load, 2)
+    assert int(counters["pairs"].sum()) == n_here and int(counters["dropped"]) == 0
+    assert int(counters["rows"]) == -(-n_here // 16) * 16
+    np.testing.assert_allclose(y, ref.moe_layer(p, x, TOY, 0), atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5 * max(float(jnp.abs(b).max()), 1.0))
+        assert n_here or not np.asarray(a).any()
 
 
 def test_bf16_operands_stay_near_f32_and_leave_f32(monkeypatch):
