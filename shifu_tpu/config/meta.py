@@ -143,9 +143,13 @@ TRAIN_PARAM_RULES: Dict[str, Rule] = {
     # the slot's own use: ``Tower`` names a deep tower the native path
     # trains itself (train/tower_trainer.py); ``TowerParams`` holds the
     # tower's published config.json keys, checked by the tower's own module
-    # (models/towers.py TOWERS: tower_sdar.py, tower_nemotron_h.py)
-    "Tower": Rule("str", allowed=("sdar_moe", "nemotron_h"), algs=("TENSORFLOW",), native=True),
+    # (models/towers.py TOWERS: tower_sdar.py, tower_nemotron_h.py,
+    # tower_afmoe.py); ``RowsPerSequence`` lays that many consecutive rows of
+    # a microbatch end to end as one sequence (default 1; ``afmoe`` only)
+    "Tower": Rule("str", allowed=("sdar_moe", "nemotron_h", "afmoe"), algs=("TENSORFLOW",),
+                  native=True),
     "TowerParams": Rule("dict", algs=("TENSORFLOW",), native=True),
+    "RowsPerSequence": Rule("int", lo=1, algs=("TENSORFLOW",), native=True),
     # WDL family
     "EmbedColumnNum": Rule("int", lo=1, algs=("WDL",)),
     "EmbedDim": Rule("int", lo=1, algs=("WDL",)),

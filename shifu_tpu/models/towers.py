@@ -15,7 +15,13 @@ trainer, ``eval`` and the entry points' refusals find it through
   ``counter_shapes(spec)``,
 - ``tag_logits(params, spec, feature_ids, tag0_id, mask_id) -> [n, 2]``,
 - ``SCOPES`` (its ``jax.named_scope`` names, most specific first) and
-  ``OBS_COUNTERS`` (counter of ``aux`` -> the telemetry counter it feeds).
+  ``OBS_COUNTERS`` (counter of ``aux`` -> the telemetry counter it feeds),
+- optionally ``sequence_block(spec)``: the tower takes several rows a sequence
+  (``train#params.RowsPerSequence``); its ``train_loss`` then gets what
+  :func:`pack_rows` makes of the microbatch — ids ``[sequences, positions]``
+  padded to whole blocks of that many positions and a weight a position —
+  and ``after_step(params, aux, spec) -> (params, aux)``: what the tower does
+  to its parameters after each optimizer step, outside the gradient.
 
 Shared here: the tokeniser (a row of the binned plane is a sequence: one
 token a column, id = the column's offset + its bin, then the specials), the
@@ -37,7 +43,7 @@ import jax.numpy as jnp
 
 from ..config.errors import ErrorCode, ShifuError
 
-TOWERS = {"sdar_moe": "tower_sdar", "nemotron_h": "tower_nemotron_h"}
+TOWERS = {"sdar_moe": "tower_sdar", "nemotron_h": "tower_nemotron_h", "afmoe": "tower_afmoe"}
 SPECIALS = ("TAG0", "TAG1", "MASK", "PAD")
 
 
@@ -116,6 +122,41 @@ def tokenize(spec, bins: np.ndarray, y: np.ndarray) -> np.ndarray:
     ids[:, spec.feature_len] = np.where(np.asarray(y) > 0.5, spec.special("TAG1"),
                                         spec.special("TAG0"))
     return ids
+
+
+def pad_to_block(ids, block: int, pad_id):
+    """[n, L] -> [n, L rounded up to whole blocks], filled with ``pad_id``."""
+    return jnp.pad(ids, ((0, 0), (0, -ids.shape[1] % block)), constant_values=pad_id)
+
+
+def pack_rows(ids, row_w, rows_per_sequence: int, block: int, pad_id):
+    """Each ``rows_per_sequence`` consecutive rows of a microbatch laid end to
+    end (a row's feature tokens then its tag), padded with ``PAD`` to whole
+    blocks.  ids [n, S], row_w [n] -> (ids [n / R, L], weights [n / R, L]:
+    each position's row's weight, 0 on the padding)."""
+    n, s = ids.shape
+    seqs = n // rows_per_sequence
+    w = jnp.repeat(row_w[:, None], s, axis=1).reshape(seqs, rows_per_sequence * s)
+    return (pad_to_block(ids.reshape(seqs, rows_per_sequence * s), block, pad_id),
+            pad_to_block(w, block, 0.0))
+
+
+def pack_plan(spec, microbatch: int, rows_per_sequence: int, block: int) -> Dict[str, int]:
+    """What :func:`pack_rows` makes of a microbatch, in numbers; a packing the
+    tower cannot take is a coded error."""
+    problems = []
+    if rows_per_sequence < 1 or microbatch % rows_per_sequence:
+        problems.append(f"train#params.MiniBatchs {microbatch} is not whole sequences of "
+                        f"RowsPerSequence {rows_per_sequence} rows")
+    if rows_per_sequence * spec.seq_len > spec.max_position_embeddings:
+        problems.append(f"RowsPerSequence {rows_per_sequence} x {spec.seq_len} positions a row "
+                        f"exceeds max_position_embeddings {spec.max_position_embeddings}")
+    if problems:
+        raise ShifuError(ErrorCode.ERROR_MODELCONFIG_NOT_VALIDATION, "; ".join(problems))
+    used = rows_per_sequence * spec.seq_len
+    positions = -(-used // block) * block
+    return {"rows": microbatch, "sequences": microbatch // rows_per_sequence,
+            "positions": positions, "pad_positions": positions - used}
 
 
 def n_params(params) -> int:

@@ -90,6 +90,15 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
                            "targets, unscaled (nemotron_h)"),
     "tower.ssm_chunks": ("counter", "chunks the Mamba-2 layers' scans ran: rows x layers "
                          "x ceil(positions / chunk_size) (nemotron_h)"),
+    "tower.attn_key_blocks": ("counter", "key blocks the attention kernels' forward visits: "
+                              "sequences x heads x layers' visits (afmoe)"),
+    "tower.attn_key_blocks_dense": ("counter", "what a full causal sweep of every layer "
+                                    "would visit (afmoe)"),
+    "tower.pad_positions": ("counter", "PAD positions of the packed training sequences (afmoe)"),
+    "tower.sequence_positions": ("counter", "positions of the packed training sequences, "
+                                 "PAD included (afmoe)"),
+    "tower.router_bias_absmax": ("counter", "how far the largest |selection bias| moved: "
+                                 "summed since a zero start, the largest |b| (afmoe)"),
     "train.host_syncs": ("counter", "device->host value-forcing fetches"),
     "train.tail_sweeps": ("counter", "disk-tail re-streams paid"),
     "train.tail_repairs": ("counter", "c2f speculation repairs"),
@@ -382,6 +391,8 @@ SPANS: Dict[str, str] = {
     "nn.epoch.checkpoint": "tmp-model and trainer-state checkpoints",
     # the tower trainer (train/tower_trainer.py): one TRAIN job
     "tower.tokenize": "bins -> token ids and the train/validation split (rows, ids)",
+    "tower.pack": ("the packing of a microbatch's rows into sequences, in numbers (rows, "
+                   "sequences, positions, pad_positions)"),
     "tower.init": ("parameters and optimizer state made on the device or "
                    "restored, the id plane put up (params, bytes)"),
     "tower.epoch": "one epoch of the tower trainer (epoch)",

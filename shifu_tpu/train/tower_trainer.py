@@ -4,9 +4,11 @@ Trains the tower ``train#params.Tower`` names (:mod:`shifu_tpu.models.towers`:
 its module gives the spec, the initial parameters, the loss with its counters,
 the scopes and the scorer) over the binned plane
 (``tmp/CleanedData``, the plane the tree trainers read): rows tokenised once,
-microbatches of ``MiniBatchs`` rows, one jitted step a microbatch (loss and
-gradients with each layer recomputed in the backward pass, then the
-``train/optimizers.py`` update rule over every parameter), the epoch's loss
+microbatches of ``MiniBatchs`` rows (each ``RowsPerSequence`` consecutive ones
+laid end to end as one sequence, where the tower takes that), one jitted step
+a microbatch (loss and gradients with each layer recomputed in the backward
+pass, then the ``train/optimizers.py`` update rule over every parameter, then
+the tower's own ``after_step`` if it has one), the epoch's loss
 and MoE counters accumulated on the device and fetched once an epoch.  The
 epoch hooks are the NN trainer's: a progress line, trainer-state checkpoints
 every ``CheckpointInterval`` epochs (``train/checkpoint.py``) and resume from
@@ -36,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import faults, obs
+from ..config.errors import ErrorCode, ShifuError
 from ..models import towers
 from ..obs.costs import op_scopes
 from . import checkpoint as ckpt
@@ -82,7 +85,7 @@ def _zero_acc(spec) -> Dict[str, jnp.ndarray]:
             **{k: f32(*shape) for k, shape in tower.counter_shapes(spec).items()}}
 
 
-def build_programs(spec, opt, mb: int):
+def build_programs(spec, opt, mb: int, rows_per_sequence: int = 1):
     """(step, valid_step): the two programs an epoch launches.  State and
     accumulators are donated: 16 bytes a parameter, updated in place.  A
     step takes its microbatch's row indices (-1 = padding), so the programs
@@ -90,27 +93,34 @@ def build_programs(spec, opt, mb: int):
     epoch has."""
     tower = towers.module(spec.tower)
     counters = tuple(tower.counter_shapes(spec))
+    block = tower.sequence_block(spec) if hasattr(tower, "sequence_block") else None
 
-    def gather(ids, w, rows):
+    def gather(ids, w, rows, specials):
         keep = rows >= 0
         rows = jnp.maximum(rows, 0)
-        return ids[rows], jnp.where(keep, w[rows], 0.0)
+        x0, row_w = ids[rows], jnp.where(keep, w[rows], 0.0)
+        if block is None:
+            return x0, row_w
+        return towers.pack_rows(x0, row_w, rows_per_sequence, block,
+                                specials[towers.SPECIALS.index("PAD")])
 
     @partial(obs.costed_jit, "tower.step", donate_argnums=(0, 1, 2))
     def tower_step(params, opt_state, acc, ids, w, rows, key, specials, epoch, i):
-        x0, row_w = gather(ids, w, rows)
+        x0, row_w = gather(ids, w, rows, specials)
         step_key = jax.random.fold_in(jax.random.fold_in(key, epoch), 1 + i)
         (_, aux), grads = jax.value_and_grad(tower.train_loss, has_aux=True)(
             params, spec, x0, row_w, step_key, specials)
         with jax.named_scope("tower/opt"):
             delta, opt_state = opt.update(grads, opt_state, params)
             params = jax.tree_util.tree_map(jnp.add, params, delta)
+            if hasattr(tower, "after_step"):
+                params, aux = tower.after_step(params, aux, spec)
         acc = {**acc, **{k: acc[k] + aux[k] for k in ("loss_sum", "positions") + counters}}
         return params, opt_state, acc
 
     @partial(obs.costed_jit, "tower.valid_step", donate_argnums=(1,))
     def tower_valid_step(params, acc, ids, w, rows, key, specials, i):
-        x0, row_w = gather(ids, w, rows)
+        x0, row_w = gather(ids, w, rows, specials)
         step_key = jax.random.fold_in(jax.random.fold_in(key, VALID_FOLD), 1 + i)
         _, aux = tower.train_loss(params, spec, x0, row_w, step_key, specials)
         return {**acc, "valid_loss_sum": acc["valid_loss_sum"] + aux["loss_sum"],
@@ -125,15 +135,25 @@ def _nbytes(tree) -> int:
 
 def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
                 valid_rate: float,
-                progress: Optional[Callable[[int, float, float], None]] = None) -> TowerResult:
+                progress: Optional[Callable[[int, float, float], None]] = None,
+                rows_per_sequence: int = 1) -> TowerResult:
     tower = towers.module(spec.tower)
     precision = resolve_precision(settings.precision)
     if precision != "f32":
         raise ValueError(f"a tower trains under shifu.train.precision=f32; got {precision!r}")
+    packs = hasattr(tower, "sequence_block")
+    if rows_per_sequence != 1 and not packs:
+        raise ShifuError(ErrorCode.ERROR_MODELCONFIG_NOT_VALIDATION,
+                         f"train#params.RowsPerSequence {rows_per_sequence}: the {spec.tower} "
+                         "tower takes one row a sequence (its mask and recurrence end with the "
+                         "row); `afmoe` packs rows")
     with obs.span("tower.tokenize", rows=len(y), ids=spec.n_ids):
         ids = towers.tokenize(spec, bins, y)
         train_rows, valid_rows = split_rows(len(y), valid_rate, settings.seed)
     mb = min(settings.batch_size or DEFAULT_MICROBATCH, max(len(train_rows), 1))
+    if packs:
+        with obs.span("tower.pack") as sp:
+            sp.set(**towers.pack_plan(spec, mb, rows_per_sequence, tower.sequence_block(spec)))
     valid_order = _microbatches(valid_rows, mb)
     steps = -(-len(train_rows) // mb)
 
@@ -158,7 +178,7 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
         # the special ids follow the columns' bins: an argument, so that
         # another table's job finds these programs in the compile cache
         specials = jnp.asarray([spec.special(n) for n in towers.SPECIALS], jnp.int32)
-        tower_step, tower_valid_step = build_programs(spec, opt, mb)
+        tower_step, tower_valid_step = build_programs(spec, opt, mb, rows_per_sequence)
         n_par = towers.n_params(params)
         sp.set(params=n_par, bytes=_nbytes(params) + _nbytes(opt_state))
     log.info("tower %s: %d rows x %d positions (%d train, %d validation), %d parameters, "
@@ -234,7 +254,6 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
 
 def run_tower_training(proc) -> int:
     """Entry called by TrainProcessor for ``TENSORFLOW`` with ``Tower``."""
-    from ..config.errors import ErrorCode, ShifuError
     from ..pipeline.train import settings_from_params
     mc = proc.model_config
     p = dict(mc.train.params or {})
@@ -270,7 +289,8 @@ def run_tower_training(proc) -> int:
             log.info(line)
         with proc.phase("train"):
             res = train_tower(data["bins"], data["y"], data["w"], spec, settings,
-                              mc.train.validSetRate, progress)
+                              mc.train.validSetRate, progress,
+                              rows_per_sequence=int(p.get("RowsPerSequence", 1)))
 
     with proc.phase("save_models"), obs.span("tower.save") as sp:
         os.makedirs(proc.paths.models_dir, exist_ok=True)

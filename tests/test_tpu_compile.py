@@ -71,7 +71,7 @@ def _compiled_step(tw, spec, doc, rows, one_chip):
     params, opt_state = jax.tree_util.tree_map(on_chip, state)
     acc = jax.tree_util.tree_map(on_chip, jax.eval_shape(lambda: tt._zero_acc(spec)))
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    step, _ = tt.build_programs(spec, opt, mb)
+    step, _ = tt.build_programs(spec, opt, mb, doc["train"]["params"].get("RowsPerSequence", 1))
     compiled = step.lower(params, opt_state, acc, arg((rows, spec.seq_len), jnp.int32),
                           arg((rows,), jnp.float32), arg((mb,), jnp.int32), arg((2,), jnp.uint32),
                           arg((4,), jnp.int32), arg((), jnp.int32), arg((), jnp.int32)).compile()
@@ -119,3 +119,33 @@ def test_sdar_train_step_fits_one_v5e_chip(one_chip, no_cache_no_x64):
     assert compiled.memory_analysis().alias_size_in_bytes > 5.4e9
     assert _live_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
     assert "[13952,8,2048]" not in compiled.as_text()
+
+
+def test_trinity_train_step_fits_one_v5e_chip(one_chip, no_cache_no_x64, monkeypatch):
+    """The cell ``trinity-train``'s step program — 18 rows packed into one
+    sequence of 8,192 positions, the 705 M-parameter share with its Adam state
+    donated, the attention kernels compiled by Mosaic at their real shape —
+    fits, holds no ``[heads, S, S]`` scores, and every attention kernel keeps
+    its scope."""
+    from benchmark.drivers.train_afmoe import tower_params
+    from shifu_tpu.models import tower_afmoe as tw
+    from shifu_tpu.obs.costs import op_scopes
+    from shifu_tpu.ops import attention
+    # the code asks jax.default_backend(), which is the CPU here: steer it to the chip's branch
+    monkeypatch.setattr(attention, "mxu_operand_dtype", lambda like: jnp.bfloat16)
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    doc = _doc("configs", "trinity-mini-ep8.json")
+    spec = _spec(tw, tower_params(doc), doc)
+    assert (doc["train"]["params"]["MiniBatchs"], doc["train"]["params"]["RowsPerSequence"],
+            spec.seq_len, spec.n_ids) == (18, 18, 433, 17360)
+    compiled, n_params = _compiled_step(
+        tw, spec, doc, _doc("traffic", "retrain-540x433-pack18-2epochs.json")["rows"], one_chip)
+    assert n_params == 705_474_304
+    assert compiled.memory_analysis().alias_size_in_bytes > 8.4e9   # 12 bytes a parameter updated in place
+    assert _live_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+    text = compiled.as_text()
+    assert "8192,8192]" not in text
+    scopes = op_scopes(text, tw.SCOPES)
+    calls = lambda scope: [n for n in scopes[scope] if n.startswith("blocked_attention")]
+    assert len(calls("tower/attn/window")) == 4 * 4 and len(calls("tower/attn/full")) == 4, \
+        {k: len(v) for k, v in scopes.items()}
