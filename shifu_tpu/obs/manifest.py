@@ -81,6 +81,8 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
     "tower.moe_rows_computed": ("counter", "buffer rows the held experts' chunk walks ran "
                                 "(chunks run x a chunk's rows, all layers); routed pairs over it = "
                                 "the walk's occupancy"),
+    "tower.programs_reused": ("counter", "jobs that found their three programs held by the "
+                              "process (compile_cache.PROGRAMS) and built none"),
     "tower.dropped_pairs": ("counter", "pairs routed to a held expert that no "
                             "grouped product covered (must stay 0)"),
     "tower.masked_positions": ("counter", "masked non-PAD positions trained on (sdar_moe)"),
@@ -393,11 +395,13 @@ SPANS: Dict[str, str] = {
     "tower.tokenize": "bins -> token ids and the train/validation split (rows, ids)",
     "tower.pack": ("the packing of a microbatch's rows into sequences, in numbers (rows, "
                    "sequences, positions, pad_positions)"),
-    "tower.init": ("parameters and optimizer state made on the device or "
-                   "restored, the id plane put up (params, bytes)"),
+    "tower.init": ("the three programs found in compile_cache.PROGRAMS or made anew, "
+                   "parameters and optimizer state made on the device or restored, the id "
+                   "plane put up (params, bytes, programs_built: 3 when made, 0 when the "
+                   "process's last job left them)"),
     "tower.epoch": "one epoch of the tower trainer (epoch)",
     "tower.epoch.dispatch": ("the epoch's order and its step and validation "
-                             "programs launched (builds them in epoch 0)"),
+                             "programs launched (epoch 0 builds them, unless they were held)"),
     "tower.epoch.fetch": "the fetch of the epoch's loss and counters that waits",
     "tower.epoch.checkpoint": "the trainer-state checkpoint (bytes)",
     "tower.save": "device->host copy of the parameters and the model file (bytes)",

@@ -15,6 +15,17 @@ every ``CheckpointInterval`` epochs (``train/checkpoint.py``) and resume from
 the latest, bit-exactly: what an epoch does is a function of (seed, epoch,
 step) and the restored state alone.
 
+The three jitted programs (``tower.init``, ``tower.step``, ``tower.valid_step``)
+outlive the job: ``compile_cache.PROGRAMS`` keeps them for the next job of
+this process that asks under an equal :func:`programs_key`, which then
+traces, lowers and loads nothing.  A program depends on what the key names —
+the tower's spec, the optimizer and its settings, ``RowsPerSequence``, the id
+plane's shape, the microbatch, telemetry on or off — and on nothing else: what
+else a job has (the seed, the rows, the special ids, the epoch) is an argument.
+A job with another key drops the held programs and builds its own; parameters
+and optimizer state are never held, a job makes them from its seed or reads
+them from its checkpoint.
+
 What the seed decides, restated by the towers' references under
 ``benchmark/reference/``: the order of an epoch's training rows is
 ``permutation(fold_in(fold_in(key, epoch), 0))``; step ``i``'s loss gets the
@@ -25,10 +36,11 @@ VALID_FOLD), 1 + i)``.
 
 from __future__ import annotations
 
+import json
 import logging
 import os
 import time
-from dataclasses import dataclass
+from dataclasses import asdict, dataclass
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -37,7 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import faults, obs
+from .. import compile_cache, faults, obs
 from ..config.errors import ErrorCode, ShifuError
 from ..models import towers
 from ..obs.costs import op_scopes
@@ -86,11 +98,14 @@ def _zero_acc(spec) -> Dict[str, jnp.ndarray]:
 
 
 def build_programs(spec, opt, mb: int, rows_per_sequence: int = 1):
-    """(step, valid_step): the two programs an epoch launches.  State and
-    accumulators are donated: 16 bytes a parameter, updated in place.  A
-    step takes its microbatch's row indices (-1 = padding), so the programs
-    depend on the plane's rows and the microbatch, not on how many steps an
-    epoch has."""
+    """(step, valid_step): the two programs an epoch launches, made anew at
+    every call (:func:`train_tower` keeps what it built for the process's
+    next job, see the module's text).  State and accumulators are donated:
+    16 bytes a parameter, updated in place.  A step takes its microbatch's
+    row indices (-1 = padding), so the programs depend on the plane's rows
+    and the microbatch, not on how many steps an epoch has.  They close over
+    ``spec``, ``opt`` and ``rows_per_sequence`` and read nothing else from
+    outside their arguments."""
     tower = towers.module(spec.tower)
     counters = tuple(tower.counter_shapes(spec))
     block = tower.sequence_block(spec) if hasattr(tower, "sequence_block") else None
@@ -129,6 +144,28 @@ def build_programs(spec, opt, mb: int, rows_per_sequence: int = 1):
     return tower_step, tower_valid_step
 
 
+def _make_programs(spec, settings, mb: int, rows_per_sequence: int):
+    """(init, step, valid_step), made anew: what :func:`train_tower` holds for
+    the process's next job.  A function of its own, so that the held closures
+    capture these arguments and nothing of a job's planes or state."""
+    tower = towers.module(spec.tower)
+    opt = make_optimizer(settings.optimizer, settings.learning_rate, **settings.opt_kwargs)
+    init = obs.costed_jit(
+        "tower.init", lambda k: (lambda p: (p, opt.init(p)))(tower.init_params(k, spec)))
+    return (init, *build_programs(spec, opt, mb, rows_per_sequence))
+
+
+def programs_key(spec, settings, mb: int, rows_per_sequence: int, ids_shape) -> tuple:
+    """Everything the three programs close over or are specialised to.  The
+    specs are dataclasses that hold lists, so their canonical JSON stands for
+    them; telemetry is in it because ``obs.costed_jit`` wraps differently
+    with it on."""
+    return ("tower", spec.tower, json.dumps(asdict(spec), sort_keys=True),
+            str(settings.optimizer).upper(), float(settings.learning_rate),
+            json.dumps(settings.opt_kwargs, sort_keys=True, default=repr),
+            int(rows_per_sequence), tuple(ids_shape), int(mb), obs.enabled())
+
+
 def _nbytes(tree) -> int:
     return int(sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree)))
 
@@ -159,9 +196,12 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
 
     with obs.span("tower.init") as sp:
         key = jax.random.PRNGKey(settings.seed)
-        opt = make_optimizer(settings.optimizer, settings.learning_rate, **settings.opt_kwargs)
-        init = obs.costed_jit(
-            "tower.init", lambda k: (lambda p: (p, opt.init(p)))(tower.init_params(k, spec)))
+        programs, reused = compile_cache.PROGRAMS.programs(
+            programs_key(spec, settings, mb, rows_per_sequence, ids.shape),
+            partial(_make_programs, spec, settings, mb, rows_per_sequence))
+        init, tower_step, tower_valid_step = programs
+        if reused:
+            obs.counter("tower.programs_reused").inc()
         start_epoch, state = 0, None
         if settings.resume and settings.checkpoint_dir:
             # shapes only: the restored state never shares the chip with a fresh one
@@ -176,11 +216,11 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
         del state
         ids_d, w_d = jax.device_put((ids, np.asarray(w, np.float32)))
         # the special ids follow the columns' bins: an argument, so that
-        # another table's job finds these programs in the compile cache
+        # another table's job finds these programs in the compile cache on disk
         specials = jnp.asarray([spec.special(n) for n in towers.SPECIALS], jnp.int32)
-        tower_step, tower_valid_step = build_programs(spec, opt, mb, rows_per_sequence)
         n_par = towers.n_params(params)
-        sp.set(params=n_par, bytes=_nbytes(params) + _nbytes(opt_state))
+        sp.set(params=n_par, bytes=_nbytes(params) + _nbytes(opt_state),
+               programs_built=0 if reused else len(programs))
     log.info("tower %s: %d rows x %d positions (%d train, %d validation), %d parameters, "
              "microbatches of %d rows, %d steps an epoch", spec.tower, len(y), spec.seq_len,
              len(train_rows), len(valid_rows), n_par, mb, steps)

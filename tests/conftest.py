@@ -33,6 +33,16 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture(autouse=True)
+def _no_programs_held_across_tests():
+    """A trainer's jitted programs outlive its job (``compile_cache.PROGRAMS``):
+    not its test, or a test's monkeypatched constant would be traced into the
+    next test's programs."""
+    yield
+    from shifu_tpu import compile_cache
+    compile_cache.PROGRAMS.clear()
+
+
 @pytest.fixture(scope="session")
 def fraud_csv(tmp_path_factory):
     """Synthetic fraud-style dataset: mixed numeric/categorical, missing

@@ -34,8 +34,8 @@ ALLOWED = {
                  "data", "models"},
     "eval": {"config", "ops", "data", "models", "parallel"},
     "export": {"config", "ioutil", "ops", "models"},
-    "train": {"config", "ioutil", "faults", "obs", "ops", "data", "models",
-              "parallel"},
+    "train": {"config", "compile_cache", "ioutil", "faults", "obs", "ops", "data",
+              "models", "parallel"},
     "serve": {"config", "ioutil", "faults", "obs", "ops", "data", "models",
               "parallel", "eval", "train"},
     "refresh": {"config", "ioutil", "faults", "obs", "data", "eval",
