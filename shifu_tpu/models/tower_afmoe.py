@@ -55,8 +55,11 @@ from ..ops import attention, moe
 from .towers import SPECIALS, RowTokens, nest_names, pad_to_block
 
 # the step's named scopes, most specific first: device ops carry them
+# ``tower/trunk`` is the catch-all around the layer loop: after every scope that
+# occurs inside it (the first scope an op's name contains takes the op)
 SCOPES = ("tower/attn/window", "tower/attn/full", "tower/attn/proj", "tower/mlp",
-          "tower/moe/route", "tower/moe/experts", "tower/moe/shared", "tower/head", "tower/opt")
+          "tower/moe/route", "tower/moe/experts", "tower/moe/shared", "tower/head", "tower/input",
+          "tower/embed", "tower/trunk", "tower/acc", "tower/opt")
 OBS_COUNTERS = {"attn_key_blocks": "tower.attn_key_blocks",
                 "attn_key_blocks_dense": "tower.attn_key_blocks_dense",
                 "pad_positions": "tower.pad_positions",
@@ -300,14 +303,16 @@ def trunk(params, spec: TowerSpec, ids):
                 f, counters = _moe(p, m, spec)
             return h + _rms(f, p["norm_post_mlp"], eps), counters
         return jax.checkpoint(fn)
-    h = params["embed"][ids]
-    if spec.mup_enabled:
-        h = h * jnp.float32(spec.hidden_size ** 0.5)
+    with jax.named_scope("tower/embed"):
+        h = params["embed"][ids]
+        if spec.mup_enabled:
+            h = h * jnp.float32(spec.hidden_size ** 0.5)
     found = []
-    for i, name in enumerate(sorted(params["blocks"])):
-        h, counters = layer(i)(h, params["blocks"][name])
-        if counters is not None:
-            found.append(counters)
+    with jax.named_scope("tower/trunk"):
+        for i, name in enumerate(sorted(params["blocks"])):
+            h, counters = layer(i)(h, params["blocks"][name])
+            if counters is not None:
+                found.append(counters)
     return h, found
 
 
@@ -333,22 +338,24 @@ def causal_loss(params, spec: TowerSpec, ids, w, pad_id):
             jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         tw = jnp.where(targets != pad_id, jnp.roll(w, -1, axis=1), 0.0).at[:, -1].set(0.0)
         loss_sum, count = jnp.sum(ce * tw), jnp.sum(tw)
-    counts = jnp.any(w > 0, axis=1, keepdims=True)                      # sequences that count
-    live = jnp.sum(counts).astype(jnp.float32)
-    visited, dense = _key_blocks(spec, ids.shape[1])
-    aux = {"loss_sum": loss_sum, "positions": count,
-           "attn_key_blocks": live * visited, "attn_key_blocks_dense": live * dense,
-           "pad_positions": jnp.sum((ids == pad_id) & counts).astype(jnp.float32),
-           "sequence_positions": live * ids.shape[1],
-           "router_bias_absmax": jnp.float32(0.0),
-           **{k: jnp.stack([c[k] for c in found]) for k in ("pairs", "rows", "dropped", "tokens")}}
-    return loss_sum / jnp.maximum(count, 1.0), aux
+        counts = jnp.any(w > 0, axis=1, keepdims=True)                      # sequences that count
+        live = jnp.sum(counts).astype(jnp.float32)
+        visited, dense = _key_blocks(spec, ids.shape[1])
+        aux = {"loss_sum": loss_sum, "positions": count,
+               "attn_key_blocks": live * visited, "attn_key_blocks_dense": live * dense,
+               "pad_positions": jnp.sum((ids == pad_id) & counts).astype(jnp.float32),
+               "sequence_positions": live * ids.shape[1],
+               "router_bias_absmax": jnp.float32(0.0),
+               **{k: jnp.stack([c[k] for c in found]) for k in ("pairs", "rows", "dropped", "tokens")}}
+        return loss_sum / jnp.maximum(count, 1.0), aux
 
 
 def train_loss(params, spec: TowerSpec, ids, w, key, specials):
     """The trainer's loss of one microbatch of packed sequences
     (``towers.pack_rows``); nothing is drawn: ``key`` goes unused."""
-    return causal_loss(params, spec, ids, w, specials[SPECIALS.index("PAD")])
+    with jax.named_scope("tower/input"):
+        pad_id = specials[SPECIALS.index("PAD")]
+    return causal_loss(params, spec, ids, w, pad_id)
 
 
 def _bias_absmax(params):

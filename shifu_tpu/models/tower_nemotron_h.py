@@ -55,8 +55,11 @@ from .towers import RowTokens, nest_names
 MTP_LOSS_SCALE = 0.1                # Megatron-Core's default scaling of the MTP loss
 # the step's named scopes, most specific first: device ops carry them (the
 # MTP module's own layers are nested under its scope, which takes them)
+# ``tower/mtp`` first: it takes its module's own layers and lookup; ``tower/trunk``
+# is the catch-all around the layer loop, after every scope that occurs inside it
 SCOPES = ("tower/mtp", "tower/ssm/proj", "tower/ssm/scan", "tower/attn", "tower/moe/route",
-          "tower/moe/latent", "tower/moe/experts", "tower/moe/shared", "tower/head", "tower/opt")
+          "tower/moe/latent", "tower/moe/experts", "tower/moe/shared", "tower/head", "tower/input",
+          "tower/embed", "tower/trunk", "tower/acc", "tower/opt")
 OBS_COUNTERS = {"mtp_loss_sum": "tower.mtp_loss_sum", "ssm_chunks": "tower.ssm_chunks"}
 _NEG = float(np.finfo(np.float32).min)
 
@@ -383,7 +386,10 @@ def _layers(blocks, pattern: str, h, spec: TowerSpec):
 
 def trunk(params, spec: TowerSpec, ids):
     """ids [n, T] -> (the last layer's output [n, T, D] before ``norm_f``, counters)."""
-    return _layers(params["blocks"], spec.hybrid_override_pattern, params["embed"][ids], spec)
+    with jax.named_scope("tower/embed"):
+        h = params["embed"][ids]
+    with jax.named_scope("tower/trunk"):
+        return _layers(params["blocks"], spec.hybrid_override_pattern, h, spec)
 
 
 def _mtp_hidden(params, spec: TowerSpec, h, next_ids):
@@ -417,13 +423,14 @@ def causal_loss(params, spec: TowerSpec, ids, row_w):
             found = found + more
             with jax.named_scope("tower/head"):
                 mtp = jnp.sum(_ce(hm, params["head"], ids[:, 2:]) * row_w[:, None]) / (s - 2)
-    rows = jnp.sum(row_w)
-    total = main + MTP_LOSS_SCALE * mtp
-    chunks = -(-(s - 1) // spec.chunk_size) * spec.hybrid_override_pattern.count("M")
-    aux = {"loss_sum": total * (s - 1), "positions": rows * (s - 1),
-           "mtp_loss_sum": mtp * (s - 2), "ssm_chunks": jnp.sum(row_w > 0).astype(jnp.float32) * chunks,
-           **{k: jnp.stack([c[k] for c in found]) for k in ("pairs", "rows", "dropped")}}
-    return total / jnp.maximum(rows, 1.0), aux
+    with jax.named_scope("tower/head"):
+        rows = jnp.sum(row_w)
+        total = main + MTP_LOSS_SCALE * mtp
+        chunks = -(-(s - 1) // spec.chunk_size) * spec.hybrid_override_pattern.count("M")
+        aux = {"loss_sum": total * (s - 1), "positions": rows * (s - 1),
+               "mtp_loss_sum": mtp * (s - 2), "ssm_chunks": jnp.sum(row_w > 0).astype(jnp.float32) * chunks,
+               **{k: jnp.stack([c[k] for c in found]) for k in ("pairs", "rows", "dropped")}}
+        return total / jnp.maximum(rows, 1.0), aux
 
 
 def train_loss(params, spec: TowerSpec, ids, row_w, key, specials):

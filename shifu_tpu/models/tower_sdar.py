@@ -36,7 +36,10 @@ from ..ops import moe
 from .towers import RowTokens, load_model  # noqa: F401  (load_model: benchmark/drivers/train_tower.py)
 
 # the step's named scopes, most specific first: device ops carry them
-SCOPES = ("tower/attn", "tower/moe/route", "tower/moe/experts", "tower/head", "tower/opt")
+# ``tower/trunk`` is the catch-all around the layer loop: after every scope that
+# occurs inside it (the first scope an op's name contains takes the op)
+SCOPES = ("tower/attn", "tower/moe/route", "tower/moe/experts", "tower/head", "tower/input",
+          "tower/embed", "tower/trunk", "tower/acc", "tower/opt")
 OBS_COUNTERS = {"masked": "tower.masked_positions"}
 T_MIN = 1e-3                        # per block t ~ U(T_MIN, 1]
 ATTN_ROWS = 4                       # rows whose f32 scores are alive at once
@@ -232,9 +235,13 @@ def _attention(p, x, pos, mask, spec: TowerSpec, noised: int = 0):
         blk = np.arange(noised) // b
         if mask[noised:, :noised].any() or (mask[:noised, :noised] != (blk[:, None] == blk)).any():
             raise ValueError("the mask is not the block-diffusion mask of [x_t ; x_0]")
-        core = lambda qkv: _attend_split(*qkv, jnp.asarray(mask), noised, b)
+        attend = lambda qkv: _attend_split(*qkv, jnp.asarray(mask), noised, b)
     else:
-        core = lambda qkv: _attend(*qkv, jnp.asarray(mask))
+        attend = lambda qkv: _attend(*qkv, jnp.asarray(mask))
+
+    def core(qkv):
+        with jax.named_scope("tower/attn"):      # again: under lax.map a checkpointed body names its ops from its own root
+            return attend(qkv)
     out = jax.lax.map(jax.checkpoint(core),
                       (chunks(q.reshape(n, t, kv, h // kv, hd)), chunks(k), chunks(v)))
     return out.reshape(n, t, h * hd) @ p["wo"]
@@ -262,8 +269,11 @@ def hidden(params, spec: TowerSpec, ids, pos, mask: np.ndarray,
     ``[x_t ; x_0]`` under :func:`block_mask` (attention then skips the score
     pairs that mask never allows)."""
     layer = jax.checkpoint(lambda h, p: _layer(spec, pos, mask, noised, h, p))
-    h, counters = jax.lax.scan(layer, params["embed"][ids], params["layers"])
-    return _rms(h, params["final_norm"], spec.rms_norm_eps), counters
+    with jax.named_scope("tower/embed"):
+        h = params["embed"][ids]
+    with jax.named_scope("tower/trunk"):
+        h, counters = jax.lax.scan(layer, h, params["layers"])
+        return _rms(h, params["final_norm"], spec.rms_norm_eps), counters
 
 
 def diffusion_loss(params, spec: TowerSpec, x0, t, masked, row_w, mask_id, pad_id):
@@ -274,10 +284,11 @@ def diffusion_loss(params, spec: TowerSpec, x0, t, masked, row_w, mask_id, pad_i
     ``mask_id`` / ``pad_id`` come in as values, not constants: they follow the
     columns' bins, and a program must not be rebuilt for another table."""
     s = spec.seq_len
-    xt = jnp.where(masked, mask_id, x0)
-    pos = jnp.concatenate([jnp.arange(s), jnp.arange(s)])
-    h, counters = hidden(params, spec, jnp.concatenate([xt, x0], axis=1), pos,
-                         block_mask(s, spec.block_length), noised=s)
+    with jax.named_scope("tower/input"):
+        xt = jnp.where(masked, mask_id, x0)
+        pos = jnp.concatenate([jnp.arange(s), jnp.arange(s)])
+        ids = jnp.concatenate([xt, x0], axis=1)
+    h, counters = hidden(params, spec, ids, pos, block_mask(s, spec.block_length), noised=s)
     with jax.named_scope("tower/head"):
         logits = (h[:, :s] @ params["head"]).astype(jnp.float32)
         ce = jax.nn.logsumexp(logits, axis=-1) - \
@@ -285,9 +296,9 @@ def diffusion_loss(params, spec: TowerSpec, x0, t, masked, row_w, mask_id, pad_i
         real = (x0 != pad_id) * row_w[:, None]
         total = jnp.sum(jnp.where(masked, ce / t, 0.0) * real)
         count = jnp.sum(real)
-    aux = {"loss_sum": total, "positions": count,
-           "masked": jnp.sum(masked * real), **counters}
-    return total / jnp.maximum(count, 1.0), aux
+        aux = {"loss_sum": total, "positions": count,
+               "masked": jnp.sum(masked * real), **counters}
+        return total / jnp.maximum(count, 1.0), aux
 
 
 def noise(key, rows: int, spec: TowerSpec):
@@ -304,8 +315,10 @@ def train_loss(params, spec: TowerSpec, x0, row_w, key, specials):
     """The trainer's loss of one microbatch: :func:`diffusion_loss` under the
     noise drawn from the step's key.  ``specials``: the ids of
     :data:`.towers.SPECIALS`, as values."""
-    t, masked = noise(key, x0.shape[0], spec)
-    return diffusion_loss(params, spec, x0, t, masked, row_w, specials[2], specials[3])
+    with jax.named_scope("tower/input"):
+        t, masked = noise(key, x0.shape[0], spec)
+        mask_id, pad_id = specials[2], specials[3]
+    return diffusion_loss(params, spec, x0, t, masked, row_w, mask_id, pad_id)
 
 
 def counter_shapes(spec: TowerSpec) -> Dict[str, tuple]:

@@ -41,6 +41,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..config.errors import ErrorCode, ShifuError
 
 TOWERS = {"sdar_moe": "tower_sdar", "nemotron_h": "tower_nemotron_h", "afmoe": "tower_afmoe"}
@@ -187,14 +188,27 @@ def nest_names(flat: Dict[str, Any]) -> Dict[str, Any]:
 def save_model(path: str, spec, params) -> int:
     """Self-contained ``.tower`` file: an uncompressed npz of the f32 arrays +
     the spec json, written beside the path and renamed into place (a
-    gigabyte-sized file is never buffered whole).  Returns its bytes."""
+    gigabyte-sized file is never buffered whole).  Returns its bytes.  The
+    write (``np.savez`` into the temp file, its close included) and the
+    commit (the rename) are the spans ``tower.save.write`` (bytes) and
+    ``tower.save.commit``; a caller that times the fetch hands in host arrays."""
     arrays = {k: np.asarray(v, np.float32) for k, v in flat_names(params).items()}
     arrays["__spec__"] = np.frombuffer(spec.to_json().encode(), dtype=np.uint8)
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    from ..ioutil import atomic_open
-    with atomic_open(path, "wb") as f:           # a temp file, renamed into place
-        np.savez(f, **arrays)  # shifu-lint: disable=atomic-write
-    return os.path.getsize(path)
+    tmp = f"{path}.tmp{os.getpid()}"             # ioutil's temp name: sweep_orphan_tmp finds it
+    try:
+        with obs.span("tower.save.write") as sp:
+            with open(tmp, "wb") as f:
+                np.savez(f, **arrays)
+            size = os.path.getsize(tmp)
+            sp.set(bytes=size)
+        with obs.span("tower.save.commit"):
+            os.replace(tmp, path)
+    except BaseException:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+        raise
+    return size
 
 
 def load_model(path: str) -> Tuple[Any, Dict[str, Any]]:

@@ -11,7 +11,11 @@ Modules:
 
 - :mod:`tracer` — nested wall-clock spans (each also a ``shifu:``
   annotation on the ``jax.profiler`` clock) + point events, thread-safe
-  collector with a live-span registry, JSONL sink;
+  collector with a live-span registry, JSONL sink.  A job's spans by
+  name: the step's root > ``setup`` > ``setup.config`` / ``.probe`` /
+  ``.columns`` / ``.journal`` / ``.precheck``, then ``process`` > the
+  step's phases > ``data.*``, ``nn.*`` or ``tower.*`` (a tower job ends
+  in ``tower.save`` > ``.clear`` / ``.fetch`` / ``.write`` / ``.commit``);
 - :mod:`registry` — named counters/gauges/histograms (rows, epochs,
   loss, throughput, device-memory high-water, XLA compile accounting);
   instruments are thread-safe (ingest prep thread + trainers + the
@@ -52,7 +56,10 @@ Modules:
 - :mod:`costs` — device cost attribution: ``costed_jit`` captures
   FLOPs / bytes / memory per named executable, counts compiles,
   launches and RECOMPILES (the shape-churn sentinel), analytic models
-  cover Pallas kernels XLA cannot see through;
+  cover Pallas kernels XLA cannot see through; ``op_scopes`` maps a
+  ``jax.named_scope`` to the compiled step's instructions (what it
+  builds is keyed in the compile cache with its ops' names, so the
+  names read are this source's);
 - :mod:`utilization` — joins executable costs against span wall times:
   achieved FLOP/s, bytes/s, percent-of-peak and a roofline verdict per
   plane (``analysis --telemetry --utilization``).

@@ -43,6 +43,7 @@ lines (schema v6) alongside spans and metrics, so ``analysis
 
 from __future__ import annotations
 
+import contextlib
 import inspect
 import logging
 import os
@@ -375,6 +376,24 @@ def op_scopes(hlo_text: str, scopes: Sequence[str]) -> Dict[str, List[str]]:
 
 
 # ------------------------------------------------------------ costed_jit
+@contextlib.contextmanager
+def _keyed_with_metadata():
+    """jax keys its persistent compile cache on a program without its
+    ``op_name`` metadata, so a source that only renamed its scopes is handed
+    the executable an older source built, under that source's names.  What
+    telemetry builds is read by those names (:meth:`CostedJit.hlo_text` ->
+    :func:`op_scopes`), so it is keyed with them; a plain ``jax.jit`` (telemetry
+    off) keeps jax's key."""
+    import jax
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, was)
+
+
 class CostedJit:
     """A named, cost-attributed jitted callable (see module docs).
 
@@ -413,7 +432,8 @@ class CostedJit:
         if compiled is None:
             try:
                 lowered = self._jitted.lower(*args, **kwargs)
-                compiled = lowered.compile()
+                with _keyed_with_metadata():
+                    compiled = lowered.compile()
             except Exception:
                 log.debug("costed_jit %r AOT build failed; falling back "
                           "to plain jit", self.name, exc_info=True)

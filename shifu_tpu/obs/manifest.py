@@ -17,7 +17,10 @@ one of them.
 SPAN names get the same treatment (``SPANS``; no dynamic families: an
 f-string span name is a finding): the timeline/report joins key on
 span-name literals, so a typo'd span name would silently vanish from
-every report.  Root spans named after the
+every report.  A span that only splits another is named after it
+(``setup.columns`` under ``setup``, ``tower.save.fetch`` under
+``tower.save``, ``nn.epoch.fetch`` under ``nn.epoch``): the parent keeps
+its name and its readers, the children say where its seconds went.  Root spans named after the
 step (``obs.span(self.profile_name, ...)``) are variables, not
 literals, and ride outside the lint.
 """
@@ -333,6 +336,16 @@ PREFIXES: Tuple[str, ...] = (
 # sites) — the timeline tracks, report sections and tests join on these
 SPANS: Dict[str, str] = {
     "setup": "step scaffolding before process() (processor base)",
+    # setup's children (BasicProcessor.setup): what a job pays before its
+    # step body, by name; attrs are counts the code holds
+    "setup.config": "ModelConfig.load and the PathFinder",
+    "setup.probe": "the step's validation of ModelConfig (config.validator.probe)",
+    "setup.columns": ("load_column_configs: ColumnConfig.json parsed into its "
+                      "objects, bins included (columns, bytes of the file)"),
+    "setup.journal": "ensure_dirs and the step's StepJournal read",
+    "setup.precheck": ("_check_step_preconditions: the inputs of the step "
+                       "exist; `train` stats every journaled norm shard "
+                       "(shards verified)"),
     "process": "step body (processor base)",
     "varselect.sensitivity": "SE/ST sensitivity scoring phase",
     "ingest.window_prep": "background window materialization (prep thread)",
@@ -404,7 +417,14 @@ SPANS: Dict[str, str] = {
                              "programs launched (epoch 0 builds them, unless they were held)"),
     "tower.epoch.fetch": "the fetch of the epoch's loss and counters that waits",
     "tower.epoch.checkpoint": "the trainer-state checkpoint (bytes)",
-    "tower.save": "device->host copy of the parameters and the model file (bytes)",
+    "tower.save": ("device->host copy of the parameters and the model file (bytes of the "
+                   "file); its children tell the seconds apart"),
+    "tower.save.clear": "the models directory's old model files unlinked (bytes removed)",
+    "tower.save.fetch": ("jax.device_get of the parameters alone: ends when the last array "
+                         "is on the host (bytes fetched)"),
+    "tower.save.write": ("np.savez of the host arrays into the temp file, its close "
+                         "included (bytes of the file; models/towers.save_model)"),
+    "tower.save.commit": "the temp file renamed into place (models/towers.save_model)",
     "xla.build": ("jax traced / lowered / built (compiled or loaded from "
                   "the compile cache) one program (stage, program, secs); "
                   "recorded when it ends"),

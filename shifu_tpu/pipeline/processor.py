@@ -52,25 +52,35 @@ class BasicProcessor:
         if not os.path.isfile(mc_path):
             raise FileNotFoundError(
                 f"{mc_path} not found — run `shifu-tpu new <name>` first")
-        self.model_config = ModelConfig.load(mc_path)
-        self.paths = PathFinder(self.model_config, self.dir)
-        probe(self.model_config, self.step, self.dir)
+        # what a job pays before its step body, by name: children of the
+        # step's `setup` span (attrs are counts the code holds)
+        with obs.span("setup.config"):
+            self.model_config = ModelConfig.load(mc_path)
+            self.paths = PathFinder(self.model_config, self.dir)
+        with obs.span("setup.probe"):
+            probe(self.model_config, self.step, self.dir)
         cc_path = self.paths.column_config_path
         if os.path.isfile(cc_path):
-            self.column_configs = load_column_configs(cc_path)
+            with obs.span("setup.columns") as sp:
+                self.column_configs = load_column_configs(cc_path)
+                sp.set(columns=len(self.column_configs),
+                       bytes=os.path.getsize(cc_path))
         elif require_columns:
             raise FileNotFoundError(
                 f"{cc_path} not found — run `shifu-tpu init` first")
-        self.paths.ensure_dirs()
-        self.journal = StepJournal(
-            self.paths.journal_path(self.profile_name), self.profile_name,
-            self.dir)
-        self._check_step_preconditions()
+        with obs.span("setup.journal"):
+            self.paths.ensure_dirs()
+            self.journal = StepJournal(
+                self.paths.journal_path(self.profile_name), self.profile_name,
+                self.dir)
+        with obs.span("setup.precheck") as sp:
+            sp.set(shards=self._check_step_preconditions())
 
-    def _check_step_preconditions(self) -> None:
+    def _check_step_preconditions(self) -> int:
         """Ordered-pipeline guard: running a step before its inputs exist
         fails with a coded hint instead of a raw traceback deep in the
-        step (stats -> norm -> train dependency chain)."""
+        step (stats -> norm -> train dependency chain).  Returns the
+        journaled norm shards whose files it verified (`train` alone)."""
         from ..config.errors import ErrorCode, ShifuError
         s = self.step
         if s in (ModelStep.NORMALIZE, ModelStep.VARSELECT, ModelStep.TRAIN):
@@ -109,6 +119,9 @@ class BasicProcessor:
                     "materialized norm shards no longer match their "
                     "journaled sizes (torn/corrupted artifact) — re-run "
                     "`norm`")
+            if nj.status:
+                return len(nj.doc.get("items") or {})
+        return 0
 
     def _abs(self, p: Optional[str]) -> Optional[str]:
         """Resolve a config-relative path against the model-set dir.

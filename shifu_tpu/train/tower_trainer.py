@@ -110,19 +110,21 @@ def build_programs(spec, opt, mb: int, rows_per_sequence: int = 1):
     counters = tuple(tower.counter_shapes(spec))
     block = tower.sequence_block(spec) if hasattr(tower, "sequence_block") else None
 
-    def gather(ids, w, rows, specials):
-        keep = rows >= 0
-        rows = jnp.maximum(rows, 0)
-        x0, row_w = ids[rows], jnp.where(keep, w[rows], 0.0)
-        if block is None:
-            return x0, row_w
-        return towers.pack_rows(x0, row_w, rows_per_sequence, block,
-                                specials[towers.SPECIALS.index("PAD")])
+    def gather(ids, w, rows, specials, key, fold, i):
+        """(the microbatch's ids, its weights, the step's key): what a step
+        does before its tower, under one scope."""
+        with jax.named_scope("tower/input"):
+            keep = rows >= 0
+            rows = jnp.maximum(rows, 0)
+            x0, row_w = ids[rows], jnp.where(keep, w[rows], 0.0)
+            if block is not None:
+                x0, row_w = towers.pack_rows(x0, row_w, rows_per_sequence, block,
+                                             specials[towers.SPECIALS.index("PAD")])
+            return x0, row_w, jax.random.fold_in(jax.random.fold_in(key, fold), 1 + i)
 
     @partial(obs.costed_jit, "tower.step", donate_argnums=(0, 1, 2))
     def tower_step(params, opt_state, acc, ids, w, rows, key, specials, epoch, i):
-        x0, row_w = gather(ids, w, rows, specials)
-        step_key = jax.random.fold_in(jax.random.fold_in(key, epoch), 1 + i)
+        x0, row_w, step_key = gather(ids, w, rows, specials, key, epoch, i)
         (_, aux), grads = jax.value_and_grad(tower.train_loss, has_aux=True)(
             params, spec, x0, row_w, step_key, specials)
         with jax.named_scope("tower/opt"):
@@ -130,16 +132,17 @@ def build_programs(spec, opt, mb: int, rows_per_sequence: int = 1):
             params = jax.tree_util.tree_map(jnp.add, params, delta)
             if hasattr(tower, "after_step"):
                 params, aux = tower.after_step(params, aux, spec)
-        acc = {**acc, **{k: acc[k] + aux[k] for k in ("loss_sum", "positions") + counters}}
+        with jax.named_scope("tower/acc"):
+            acc = {**acc, **{k: acc[k] + aux[k] for k in ("loss_sum", "positions") + counters}}
         return params, opt_state, acc
 
     @partial(obs.costed_jit, "tower.valid_step", donate_argnums=(1,))
     def tower_valid_step(params, acc, ids, w, rows, key, specials, i):
-        x0, row_w = gather(ids, w, rows, specials)
-        step_key = jax.random.fold_in(jax.random.fold_in(key, VALID_FOLD), 1 + i)
+        x0, row_w, step_key = gather(ids, w, rows, specials, key, VALID_FOLD, i)
         _, aux = tower.train_loss(params, spec, x0, row_w, step_key, specials)
-        return {**acc, "valid_loss_sum": acc["valid_loss_sum"] + aux["loss_sum"],
-                "valid_positions": acc["valid_positions"] + aux["positions"]}
+        with jax.named_scope("tower/acc"):
+            return {**acc, "valid_loss_sum": acc["valid_loss_sum"] + aux["loss_sum"],
+                    "valid_positions": acc["valid_positions"] + aux["positions"]}
 
     return tower_step, tower_valid_step
 
@@ -334,11 +337,17 @@ def run_tower_training(proc) -> int:
 
     with proc.phase("save_models"), obs.span("tower.save") as sp:
         os.makedirs(proc.paths.models_dir, exist_ok=True)
-        for f in os.listdir(proc.paths.models_dir):
-            if f.startswith("model"):
-                os.remove(os.path.join(proc.paths.models_dir, f))
+        with obs.span("tower.save.clear") as part:
+            old = [os.path.join(proc.paths.models_dir, f)
+                   for f in os.listdir(proc.paths.models_dir) if f.startswith("model")]
+            part.set(bytes=sum(os.path.getsize(f) for f in old))
+            for f in old:
+                os.remove(f)
         path = proc.paths.model_path(0, "tower")
-        sp.set(bytes=towers.save_model(path, spec, jax.device_get(res.params)))
+        with obs.span("tower.save.fetch") as part:   # ends when the last array is on the host
+            host = jax.device_get(res.params)
+            part.set(bytes=_nbytes(host))
+        sp.set(bytes=towers.save_model(path, spec, host))      # .write and .commit
     log.info("train tower done: %s, train error %.6f, validation error %.6f (%d epochs)",
              path, res.train_error, res.valid_error, res.epochs_run)
     return 0

@@ -29,7 +29,7 @@ ALLOWED = {
     "obs": {"config", "ioutil", "faults"},
     "ops": {"config", "obs"},
     "data": {"config", "ioutil", "faults", "obs", "ops"},
-    "models": {"config", "ioutil", "ops"},
+    "models": {"config", "ioutil", "obs", "ops"},
     "parallel": {"config", "compile_cache", "ioutil", "faults", "obs",
                  "data", "models"},
     "eval": {"config", "ops", "data", "models", "parallel"},

@@ -17,11 +17,15 @@ from shifu_tpu.obs import manifest, tracer
 
 pytestmark = pytest.mark.obs
 
+SETUP_SPANS = ("setup.config", "setup.probe", "setup.columns",
+               "setup.journal", "setup.precheck")
 TRAIN_JOB_SPANS = (
     "data.load", "data.alloc", "data.read", "data.put", "train.split",
     "nn.init", "nn.h2d", "nn.epoch", "nn.epoch.dispatch",
     "nn.epoch.fetch", "nn.epoch.best_copy", "nn.epoch.progress",
-    "nn.epoch.checkpoint", "xla.build")
+    "nn.epoch.checkpoint", "xla.build") + SETUP_SPANS + (
+    "tower.save.clear", "tower.save.fetch", "tower.save.write",
+    "tower.save.commit")
 # went with the code they timed: the per-shard decode and the concatenate
 # (PR 26), the plane's trip down and second upload (PR 28)
 RETIRED_SPANS = ("data.shard_decode", "data.concat", "nn.repad")
@@ -184,6 +188,24 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
     spans, path = _span_tree(prepared_set)
     paths = [path(s) for s in spans]
     under_train = " < train < process < TRAIN"
+    # what the job pays before its step body, by name: setup's five
+    # children, one each, in order, and nothing of setup outside them
+    (setup,) = [s for s in spans if s["name"] == "setup"]
+    kids = sorted((s for s in spans if s["parent"] == setup["id"]),
+                  key=lambda s: s["ts"])
+    assert tuple(s["name"] for s in kids) == SETUP_SPANS
+    assert all(path(s) == s["name"] + " < setup < TRAIN" for s in kids)
+    assert sum(s["dur_s"] for s in kids) <= setup["dur_s"]
+    by_name = {s["name"]: s["attrs"] for s in kids}
+    cc_path = os.path.join(prepared_set, "ColumnConfig.json")
+    with open(cc_path) as f:
+        assert by_name["setup.columns"] == {
+            "columns": len(json.load(f)), "bytes": os.path.getsize(cc_path)}
+    with open(os.path.join(prepared_set, "tmp", "journal",
+                           "NORMALIZE.json")) as f:
+        assert by_name["setup.precheck"] == {
+            "shards": len(json.load(f)["items"])} and \
+            by_name["setup.precheck"]["shards"] > 0
     shards = Shards.open(os.path.join(prepared_set, "tmp", "NormalizedData"))
     (load,) = [s for s in spans if s["name"] == "data.load"]
     (alloc,) = [s for s in spans if s["name"] == "data.alloc"]
