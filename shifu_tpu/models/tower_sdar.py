@@ -16,13 +16,17 @@ the 1/t-weighted cross-entropy of the masked positions.  Scoring is one
 denoising step of the last block: the tag's token masked, every feature
 clean, score = p(tag = 1) over the tag's two ids.
 
+Attention is ``ops/attention.blocked_attention`` under either mask, handed
+over as data (:func:`attention_plan`): each half of the sequence is padded
+with zeros to whole blocks (:func:`attention_layout`), the pad keys lie in
+nobody's intervals, and no ``[T, T]`` scores exist.
+
 The tokeniser, the ``.tower`` file, ``eval``'s scorer and the refusals are
 every tower's: :mod:`shifu_tpu.models.towers`.
 """
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Tuple
 
@@ -32,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config.errors import ErrorCode, ShifuError
-from ..ops import moe
+from ..ops import attention, moe
 from .towers import RowTokens, load_model  # noqa: F401  (load_model: benchmark/drivers/train_tower.py)
 
 # the step's named scopes, most specific first: device ops carry them
@@ -40,10 +44,11 @@ from .towers import RowTokens, load_model  # noqa: F401  (load_model: benchmark/
 # occurs inside it (the first scope an op's name contains takes the op)
 SCOPES = ("tower/attn", "tower/moe/route", "tower/moe/experts", "tower/head", "tower/input",
           "tower/embed", "tower/trunk", "tower/acc", "tower/opt")
-OBS_COUNTERS = {"masked": "tower.masked_positions"}
+ATTN_COUNTERS = {"attn_key_blocks": "tower.attn_key_blocks",
+                 "attn_key_blocks_dense": "tower.attn_key_blocks_dense",
+                 "attn_pad_positions": "tower.attn_pad_positions"}
+OBS_COUNTERS = {"masked": "tower.masked_positions", **ATTN_COUNTERS}
 T_MIN = 1e-3                        # per block t ~ U(T_MIN, 1]
-ATTN_ROWS = 4                       # rows whose f32 scores are alive at once
-_NEG = float(np.finfo(np.float32).min)
 
 # TowerParams: config.json's keys.  Read: the shapes.  Checked: the keys whose
 # other values would be another architecture.  The rest of config.json
@@ -173,53 +178,75 @@ def _rms(x, w, eps):
 
 
 def _rope(x, pos, theta):
-    """x [n, T, heads, hd]; rotate-half over the whole head."""
+    """x [n, T, heads, hd]; rotate-half over the whole head: ``x cos +
+    [-x2, x1] sin``, each half written once (no rotated copy of x is made)."""
     hd = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attend(q, k, v, mask):
-    """q [c, T, KV, R, hd], k/v [c, T, KV, hd] -> [c, T, KV, R, hd]; scores and
-    softmax in f32."""
-    scores = jnp.einsum("cqgrd,ckgd->cgrqk", q, k,
-                        preferred_element_type=jnp.float32) / math.sqrt(q.shape[-1])
-    probs = jax.nn.softmax(jnp.where(mask, scores, _NEG), axis=-1)
-    return jnp.einsum("cgrqk,ckgd->cqgrd", probs, v, preferred_element_type=jnp.float32)
+def attention_layout(s: int, parts: int) -> Tuple[int, int]:
+    """(half, block) for a sequence of ``parts`` halves of ``s`` positions:
+    a half is padded to the power of two at or above ``s`` (beyond the
+    kernels' ``attention.BLOCK`` to whole blocks of it), and the block is as
+    long as that allows — the whole sequence while it fits one.
+    On the chip (S = 436, PR 36) a half as one block of 512 beat two of 256 by
+    a fifth, 3 block pairs of 4 against 8 of 16 a head: what a query block
+    costs beside its visits (its q, output and statistics moved, 1.1 us)
+    outweighs the scores a smaller block skips."""
+    one = min(attention.BLOCK, max(8, 1 << (s - 1).bit_length()))
+    half = -(-s // one) * one
+    return half, min(attention.BLOCK, parts * half)
 
 
-def _attend_split(q, k, v, mask, s, block):
-    """:func:`_attend` for ``[x_t ; x_0]``, where no query sees a noised key
-    outside its own block: every query against the clean keys (the mask's
-    right half) and, beside it, each noised query against its block's noised
-    keys — half the score pairs of the dense product.  One softmax over both
-    parts, in f32."""
-    c, _, g, r, d = q.shape
-    nb, scale = s // block, 1.0 / math.sqrt(d)
-    clean = jnp.einsum("cqgrd,ckgd->cgrqk", q, k[:, s:],
-                       preferred_element_type=jnp.float32) * scale
-    clean = jnp.where(mask[:, s:], clean, _NEG)                        # [c,g,r,2S,S]
-    own = jnp.einsum("cnqgrd,cnkgd->cgrnqk", q[:, :s].reshape(c, nb, block, g, r, d),
-                     k[:, :s].reshape(c, nb, block, g, d),
-                     preferred_element_type=jnp.float32) * scale       # [c,g,r,nb,B,B]
-    own = jnp.concatenate([own.reshape(c, g, r, s, block),
-                           jnp.full((c, g, r, s, block), _NEG, jnp.float32)], axis=3)
-    top = jax.lax.stop_gradient(jnp.maximum(clean.max(-1), own.max(-1)))[..., None]
-    e_clean, e_own = jnp.exp(clean - top), jnp.exp(own - top)
-    z = e_clean.sum(-1) + e_own.sum(-1)                                # [c,g,r,2S]
-    out = jnp.einsum("cgrqk,ckgd->cqgrd", e_clean, v[:, s:], preferred_element_type=jnp.float32)
-    out_own = jnp.einsum("cgrnqk,cnkgd->cnqgrd", e_own[..., :s, :].reshape(c, g, r, nb, block, block),
-                         v[:, :s].reshape(c, nb, block, g, d),
-                         preferred_element_type=jnp.float32).reshape(c, s, g, r, d)
-    out = out.at[:, :s].add(out_own)
-    return out / jnp.moveaxis(z, 3, 1)[..., None]
+@dataclass(frozen=True, eq=False)
+class AttentionPlan:
+    """How a sequence of ``parts`` halves of ``s`` positions goes through the
+    kernels: each half padded to ``half`` (whole blocks of ``block``), under
+    ``mask`` over the padded positions."""
+    s: int
+    parts: int
+    half: int
+    block: int
+    mask: attention.Mask
+
+    def pad(self, a):
+        """[n, parts x s, ...] -> [n, parts x half, ...], zeros behind each half."""
+        s, zeros = self.s, jnp.zeros((a.shape[0], self.half - self.s) + a.shape[2:], a.dtype)
+        return jnp.concatenate([piece for i in range(self.parts)
+                                for piece in (a[:, i * s:(i + 1) * s], zeros)], axis=1)
+
+    def unpad(self, a):
+        return jnp.concatenate([a[:, i * self.half:i * self.half + self.s]
+                                for i in range(self.parts)], axis=1)
+
+    def counters(self, each: int) -> Dict[str, int]:
+        """:data:`ATTN_COUNTERS` of one sequence: key blocks the kernels'
+        forward visits over ``each`` heads and layers, what a sweep of every
+        block pair would, positions padded."""
+        return {"attn_key_blocks": each * attention.schedule(self.mask, self.block).visits,
+                "attn_key_blocks_dense": each * (self.parts * self.half // self.block) ** 2,
+                "attn_pad_positions": self.parts * (self.half - self.s)}
 
 
-def _attention(p, x, pos, mask, spec: TowerSpec, noised: int = 0):
+def attention_plan(s: int, mask: np.ndarray) -> AttentionPlan:
+    """``mask`` [T, T] bool over T = 1 or 2 halves of ``s`` positions ->
+    the padded layout and the mask's description over it (``ValueError`` for a
+    mask the kernels' description cannot hold)."""
+    t = mask.shape[0]
+    if t % s or mask.shape != (t, t):
+        raise ValueError(f"a mask of {list(mask.shape)} is not over whole halves of {s} positions")
+    half, block = attention_layout(s, t // s)
+    real = (np.arange(t) // s) * half + np.arange(t) % s
+    padded = np.zeros((t // s * half,) * 2, bool)
+    padded[np.ix_(real, real)] = mask
+    return AttentionPlan(s, t // s, half, block, attention.mask_of(padded))
+
+
+def _attention(p, x, pos, plan: AttentionPlan, spec: TowerSpec):
     n, t, _ = x.shape
     h, kv, hd = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
     q = (x @ p["wq"]).reshape(n, t, h, hd)
@@ -227,30 +254,19 @@ def _attention(p, x, pos, mask, spec: TowerSpec, noised: int = 0):
     v = (x @ p["wv"]).reshape(n, t, kv, hd)
     q = _rope(_rms(q, p["q_norm"], spec.rms_norm_eps), pos, spec.rope_theta)
     k = _rope(_rms(k, p["k_norm"], spec.rms_norm_eps), pos, spec.rope_theta)
-    # a few rows' scores at a time, recomputed in the backward pass
-    c = max(d for d in range(1, ATTN_ROWS + 1) if n % d == 0)
-    chunks = lambda a: a.reshape((n // c, c) + a.shape[1:])
-    if noised:
-        b = spec.block_length
-        blk = np.arange(noised) // b
-        if mask[noised:, :noised].any() or (mask[:noised, :noised] != (blk[:, None] == blk)).any():
-            raise ValueError("the mask is not the block-diffusion mask of [x_t ; x_0]")
-        attend = lambda qkv: _attend_split(*qkv, jnp.asarray(mask), noised, b)
-    else:
-        attend = lambda qkv: _attend(*qkv, jnp.asarray(mask))
-
-    def core(qkv):
-        with jax.named_scope("tower/attn"):      # again: under lax.map a checkpointed body names its ops from its own root
-            return attend(qkv)
-    out = jax.lax.map(jax.checkpoint(core),
-                      (chunks(q.reshape(n, t, kv, h // kv, hd)), chunks(k), chunks(v)))
-    return out.reshape(n, t, h * hd) @ p["wo"]
+    # zeros behind each half, after the norms and rotary: a pad key has no
+    # weight, but 0 x NaN would still be NaN in the P.V product.  Padded as
+    # [n, T, heads x hd], the kernels' own layout: grouping the heads is then free
+    grouped = lambda a, *heads: plan.pad(a.reshape(n, t, -1)).reshape((n, plan.mask.seq) + heads + (hd,))
+    out = attention.blocked_attention(grouped(q, kv, h // kv), grouped(k, kv), grouped(v, kv),
+                                      block=plan.block, mask=plan.mask)
+    return plan.unpad(out.reshape(n, plan.mask.seq, h * hd)) @ p["wo"]
 
 
-def _layer(spec: TowerSpec, pos, mask, noised, h, p):
+def _layer(spec: TowerSpec, pos, plan, h, p):
     n, t, d = h.shape
     with jax.named_scope("tower/attn"):
-        h = h + _attention(p, _rms(h, p["ln1"], spec.rms_norm_eps), pos, mask, spec, noised)
+        h = h + _attention(p, _rms(h, p["ln1"], spec.rms_norm_eps), pos, plan, spec)
     x = _rms(h, p["ln2"], spec.rms_norm_eps).reshape(n * t, d)
     with jax.named_scope("tower/moe/route"):
         weights, experts = moe.route(x, p["router"], spec.num_experts_per_tok,
@@ -261,18 +277,19 @@ def _layer(spec: TowerSpec, pos, mask, noised, h, p):
     return h + y.reshape(n, t, d), counters
 
 
-def hidden(params, spec: TowerSpec, ids, pos, mask: np.ndarray,
-           noised: int = 0) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """ids [n, T] -> (final-normed hidden [n, T, D], the layers' MoE counters
-    stacked [L, ...]).  Each layer is recomputed in the backward pass.
-    ``noised``: the first ``noised`` positions are the ``x_t`` of
-    ``[x_t ; x_0]`` under :func:`block_mask` (attention then skips the score
-    pairs that mask never allows)."""
-    layer = jax.checkpoint(lambda h, p: _layer(spec, pos, mask, noised, h, p))
+def hidden(params, spec: TowerSpec, ids, pos, mask: np.ndarray) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """ids [n, T] -> (final-normed hidden [n, T, D], counters: the layers' MoE
+    counters stacked [L, ...] and :data:`ATTN_COUNTERS`, all layers' and heads'
+    of one sequence).  ``mask`` [T, T] bool, True = the query sees the key:
+    :func:`block_mask` over ``[x_t ; x_0]`` or :func:`eval_mask`.  Each layer
+    is recomputed in the backward pass."""
+    plan = attention_plan(spec.seq_len, mask)
+    layer = jax.checkpoint(lambda h, p: _layer(spec, pos, plan, h, p))
     with jax.named_scope("tower/embed"):
         h = params["embed"][ids]
     with jax.named_scope("tower/trunk"):
         h, counters = jax.lax.scan(layer, h, params["layers"])
+        counters.update(plan.counters(spec.num_hidden_layers * spec.num_attention_heads))
         return _rms(h, params["final_norm"], spec.rms_norm_eps), counters
 
 
@@ -288,7 +305,7 @@ def diffusion_loss(params, spec: TowerSpec, x0, t, masked, row_w, mask_id, pad_i
         xt = jnp.where(masked, mask_id, x0)
         pos = jnp.concatenate([jnp.arange(s), jnp.arange(s)])
         ids = jnp.concatenate([xt, x0], axis=1)
-    h, counters = hidden(params, spec, ids, pos, block_mask(s, spec.block_length), noised=s)
+    h, counters = hidden(params, spec, ids, pos, block_mask(s, spec.block_length))
     with jax.named_scope("tower/head"):
         logits = (h[:, :s] @ params["head"]).astype(jnp.float32)
         ce = jax.nn.logsumexp(logits, axis=-1) - \
@@ -296,8 +313,9 @@ def diffusion_loss(params, spec: TowerSpec, x0, t, masked, row_w, mask_id, pad_i
         real = (x0 != pad_id) * row_w[:, None]
         total = jnp.sum(jnp.where(masked, ce / t, 0.0) * real)
         count = jnp.sum(real)
-        aux = {"loss_sum": total, "positions": count,
-               "masked": jnp.sum(masked * real), **counters}
+        live = jnp.sum(row_w > 0).astype(jnp.float32)       # the rows that count
+        aux = {"loss_sum": total, "positions": count, "masked": jnp.sum(masked * real),
+               **counters, **{k: live * counters[k] for k in ATTN_COUNTERS}}
         return total / jnp.maximum(count, 1.0), aux
 
 
@@ -323,7 +341,7 @@ def train_loss(params, spec: TowerSpec, x0, row_w, key, specials):
 
 def counter_shapes(spec: TowerSpec) -> Dict[str, tuple]:
     """``aux``'s counters beside ``loss_sum`` and ``positions``."""
-    return {"masked": (), "pairs": (spec.num_hidden_layers, spec.experts_held),
+    return {**{k: () for k in OBS_COUNTERS}, "pairs": (spec.num_hidden_layers, spec.experts_held),
             "rows": (spec.num_hidden_layers,), "dropped": (spec.num_hidden_layers,)}
 
 

@@ -1,18 +1,30 @@
-"""Blocked causal attention with an online softmax: never more than one block
-of scores alive, and only the key blocks a query block may see are visited.
+"""Blocked attention with an online softmax: never more than one block of
+scores alive, and only the key blocks the mask allows are visited.
 
 :func:`blocked_attention` takes grouped queries ``[n, S, KV, R, hd]`` (R query
-heads share a key-value head) and keys / values ``[n, S, KV, hd]``.  Query
-``i`` sees key ``j`` when ``j <= i`` and, with ``window``, ``i - j < window``.
+heads share a key-value head) and keys / values ``[n, S, KV, hd]``.  **The mask
+is data**: a :class:`Mask` gives every query position the keys it may see as
+at most two half-open intervals ``[lo0, hi0) u [lo1, hi1)`` of key positions —
+causal ``[0, i + 1)``, a window ``[max(i - w + 1, 0), i + 1)``
+(:func:`causal_mask`), or what :func:`mask_of` reads off a dense ``[S, S]``
+statement of any other mask (block-causal, block diffusion over ``[x_t ;
+x_0]``, padded halves whose pad keys lie in nobody's interval).  A mask that
+needs a third interval is refused, never approximated; a query with no key
+gets a zero output, and its cotangent reaches nothing.  No kernel code knows a
+model or a kind of mask.
+
 The sequence is cut into blocks of ``block`` positions (S is a multiple, the
-caller pads; ``window`` is a multiple too).  Query block ``i`` visits the key
-blocks ``max(i - window / block, 0) .. i`` (:func:`key_block_range`): a window
-layer the diagonal block and the ones its window reaches, a full layer the
-lower triangle.  The blocks the mask rules out are not computed and masked:
-the grid's key axis is only as long as the longest visit, a step past a
-query block's visit does nothing and fetches nothing (its block index stands
-still), and only the first and last visited blocks apply a mask inside.
-:func:`visited_key_blocks` is that schedule's count, a number the code holds.
+caller pads).  :func:`schedule` derives, in NumPy at trace time, each query
+block's list of key blocks that hold an allowed pair, whether a visited block
+pair is wholly allowed (no mask applied inside) or cut, and then by which of
+the four bounds (the predicate is evaluated from those alone; the kernels read
+the bounds as an operand: a ``[block, lanes]`` plane a bound, every lane alike,
+where queries are rows, and a ``[4, block]`` block where they are columns),
+and the transposed lists for ``dk`` / ``dv``.  The lists reach the
+index maps as scalar-prefetch tables: the grid's key axis is only as long as
+the longest visit, and a step past a query block's visit does nothing and
+fetches nothing (its block index stands still).  :func:`visited_key_blocks`
+is that schedule's count, a number the code holds.
 
 Three Pallas TPU kernels (``interpret=`` runs them on the CPU, as
 ``ops/hist_pallas.py``'s): the forward keeps a running row maximum, row sum
@@ -35,7 +47,10 @@ the running statistics are f32.
 from __future__ import annotations
 
 import math
-from functools import partial
+from dataclasses import dataclass
+from functools import lru_cache, partial, reduce
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -49,61 +64,166 @@ _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)       # a ruled-out score
 _NT = (((1,), (1,)), ((), ()))  # [a, d] x [b, d] -> [a, b]
 
 
-def key_block_range(i, window_blocks, maximum=jnp.maximum):
-    """(first, last) key block that query block ``i`` visits; ``window_blocks``
-    = window / block, or None for a full causal layer."""
-    return (0 if window_blocks is None else maximum(i - window_blocks, 0)), i
+# ----------------------------------------------------- the mask, the schedule
+@dataclass(frozen=True, eq=False)
+class Mask:
+    """``bounds`` int32 [4, S], rows ``lo0, hi0, lo1, hi1``: query i sees the
+    keys of ``[lo0_i, hi0_i) u [lo1_i, hi1_i)``, the intervals in order and
+    inside the sequence (``0 <= lo0 <= hi0 <= lo1 <= hi1 <= S``); an empty
+    interval has ``lo == hi``."""
+    bounds: np.ndarray
+
+    def __post_init__(self):
+        b = np.asarray(self.bounds)
+        if b.ndim != 2 or b.shape[0] != 4 or not np.issubdtype(b.dtype, np.integer):
+            raise ValueError(f"a mask is an integer [4, S] array of interval bounds, not {b.dtype}{list(b.shape)}")
+        edges = np.concatenate([np.zeros((1, b.shape[1]), b.dtype), b, np.full((1, b.shape[1]), b.shape[1])])
+        if (np.diff(edges, axis=0) < 0).any():
+            raise ValueError("a mask's intervals are in order and inside the sequence: "
+                             "0 <= lo0 <= hi0 <= lo1 <= hi1 <= S")
+        object.__setattr__(self, "bounds", np.ascontiguousarray(b, np.int32))
+
+    @property
+    def seq(self) -> int:
+        return self.bounds.shape[1]
+
+    def dense(self) -> np.ndarray:
+        """[S, S] bool: True = the query (row) sees the key (column)."""
+        key = np.arange(self.seq)[None, :]
+        lo0, hi0, lo1, hi1 = self.bounds[:, :, None]
+        return ((key >= lo0) & (key < hi0)) | ((key >= lo1) & (key < hi1))
 
 
-def visited_key_blocks(seq: int, block: int = BLOCK, window=None) -> int:
-    """Key blocks one head's forward visits over a sequence of ``seq``."""
-    wb = _window_blocks(seq, block, window)
-    total = 0
-    for i in range(seq // block):
-        lo, hi = key_block_range(i, wb, max)
-        total += hi - lo + 1
-    return total
+@lru_cache(maxsize=64)
+def causal_mask(seq: int, window=None) -> Mask:
+    """Query i sees key j when ``j <= i`` and, with ``window``, ``i - j < window``."""
+    i = np.arange(seq)
+    lo = np.zeros(seq, np.int64) if window is None else np.maximum(i - window + 1, 0)
+    return Mask(np.stack([lo, i + 1, i + 1, i + 1]))
 
 
-def _window_blocks(seq: int, block: int, window):
+def mask_of(allowed: np.ndarray) -> Mask:
+    """The description of a dense ``[S, S]`` bool mask (True = the query, a
+    row, sees the key, a column); ``ValueError`` when a query's keys are more
+    than two runs."""
+    a = np.asarray(allowed, bool)
+    if a.ndim != 2 or a.shape[0] != a.shape[1]:
+        raise ValueError(f"a dense mask is [S, S], not {list(a.shape)}")
+    seq = a.shape[0]
+    step = np.diff(np.pad(a.astype(np.int8), ((0, 0), (1, 1))), axis=1)     # +1 a run starts, -1 it ends
+    runs = (step == 1).sum(1)
+    if runs.max(initial=0) > 2:
+        i = int(np.argmax(runs > 2))
+        raise ValueError(f"query {i} sees {int(runs[i])} separate runs of keys: a mask holds two "
+                         f"intervals a query, this one cannot be described")
+    bounds = np.zeros((4, seq), np.int32)
+    for row, edge in ((0, 1), (1, -1)):
+        q, at = np.nonzero(step == edge)                   # in row-major order: a query's first run first
+        first = np.concatenate([[True], q[1:] != q[:-1]])
+        bounds[row, q[first]] = at[first]
+        bounds[row + 2, q[~first]] = at[~first]
+    bounds[2:, runs < 2] = bounds[1, runs < 2]             # no second run: empty, at the first one's end
+    return Mask(bounds)
+
+
+IDLE, PLAIN = 0, 1                 # a step of a visit: past its end; a block pair wholly allowed;
+#                                    1 + m: a pair cut by the bounds of m's bits (lo0 1, hi0 2, lo1 4, hi1 8)
+
+
+@dataclass(frozen=True, eq=False)
+class Schedule:
+    """What the kernels' grids and index maps are built from.  ``q_visits``:
+    two int32 tables [nq x q_steps] — the key block that step t of query
+    block i visits (past the visit the last one again: nothing is fetched)
+    and the step's kind, :data:`IDLE`, :data:`PLAIN` or 1 + the bounds that
+    cut the pair; ``k_visits``: a key block's query blocks, the same way;
+    ``kinds``: the kinds that occur, :data:`IDLE` left out."""
+    block: int
+    bounds: np.ndarray              # the mask's, [4, S]
+    q_steps: int
+    q_visits: tuple
+    k_steps: int
+    k_visits: tuple
+    kinds: tuple
+    visits: int                     # block pairs one head's forward visits
+
+
+def _visit_tables(kind: np.ndarray):
+    """(steps, (blocks, kinds)) of the lists that the rows of ``kind`` (a
+    block pair's, :data:`IDLE` = not visited) hold, ascending."""
+    steps = max(int((kind != IDLE).sum(1).max()), 1)
+    blocks, kinds = np.zeros((2, len(kind), steps), np.int32)
+    for i, row in enumerate(kind):
+        at = np.flatnonzero(row != IDLE)
+        if len(at):
+            blocks[i, :len(at)], blocks[i, len(at):] = at, at[-1]
+            kinds[i, :len(at)] = row[at]
+    return steps, (blocks.reshape(-1), kinds.reshape(-1))
+
+
+@lru_cache(maxsize=64)
+def schedule(mask: Mask, block: int = BLOCK) -> Schedule:
+    """The blocks ``mask`` makes the kernels visit, from shapes alone."""
+    seq = mask.seq
     if seq % block:
         raise ValueError(f"a sequence of {seq} positions is not whole blocks of {block}")
+    nq = seq // block
+    first, end = np.arange(nq, dtype=np.int64) * block, np.arange(1, nq + 1, dtype=np.int64) * block
+    of_block = lambda a, how: how(a.reshape(nq, block, nq), axis=1)        # [S, key block] -> [query block, key block]
+    pairs, cuts = 0, 0
+    for c in (0, 2):                                        # an interval: its keys in each key block, a query
+        lo, hi = mask.bounds[c:c + 2].astype(np.int64)[:, :, None]
+        keys = np.clip(np.minimum(hi, end) - np.maximum(lo, first), 0, None)
+        pairs = pairs + of_block(keys, np.sum)
+        # where some query has a key there, the bounds that rule a key of the block out for some query
+        cuts = cuts + of_block(keys > 0, np.any) * ((1 << c) * of_block(lo > first, np.any) +
+                                                    (2 << c) * of_block(hi < end, np.any))
+    kind = np.where(pairs == 0, IDLE, np.where(pairs == block * block, PLAIN, 1 + cuts))
+    return Schedule(block, mask.bounds, *_visit_tables(kind), *_visit_tables(kind.T),
+                    tuple(int(k) for k in np.unique(kind[kind != IDLE])), int((kind != IDLE).sum()))
+
+
+def visited_key_blocks(seq: int, block: int = BLOCK, window=None, mask: Mask = None) -> int:
+    """Key blocks one head's forward visits over a sequence of ``seq``:
+    causal (``window`` keys back when given), or under ``mask``."""
+    if mask is None:
+        mask = causal_mask(seq, _whole_window(seq, block, window))
+    return schedule(mask, block).visits
+
+
+def _whole_window(seq: int, block: int, window):
+    """``window`` when it cuts anything (None: a full layer), whole blocks."""
     if window is None or window >= seq:
         return None
     if window % block:
         raise ValueError(f"a window of {window} keys is not whole blocks of {block}")
-    return window // block
+    return window
 
 
-def _allowed(i, j, block, wb, transposed=False):
-    """[block, block] bool: which (query, key) pairs of query block ``i`` and
-    key block ``j`` the mask allows (keys x queries when ``transposed``)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    qp, kp = (cols, rows) if transposed else (rows, cols)
-    back = (i - j) * block + qp - kp                       # keys back from the query
-    ok = back >= 0
-    return ok if wb is None else ok & (back < wb * block)
+def _allowed(bounds_ref, j, block, cuts, transposed=False):
+    """[block, block] bool: which (query, key) pairs the mask allows between
+    the query block whose intervals ``bounds_ref`` holds ([bound, block,
+    lanes], every lane alike) and key block ``j``, which the bounds of
+    ``cuts``' bits cut — keys x queries when ``transposed``, the intervals
+    then [bound, block]."""
+    key = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0 if transposed else 1)
+    bound = (lambda c: bounds_ref[c:c + 1, :]) if transposed else \
+        (lambda c: _lanes(bounds_ref[c], block))
+    terms = []
+    for c in (0, 2):                                        # an interval: of its two bounds those that cut
+        tests = ([key >= bound(c)] if cuts >> c & 1 else []) + \
+            ([key < bound(c + 1)] if cuts >> (c + 1) & 1 else [])
+        if tests:
+            terms.append(reduce(jnp.logical_and, tests))
+    return reduce(jnp.logical_or, terms)
 
 
-def _needs_mask(i, j, wb):
-    return (j == i) if wb is None else (j == i) | (j == i - wb)
-
-
-def _visit_if(active, needs_mask, visit):
-    """Run ``visit(masked)`` when the step is inside the visit: the masked
-    variant on the blocks the mask cuts, the plain one elsewhere."""
-    pl.when(active & needs_mask)(lambda: visit(True))
-    pl.when(active & jnp.logical_not(needs_mask))(lambda: visit(False))
-
-
-def _kv_index(wb, r):
-    """Index map of a key / value block for grid point (sequence, query head,
-    query block, step): the step's key block, standing still past the visit."""
-    def at(b, h, i, t):
-        lo, hi = key_block_range(i, wb)
-        return b, jnp.minimum(lo + t, hi), h // r
-    return at
+def _visit_if(kind, kinds, visit):
+    """Run ``visit(cuts)`` when the step is inside the visit: the variant of
+    the step's kind, of the ``kinds`` the schedule holds — plain (``cuts`` 0)
+    on a block pair wholly allowed, masked by the bounds that cut it elsewhere."""
+    for k in kinds:
+        pl.when(kind == k)(partial(visit, k - PLAIN))
 
 
 def _lanes(x, width):
@@ -112,11 +232,27 @@ def _lanes(x, width):
     return x if width == x.shape[1] else jnp.tile(x, (1, reps))[:, :width]
 
 
+def _call(kernel, name, semantics, grid, in_specs, out_specs, out_shape, scratch_shapes, interpret):
+    """A kernel over ``grid`` whose index maps and body read a direction's two
+    tables of the schedule (scalar prefetch, the first operands)."""
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        name=name, interpret=interpret)
+
+
+_WALK = ("parallel", "parallel", "parallel", "arbitrary")  # the last grid axis walks a visit
+
+
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *, block, wb, steps):
-    i, t = pl.program_id(2), pl.program_id(3)
-    lo, hi = key_block_range(i, wb)
-    j = lo + t
+def _fwd_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, o_ref, lse_ref,
+                m_s, l_s, acc_s, *, block, steps, kinds):
+    i, t = pl.program_id(1), pl.program_id(3)
+    at = i * steps + t
 
     @pl.when(t == 0)
     def _():
@@ -124,10 +260,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *, block, 
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def visit(masked):
-        s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT, preferred_element_type=jnp.float32)
-        if masked:
-            s = jnp.where(_allowed(i, j, block, wb), s, _MASK)
+    def visit(cuts):
+        s = jax.lax.dot_general(q_ref[...], kv_ref[0], _NT, preferred_element_type=jnp.float32)
+        if cuts:
+            s = jnp.where(_allowed(bounds_ref, blocks_ref[at], block, cuts), s, _MASK)
         m_prev = m_s[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_next, block))
@@ -135,192 +271,193 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *, block, 
         l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=-1, keepdims=True)
         m_s[...] = m_next
         acc_s[...] = _lanes(alpha, acc_s.shape[1]) * acc_s[...] + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32)
+            p.astype(kv_ref.dtype), kv_ref[1], preferred_element_type=jnp.float32)
 
-    _visit_if(j <= hi, _needs_mask(i, j, wb), visit)
+    _visit_if(kinds_ref[at], kinds, visit)
 
     @pl.when(t == steps - 1)
     def _():
-        l = l_s[...]
-        o_ref[...] = acc_s[...] * _lanes(1.0 / l, acc_s.shape[1])
-        lse_ref[...] = m_s[...] + jnp.log(l)
+        # a query that saw no key: output 0, and a log-sum-exp under which the
+        # backward's probabilities are 0
+        m, l = m_s[...], l_s[...]
+        dead = m == _MASK
+        o_ref[...] = acc_s[...] * _lanes(jnp.where(dead, 0.0, 1.0 / l), acc_s.shape[1])
+        # the log-sum-exp leaves as a row, as the backward reads it: the
+        # diagonal of the column's copies along the lanes, summed down the rows
+        lse = _lanes(jnp.where(dead, -_MASK, m + jnp.log(l)), block)
+        eye = jax.lax.broadcasted_iota(jnp.int32, lse.shape, 0) == \
+            jax.lax.broadcasted_iota(jnp.int32, lse.shape, 1)
+        lse_ref[...] = jnp.sum(jnp.where(eye, lse, 0.0), axis=0, keepdims=True)
 
 
-def _grid_steps(nq: int, wb) -> int:
-    """The key axis of the grid: the longest visit of any query block."""
-    return nq if wb is None else min(wb + 1, nq)
+def _specs(plan: Schedule, seq, hd, r):
+    """(a query block, the visit's key and value block, the query block's
+    intervals and those as an operand) for grid point (sequence, query block,
+    query head, step): the heads of a query block share its intervals, which
+    are fetched once for them all."""
+    block, steps, lanes = plan.block, plan.q_steps, min(128, plan.block)
+    bounds = plan.bounds[:max(1, (max(plan.kinds) - PLAIN).bit_length())]    # up to the last that cuts a pair
+    return (pl.BlockSpec((None, block, hd), lambda b, i, h, t, *_: (b, i, h)),
+            pl.BlockSpec((None, 2, block, hd),
+                         lambda b, i, h, t, blocks, kinds: (b, 0, blocks[i * steps + t], h // r)),
+            pl.BlockSpec((len(bounds), block, lanes), lambda b, i, h, t, *_: (0, i, 0)),
+            jnp.broadcast_to(jnp.asarray(bounds)[:, :, None], (len(bounds), seq, lanes)))
 
 
-def _forward(q, k, v, wb, block, heads, interpret):
+def _forward(q, kv, plan: Schedule, heads, interpret):
     n, seq, width = q.shape
-    hd = width // heads
-    r = heads // (k.shape[2] // hd)
+    hd, block = width // heads, plan.block
     nq, lanes = seq // block, min(128, block)
-    steps = _grid_steps(nq, wb)
-    kv_at, at_q = _kv_index(wb, r), lambda b, h, i, t: (b, i, h)
-    o, lse = pl.pallas_call(
-        partial(_fwd_kernel, block=block, wb=wb, steps=steps),
-        grid=(n, heads, nq, steps),
-        in_specs=[pl.BlockSpec((None, block, hd), at_q),
-                  pl.BlockSpec((None, block, hd), kv_at),
-                  pl.BlockSpec((None, block, hd), kv_at)],
-        out_specs=[pl.BlockSpec((None, block, hd), at_q),
-                   pl.BlockSpec((None, None, block, lanes), lambda b, h, i, t: (b, h, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, seq, width), jnp.float32),
-                   jax.ShapeDtypeStruct((n, heads, seq, lanes), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block, lanes), jnp.float32),
-                        pltpu.VMEM((block, lanes), jnp.float32),
-                        pltpu.VMEM((block, hd), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        name="blocked_attention_fwd", interpret=interpret,
-    )(q, k, v)
-    return o, lse[..., 0]
+    at_q, at_kv, at_bounds, bounds = _specs(plan, seq, hd, heads // (kv.shape[3] // hd))
+    o, lse = _call(
+        partial(_fwd_kernel, block=block, steps=plan.q_steps, kinds=plan.kinds),
+        "blocked_attention_fwd", _WALK, (n, nq, heads, plan.q_steps),
+        [at_q, at_kv, at_bounds],
+        [at_q, pl.BlockSpec((None, None, 1, block), lambda b, i, h, t, *_: (b, h, 0, i))],
+        [jax.ShapeDtypeStruct((n, seq, width), jnp.float32),
+         jax.ShapeDtypeStruct((n, heads, 1, seq), jnp.float32)],
+        [pltpu.VMEM((block, lanes), jnp.float32), pltpu.VMEM((block, lanes), jnp.float32),
+         pltpu.VMEM((block, hd), jnp.float32)], interpret,
+    )(*plan.q_visits, q, kv, bounds)
+    return o, lse[:, :, 0]
 
 
 # ----------------------------------------------------------------- backward
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_s, *, block, wb, steps):
-    i, t = pl.program_id(2), pl.program_id(3)
-    lo, hi = key_block_range(i, wb)
-    j = lo + t
+def _dq_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, do_ref, rows_ref, dq_ref, acc_s, *,
+               block, steps, kinds):
+    i, t = pl.program_id(1), pl.program_id(3)
+    at = i * steps + t
 
     @pl.when(t == 0)
     def _():
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def visit(masked):
-        s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT, preferred_element_type=jnp.float32)
-        if masked:
-            s = jnp.where(_allowed(i, j, block, wb), s, _MASK)
-        p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
-        dp = jax.lax.dot_general(do_ref[...], v_ref[...], _NT, preferred_element_type=jnp.float32)
-        ds = p * (dp - jnp.expand_dims(delta_ref[0], -1))
-        acc_s[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[...], preferred_element_type=jnp.float32)
+    def visit(cuts):
+        k = kv_ref[0]
+        s = jax.lax.dot_general(q_ref[...], k, _NT, preferred_element_type=jnp.float32)
+        if cuts:
+            s = jnp.where(_allowed(bounds_ref, blocks_ref[at], block, cuts), s, _MASK)
+        p = jnp.exp(s - jnp.expand_dims(rows_ref[0], -1))   # the rows' log-sum-exp
+        dp = jax.lax.dot_general(do_ref[...], kv_ref[1], _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - jnp.expand_dims(rows_ref[1], -1))    # their delta
+        acc_s[...] += jnp.dot(ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
-    _visit_if(j <= hi, _needs_mask(i, j, wb), visit)
+    _visit_if(kinds_ref[at], kinds, visit)
 
     @pl.when(t == steps - 1)
     def _():
         dq_ref[...] = acc_s[...]
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
-                block, wb, steps, nq, r):
-    j, t = pl.program_id(2), pl.program_id(3)
-    i = j + t % steps                                       # the query block, of head t // steps
+def _dkv_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, do_ref, rows_ref, dkv_ref, acc_s, *,
+                block, steps, kinds):
+    j, head, t = pl.program_id(2), pl.program_id(3), pl.program_id(4)
 
-    @pl.when(t == 0)
+    @pl.when((head == 0) & (t == 0))
     def _():
-        dk_s[...] = jnp.zeros_like(dk_s)
-        dv_s[...] = jnp.zeros_like(dv_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
 
-    def visit(masked):
-        s = jax.lax.dot_general(k_ref[...], q_ref[...], _NT, preferred_element_type=jnp.float32)
-        if masked:
-            s = jnp.where(_allowed(i, j, block, wb, transposed=True), s, _MASK)
-        p = jnp.exp(s - lse_ref[...])                       # [keys, queries] - [1, queries]
-        dv_s[...] += jnp.dot(p.astype(do_ref.dtype), do_ref[...], preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(v_ref[...], do_ref[...], _NT, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[...])
-        dk_s[...] += jnp.dot(ds.astype(q_ref.dtype), q_ref[...], preferred_element_type=jnp.float32)
+    def visit(cuts):
+        s = jax.lax.dot_general(kv_ref[0], q_ref[...], _NT, preferred_element_type=jnp.float32)
+        if cuts:
+            s = jnp.where(_allowed(bounds_ref, j, block, cuts, transposed=True), s, _MASK)
+        p = jnp.exp(s - rows_ref[0:1, :])                   # [keys, queries] - [1, queries]: the log-sum-exp
+        acc_s[1] += jnp.dot(p.astype(do_ref.dtype), do_ref[...], preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(kv_ref[1], do_ref[...], _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - rows_ref[1:2, :])                    # the queries' delta
+        acc_s[0] += jnp.dot(ds.astype(q_ref.dtype), q_ref[...], preferred_element_type=jnp.float32)
 
-    _visit_if(i < nq, _needs_mask(i, j, wb), visit)
+    _visit_if(kinds_ref[j * steps + t], kinds, visit)
 
-    @pl.when(t == r * steps - 1)
+    @pl.when((head == pl.num_programs(3) - 1) & (t == steps - 1))
     def _():
-        dk_ref[...] = dk_s[...]
-        dv_ref[...] = dv_s[...]
+        dkv_ref[...] = acc_s[...]
 
 
-def _backward(q, k, v, o, lse, do, wb, block, heads, interpret):
+def _backward(q, kv, o, lse, do, plan: Schedule, heads, interpret):
     n, seq, width = q.shape
-    hd = width // heads
-    kv = k.shape[2] // hd
-    r = heads // kv
+    hd, block = width // heads, plan.block
+    groups = kv.shape[3] // hd
+    r = heads // groups
     nq = seq // block
-    steps = _grid_steps(nq, wb)
-    delta = jnp.sum((do * o).reshape(n, seq, heads, hd), axis=-1).transpose(0, 2, 1)[:, :, None]
-    lse, do = lse[:, :, None], do.astype(q.dtype)           # [n, heads, 1, S]: a row a block
+    delta = jnp.sum((do * o).reshape(n, seq, heads, hd), axis=-1).transpose(0, 2, 1)
+    rows, do = jnp.stack([lse, delta], axis=2), do.astype(q.dtype)    # [n, heads, 2, S]: two rows a block
 
-    kv_at, at_q = _kv_index(wb, r), lambda b, h, i, t: (b, i, h)
-    row_q = lambda b, h, i, t: (b, h, 0, i)
-    dq = pl.pallas_call(
-        partial(_dq_kernel, block=block, wb=wb, steps=steps),
-        grid=(n, heads, nq, steps),
-        in_specs=[pl.BlockSpec((None, block, hd), at_q),
-                  pl.BlockSpec((None, block, hd), kv_at),
-                  pl.BlockSpec((None, block, hd), kv_at),
-                  pl.BlockSpec((None, block, hd), at_q),
-                  pl.BlockSpec((None, None, 1, block), row_q),
-                  pl.BlockSpec((None, None, 1, block), row_q)],
-        out_specs=pl.BlockSpec((None, block, hd), at_q),
-        out_shape=jax.ShapeDtypeStruct((n, seq, width), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block, hd), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        name="blocked_attention_dq", interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    at_q, at_kv, at_bounds, bounds = _specs(plan, seq, hd, r)
+    rows_q = pl.BlockSpec((None, None, 2, block), lambda b, i, h, t, *_: (b, h, 0, i))
+    dq = _call(
+        partial(_dq_kernel, block=block, steps=plan.q_steps, kinds=plan.kinds),
+        "blocked_attention_dq", _WALK, (n, nq, heads, plan.q_steps),
+        [at_q, at_kv, at_bounds, at_q, rows_q], at_q,
+        jax.ShapeDtypeStruct((n, seq, width), jnp.float32),
+        [pltpu.VMEM((block, hd), jnp.float32)], interpret,
+    )(*plan.q_visits, q, kv, bounds, do, rows)
 
-    # a key block's query blocks: j .. j + steps - 1 (those past the sequence
-    # do nothing), over the r query heads that share the key-value head
-    q_block = lambda j, t: jnp.minimum(j + t % steps, nq - 1)
-    q_of = lambda b, g, j, t: (b, q_block(j, t), g * r + t // steps)
-    row_of = lambda b, g, j, t: (b, g * r + t // steps, 0, q_block(j, t))
-    at_kv = lambda b, g, j, t: (b, j, g)
-    dk, dv = pl.pallas_call(
-        partial(_dkv_kernel, block=block, wb=wb, steps=steps, nq=nq, r=r),
-        grid=(n, kv, nq, r * steps),
-        in_specs=[pl.BlockSpec((None, block, hd), q_of),
-                  pl.BlockSpec((None, block, hd), at_kv),
-                  pl.BlockSpec((None, block, hd), at_kv),
-                  pl.BlockSpec((None, block, hd), q_of),
-                  pl.BlockSpec((None, None, 1, block), row_of),
-                  pl.BlockSpec((None, None, 1, block), row_of)],
-        out_specs=[pl.BlockSpec((None, block, hd), at_kv)] * 2,
-        out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((block, hd), jnp.float32)] * 2,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        name="blocked_attention_dkv", interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    # grid point (sequence, key-value head, key block, query head of the r
+    # that share it, step): a key block's query blocks, a head after the other
+    steps = plan.k_steps
+    q_of = pl.BlockSpec((None, block, hd), lambda b, g, j, h, t, blocks, kinds:
+                        (b, blocks[j * steps + t], g * r + h))
+    rows_of = pl.BlockSpec((None, None, 2, block), lambda b, g, j, h, t, blocks, kinds:
+                           (b, g * r + h, 0, blocks[j * steps + t]))
+    bounds_of = pl.BlockSpec((4, block), lambda b, g, j, h, t, blocks, kinds:
+                             (0, blocks[j * steps + t]))
+    at_kv = pl.BlockSpec((None, 2, block, hd), lambda b, g, j, h, t, *_: (b, 0, j, g))
+    dkv = _call(
+        partial(_dkv_kernel, block=block, steps=steps, kinds=plan.kinds),
+        "blocked_attention_dkv", _WALK + ("arbitrary",), (n, groups, nq, r, steps),
+        [q_of, at_kv, bounds_of, q_of, rows_of], at_kv,
+        jax.ShapeDtypeStruct(kv.shape, jnp.float32),
+        [pltpu.VMEM((2, block, hd), jnp.float32)], interpret,
+    )(*plan.k_visits, q, kv, plan.bounds, do, rows)
+    return dq, dkv[:, 0], dkv[:, 1]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _attend(q, k, v, wb, block, heads, interpret):
-    return _attend_fwd(q, k, v, wb, block, heads, interpret)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attend(q, k, v, plan, heads, interpret):
+    return _attend_fwd(q, k, v, plan, heads, interpret)[0]
 
 
-def _attend_fwd(q, k, v, wb, block, heads, interpret):
+def _attend_fwd(q, k, v, plan, heads, interpret):
     dt = mxu_operand_dtype(q)
-    q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)      # once, not a block
-    o, lse = _forward(q, k, v, wb, block, heads, interpret)
-    return o, (q, k, v, o, lse)
+    q, kv = q.astype(dt), jnp.stack([k, v], axis=1).astype(dt)     # once, not a block; [n, 2, S, KV x hd]
+    o, lse = _forward(q, kv, plan, heads, interpret)
+    return o, (q, kv, o, lse)
 
 
-def _attend_bwd(wb, block, heads, interpret, res, do):
-    q, k, v, o, lse = res
-    dq, dk, dv = _backward(q, k, v, o, lse, do, wb, block, heads, interpret)
-    return dq.astype(do.dtype), dk.astype(do.dtype), dv.astype(do.dtype)
+def _attend_bwd(plan, heads, interpret, res, do):
+    q, kv, o, lse = res
+    return tuple(g.astype(do.dtype) for g in _backward(q, kv, o, lse, do, plan, heads, interpret))
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, window=None,
-                      block: int = BLOCK, interpret=None) -> jnp.ndarray:
-    """Causal grouped-query attention, ``window`` keys back when given.
+                      block: int = BLOCK, interpret=None, mask: Mask = None) -> jnp.ndarray:
+    """Grouped-query attention under ``mask``; without one causal, ``window``
+    keys back when given.
 
     q [n, S, KV, R, hd], k / v [n, S, KV, hd], f32 -> [n, S, KV, R, hd] f32:
-    ``softmax_j(q_i . k_j / sqrt(hd)) v_j`` over the keys ``j <= i`` (and ``i -
-    j < window``).  S is a multiple of ``block``, and so is a ``window``
-    shorter than S (a longer one is a full layer).  ``interpret`` None: the
-    Pallas interpreter anywhere but on a TPU."""
+    ``softmax_j(q_i . k_j / sqrt(hd)) v_j`` over the keys j that ``mask``
+    gives query i (0 where it gives none) — without a mask the keys ``j <= i``
+    (and ``i - j < window``).  S is a multiple of ``block``, and so is a
+    ``window`` shorter than S (a longer one is a full layer).  ``interpret``
+    None: the Pallas interpreter anywhere but on a TPU."""
     n, seq, kv, r, hd = q.shape
-    wb = _window_blocks(seq, block, window)
+    if mask is None:
+        mask = causal_mask(seq, _whole_window(seq, block, window))
+    elif window is not None or mask.seq != seq:
+        raise ValueError(f"a mask of {mask.seq} positions and window {window} for a sequence "
+                         f"of {seq}: a mask says it all, and for every position")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    o = _attend((q * (1.0 / math.sqrt(hd))).reshape(n, seq, kv * r * hd),
-                k.reshape(n, seq, kv * hd), v.reshape(n, seq, kv * hd),
-                wb, block, kv * r, bool(interpret))
+    attend = partial(_attend, plan=schedule(mask, block), heads=kv * r, interpret=bool(interpret))
+    q, k, v = (q.reshape(n, seq, kv * r * hd) * (1.0 / math.sqrt(hd)),
+               k.reshape(n, seq, kv * hd), v.reshape(n, seq, kv * hd))
+    if interpret and n > 1:
+        # the interpreter copies every operand whole at each grid step: a sequence a call
+        o = jax.lax.map(lambda one: attend(*(a[None] for a in one))[0], (q, k, v))
+    else:
+        o = attend(q, k, v)
     return o.reshape(n, seq, kv, r, hd)

@@ -1,10 +1,12 @@
 """``ops/attention.blocked_attention`` (the Pallas kernels, interpreted on the
 CPU) against dense masked softmax attention (``tests/helpers/dense_attention``):
-forward and the gradients of q, k, v for both mask kinds, the block schedule's
-count against a hand count."""
+forward and the gradients of q, k, v under the causal masks and under masks
+handed over as data (``sdar_moe``'s two, over halves padded to toy blocks), the
+block schedule's count against a hand count."""
 
 import os
 import sys
+from functools import partial
 
 import numpy as np
 import pytest
@@ -12,12 +14,29 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from shifu_tpu.models.tower_sdar import block_mask, eval_mask
 from shifu_tpu.ops import attention
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "helpers"))
 from dense_attention import allowed, dense_attention  # noqa: E402
 
 BLOCK, W = 16, 32                   # a window of two blocks
+TOY = 8                             # the general masks' block
+
+
+def _halves(mask, s, block=TOY):
+    """``mask`` over halves of ``s`` positions -> over halves padded to whole
+    blocks: a pad key lies in nobody's row, a pad query sees nothing."""
+    half = -(-s // block) * block
+    real = (np.arange(len(mask)) // s) * half + np.arange(len(mask)) % s
+    out = np.zeros((len(mask) // s * half,) * 2, bool)
+    out[np.ix_(real, real)] = mask
+    return out
+
+
+# sdar_moe's masks at block length 4: [x_t ; x_0] under the block-diffusion mask, one half under eval's
+GENERAL = {f"{name}-{s}": _halves(fn(s, 4), s) for name, fn in (("diffusion", block_mask), ("eval", eval_mask))
+           for s in (12, 20)}
 
 
 def _qkv(seq, kv, r, hd=16, n=2, seed=0):
@@ -29,17 +48,23 @@ def _qkv(seq, kv, r, hd=16, n=2, seed=0):
 
 
 @pytest.mark.parametrize("r", [1, 8])
-@pytest.mark.parametrize("seq", [BLOCK, W, 4 * W + BLOCK])
-@pytest.mark.parametrize("window", [None, W])
+@pytest.mark.parametrize("window,seq", [(w, seq) for w in (None, W) for seq in (BLOCK, W, 4 * W + BLOCK)] +
+                         [(name, len(dense)) for name, dense in GENERAL.items()])
 def test_forward_and_gradients_match_dense_masked_attention(window, seq, r):
+    """``window``: None or a number of keys (causal), or the name of a mask
+    handed over as data."""
     q, k, v, c = _qkv(seq, 2 if r == 1 else 1, r)
+    if window in GENERAL:
+        attend = partial(attention.blocked_attention, block=TOY, mask=attention.mask_of(GENERAL[window]))
+        dense = partial(dense_attention, mask=GENERAL[window])
+    else:
+        attend = partial(attention.blocked_attention, window=window, block=BLOCK)
+        dense = partial(dense_attention, window=window)
     both = lambda fn: jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * c), argnums=(0, 1, 2))
-    got, got_grads = jax.jit(both(
-        lambda q, k, v: attention.blocked_attention(q, k, v, window, BLOCK)))(q, k, v)
-    want, want_grads = both(lambda q, k, v: dense_attention(q, k, v, window))(q, k, v)
+    got, got_grads = jax.jit(both(attend))(q, k, v)
+    want, want_grads = both(dense)(q, k, v)
     np.testing.assert_allclose(got, want, rtol=1e-5)
-    np.testing.assert_allclose(attention.blocked_attention(q, k, v, window, BLOCK),
-                               dense_attention(q, k, v, window), atol=2e-6)
+    np.testing.assert_allclose(attend(q, k, v), dense(q, k, v), atol=2e-6)
     for a, b in zip(got_grads, want_grads):
         assert a.dtype == jnp.float32
         np.testing.assert_allclose(a, b, atol=2e-5)
@@ -71,13 +96,62 @@ def test_a_window_as_long_as_the_sequence_is_a_full_layer():
 
 @pytest.mark.parametrize("seq,block,window,by_hand", [
     (16, 16, None, 1), (64, 16, None, 1 + 2 + 3 + 4), (64, 16, 16, 1 + 2 + 2 + 2),
-    (64, 16, 32, 1 + 2 + 3 + 3), (8192, 512, None, 136), (8192, 512, 2048, 1 + 2 + 3 + 4 + 12 * 5)])
+    (64, 16, 32, 1 + 2 + 3 + 3), (8192, 512, None, 136), (8192, 512, 2048, 1 + 2 + 3 + 4 + 12 * 5),
+    # [x_t ; x_0], halves of 16 (12 real) in blocks of 8: a noised block its own and the clean one
+    # before it, 2 + 2; the clean blocks themselves and those before them, 1 + 2
+    (32, 8, "diffusion-12", 2 + 2 + 1 + 2),
+    (48, 8, "diffusion-20", 2 + 3 + 3 + 1 + 2 + 3), (16, 8, "eval-12", 1 + 2),
+    # the cell sdar-train: halves of 512 (436 real); a noised half its own block and the clean one, the clean one itself
+    (1024, 512, _halves(block_mask(436, 4), 436, 512), 2 + 1),
+    (1024, 256, _halves(block_mask(436, 4), 436, 256), 2 + 3 + 1 + 2),
+    (512, 512, _halves(eval_mask(436, 4), 436, 512), 1)])
 def test_visited_key_blocks_against_a_hand_count(seq, block, window, by_hand):
-    assert attention.visited_key_blocks(seq, block, window) == by_hand
+    """``window`` as in the test above, or a dense mask itself."""
+    dense = GENERAL[window] if isinstance(window, str) else window if isinstance(window, np.ndarray) else None
+    if dense is None:
+        assert attention.visited_key_blocks(seq, block, window) == by_hand
+    else:
+        assert attention.visited_key_blocks(seq, block, mask=attention.mask_of(dense)) == by_hand
+        assert (attention.mask_of(dense).dense() == dense).all()
     # the schedule covers the mask: every allowed pair lies in a visited block
-    if seq <= 64:
-        ok = allowed(seq, window).reshape(seq // block, block, seq // block, block).any((1, 3))
+    if seq <= 1024:
+        ok = (allowed(seq, window) if dense is None else dense).reshape(
+            seq // block, block, seq // block, block).any((1, 3))
         assert int(ok.sum()) == by_hand
+
+
+def test_pad_keys_have_no_weight_and_pad_queries_reach_nothing():
+    dense = GENERAL["diffusion-12"]                         # halves of 16, the last 4 of each a pad
+    mask, pad = attention.mask_of(dense), ~dense.any(0)
+    assert pad.sum() == 8 and (~dense.any(1) == pad).all()
+    q, k, v, c = _qkv(len(dense), 1, 8)
+    attend = partial(attention.blocked_attention, block=TOY, mask=mask)
+    out = attend(q, k, v)
+    assert (np.asarray(out)[:, pad] == 0).all()             # a query that sees no key
+    # whatever stands at a pad key moves no output ...
+    loud = lambda a: a.at[:, pad].set(1e3)
+    np.testing.assert_array_equal(attend(q, loud(k), loud(v)), out)
+    # ... and a cotangent at the pad queries alone reaches no q, k or v
+    grads = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * c * pad[None, :, None, None, None]),
+                     argnums=(0, 1, 2))(q, k, v)
+    assert all(not np.asarray(g).any() for g in grads)
+    real = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * c), argnums=(1, 2))(q, k, v)
+    assert all(np.asarray(g)[:, ~pad].any() and not np.asarray(g)[:, pad].any() for g in real)
+
+
+def test_a_mask_the_description_cannot_hold_is_refused():
+    comb = np.eye(8, dtype=bool) | np.eye(8, k=3, dtype=bool) | np.eye(8, k=-3, dtype=bool)
+    with pytest.raises(ValueError, match="query 3 sees 3 separate runs of keys"):
+        attention.mask_of(comb)
+    two = np.eye(8, dtype=bool) | np.eye(8, k=-3, dtype=bool)          # two runs a query: held
+    assert (attention.mask_of(two).dense() == two).all()
+    with pytest.raises(ValueError, match="in order and inside the sequence"):
+        attention.Mask(np.array([[0, 2], [3, 1], [3, 2], [3, 2]]))     # query 1: hi0 < lo0
+    q, k, v, _ = _qkv(16, 1, 1)
+    with pytest.raises(ValueError, match="a mask says it all"):
+        attention.blocked_attention(q, k, v, window=8, block=8, mask=attention.causal_mask(16))
+    with pytest.raises(ValueError, match="a mask says it all"):
+        attention.blocked_attention(q, k, v, block=8, mask=attention.causal_mask(32))
 
 
 def test_shapes_the_blocks_do_not_divide_are_refused():

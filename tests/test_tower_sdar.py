@@ -4,6 +4,7 @@ weights, toy size (hidden 64, 4/2 heads of 16, 8 experts top-2 of width 32 —
 4 held by each of 2 ranks —, 2 layers, 97 ids, B = 4, S = 12), on the CPU.
 """
 
+import json
 import os
 import shutil
 import sys
@@ -138,20 +139,27 @@ def test_block_mask_against_two_loops(s, block):
 
 
 # -------------------------------------------- forward, loss, every gradient
-@pytest.mark.parametrize("noised", [12, 0], ids=["split", "dense"])
+@pytest.mark.parametrize("mask", ["split", "dense"])
 @pytest.mark.parametrize("rank", [0, 1])
-def test_forward_logits_match_the_reference(case, rank, noised):
-    """``noised``: attention over the clean keys and each block's own noised
-    keys only (what training runs), or the dense masked product."""
+def test_forward_logits_match_the_reference(case, rank, mask):
+    """Both masks through the attention kernels: ``split``, what training runs
+    (``[x_t ; x_0]`` under the block-diffusion mask), and ``dense``, ``eval``'s
+    (one half, block-causal)."""
     c = case[rank]
     spec, s = c["spec"], c["spec"].seq_len
     xt = jnp.where(c["masked"], spec.special("MASK"), jnp.asarray(c["ids"]))
-    h, _ = tw.hidden(c["params"], spec, jnp.concatenate([xt, jnp.asarray(c["ids"])], 1),
-                     jnp.concatenate([jnp.arange(s), jnp.arange(s)]), tw.block_mask(s, 4),
-                     noised=noised)
-    got = np.asarray(h[:, :s] @ c["params"]["head"])
-    want = ref.forward_logits(_np(c["params"]), c["ids"], c["masked"], TOY, spec.expert_lo,
-                              COL_BINS, 4)
+    if mask == "split":
+        h, _ = tw.hidden(c["params"], spec, jnp.concatenate([xt, jnp.asarray(c["ids"])], 1),
+                         jnp.concatenate([jnp.arange(s), jnp.arange(s)]), tw.block_mask(s, 4))
+        got = np.asarray(h[:, :s] @ c["params"]["head"])
+        want = ref.forward_logits(_np(c["params"]), c["ids"], c["masked"], TOY, spec.expert_lo,
+                                  COL_BINS, 4)
+    else:
+        h, _ = tw.hidden(c["params"], spec, xt, jnp.arange(s), tw.eval_mask(s, 4))
+        got = np.asarray(h @ c["params"]["head"])
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(ref.hidden(c["params"], xt, jnp.arange(s), ref.eval_mask(s, 4), TOY,
+                                         spec.expert_lo) @ c["params"]["head"])
     assert got.shape == want.shape == (6, 12, 97)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -181,12 +189,61 @@ def test_eval_score_is_one_denoising_step_of_the_tag_block(case):
     np.testing.assert_allclose(got, 1.0 / (1.0 + np.exp(-d)), atol=1e-6)
 
 
-def test_split_attention_refuses_another_mask():
+def test_a_mask_the_kernels_cannot_describe_is_refused():
     spec = _spec()
-    ids = jnp.zeros((2, 24), jnp.int32)
-    with pytest.raises(ValueError, match="not the block-diffusion mask"):
-        tw.hidden(_params(spec), spec, ids, jnp.arange(24), np.tril(np.ones((24, 24), bool)),
-                  noised=12)
+    comb = (np.arange(12)[:, None] - np.arange(12)[None, :]) % 3 == 0    # every third key: four runs a query
+    with pytest.raises(ValueError, match="separate runs of keys"):
+        tw.hidden(_params(spec), spec, jnp.zeros((2, 12), jnp.int32), jnp.arange(12), comb)
+    with pytest.raises(ValueError, match="not over whole halves of 12"):
+        tw.attention_plan(12, np.ones((18, 18), bool))
+
+
+@pytest.mark.parametrize("s,block,half", [(12, 32, 16), (20, 64, 32), (436, 512, 512), (600, 512, 1024)])
+def test_attention_plan_pads_each_half_to_whole_blocks(s, block, half):
+    """The padded description allows exactly the mask's pairs, moved to the
+    padded positions: a pad key lies in nobody's intervals, a pad query has none."""
+    from shifu_tpu.ops import attention
+    plan = tw.attention_plan(s, tw.block_mask(s, 4))
+    assert (plan.block, plan.half, plan.parts, plan.mask.seq) == (block, half, 2, 2 * half)
+    real = np.concatenate([np.arange(s), half + np.arange(s)])
+    dense = plan.mask.dense()
+    assert (dense[np.ix_(real, real)] == tw.block_mask(s, 4)).all()
+    assert dense.sum() == tw.block_mask(s, 4).sum()
+    x = jnp.arange(2 * 2 * s * 3, dtype=jnp.float32).reshape(2, 2 * s, 3) + 1.0
+    padded = np.asarray(plan.pad(x))
+    assert padded.shape == (2, 2 * half, 3) and (padded[:, real] == np.asarray(x)).all()
+    assert padded.sum() == float(x.sum()) and (np.asarray(plan.unpad(plan.pad(x))) == np.asarray(x)).all()
+    got = plan.counters(each=3)
+    assert got["attn_pad_positions"] == 2 * (half - s)
+    assert got["attn_key_blocks"] == 3 * attention.visited_key_blocks(2 * half, block, mask=plan.mask)
+    assert got["attn_key_blocks_dense"] == 3 * (2 * half // block) ** 2
+    if s == 436:                                            # the cell: 3 of 4 block pairs a head, 152 of 1,024 positions
+        assert (got["attn_key_blocks"], got["attn_key_blocks_dense"], got["attn_pad_positions"]) == (9, 12, 152)
+    one = tw.attention_plan(s, tw.eval_mask(s, 4))
+    assert (one.parts, one.mask.seq) == (1, half) and one.mask.dense().sum() == tw.eval_mask(s, 4).sum()
+
+
+def test_the_attention_counters_are_declared_and_read_the_schedule_and_the_pad(case):
+    """``aux`` counts, for the rows that count: heads x layers x the
+    schedule's visits, x every block pair, and the positions padded."""
+    from shifu_tpu.obs.manifest import MANIFEST
+    from shifu_tpu.ops import attention
+    c = case[0]
+    spec, plan = c["spec"], tw.attention_plan(12, tw.block_mask(12, 4))
+    assert set(tw.ATTN_COUNTERS) <= set(tw.OBS_COUNTERS) <= set(tw.counter_shapes(spec))
+    for name in tw.ATTN_COUNTERS.values():
+        assert MANIFEST[name][0] == "counter", name
+    visits = attention.visited_key_blocks(32, 32, mask=plan.mask)
+    assert (plan.block, visits) == (32, 1)                 # a toy sequence is one block; the cell's 3 of 4: the test above
+    assert c["aux"]["attn_key_blocks"] == 6 * 4 * 2 * visits          # rows x heads x layers
+    assert c["aux"]["attn_key_blocks_dense"] == 6 * 4 * 2 * 1
+    assert c["aux"]["attn_pad_positions"] == 6 * 2 * 4
+    # a padding row (weight 0) counts for nothing
+    ids = jnp.asarray(c["ids"])
+    _, aux = tw.diffusion_loss(c["params"], spec, ids, c["t"], c["masked"],
+                               jnp.asarray([1, 1, 0, 1, 0, 0], jnp.float32),
+                               spec.special("MASK"), spec.special("PAD"))
+    assert aux["attn_key_blocks"] == 3 * 4 * 2 * visits and aux["attn_pad_positions"] == 3 * 8
 
 
 # ------------------------------------------------------------------ the share
@@ -341,13 +398,18 @@ def test_op_scopes_reads_named_scopes_from_the_compiled_program():
 
 
 # ------------------------------------------------------------------- the CLI
+# the attention kernels run in the interpreter, a sequence a call, and its cost is a grid
+# step's: two query heads on one key-value head, the set's 4,000 rows one block each
+CLI = {**TOY, "num_attention_heads": 2, "num_key_value_heads": 1, "vocab_size": 4200}
+
+
 def _tower_set(mdir, epochs=3, **params):
     mc = ModelConfig.load(os.path.join(mdir, "ModelConfig.json"))
     mc.train.algorithm = "TENSORFLOW"
     mc.train.numTrainEpochs = epochs
     mc.train.params = {"Tower": "sdar_moe", "MiniBatchs": 512, "LearningRate": 0.003,
                        "Propagation": "ADAM",
-                       "TowerParams": {**TOY, "vocab_size": 4200, "max_position_embeddings": 64},
+                       "TowerParams": {**CLI, "max_position_embeddings": 64},
                        **params}
     mc.save(os.path.join(mdir, "ModelConfig.json"))
 
@@ -387,11 +449,28 @@ def test_cli_train_writes_a_tower_and_eval_scores_it_as_the_reference(prepared_s
         col = f.readline().strip().split("|").index("mean")
         got = np.sort([float(line.split("|")[col]) for line in f])
     bins = Shards.open(os.path.join(prepared_set, "tmp", "CleanedData")).load_all()["bins"]
-    cfg = {**TOY, "vocab_size": 4200}
-    d = ref.tag_logit_difference(params, bins, cfg, spec.expert_lo, spec.column_bins, 4, 256)
+    d = ref.tag_logit_difference(params, bins, CLI, spec.expert_lo, spec.column_bins, 4, 256)
     want = np.sort(1000.0 / (1.0 + np.exp(-d)))
     assert len(got) == len(want)
     np.testing.assert_allclose(got, want, atol=2e-3)
+
+
+def test_a_job_counts_the_attention_counters_and_the_report_shows_them(prepared_set, capsys):
+    from shifu_tpu.cli import main
+    _tower_set(prepared_set, epochs=1)
+    assert main(["--dir", prepared_set, "train", "--telemetry"]) == 0
+    found = {}
+    with open(os.path.join(prepared_set, "telemetry", "trace.jsonl")) as f:
+        for doc in map(json.loads, f):
+            if str(doc.get("name", "")).startswith("tower.attn_") and "value" in doc:
+                found[doc["name"]] = found.get(doc["name"], 0.0) + float(doc["value"])
+    # 3,200 training rows of 12 positions: one block of 32 a sequence, 2 heads x 2 layers; 2 x 4 pad positions a row
+    assert found == {"tower.attn_key_blocks": 3200 * 4.0, "tower.attn_key_blocks_dense": 3200 * 4.0,
+                     "tower.attn_pad_positions": 3200 * 8.0}
+    capsys.readouterr()
+    assert main(["--dir", prepared_set, "analysis", "--telemetry"]) == 0
+    shown = capsys.readouterr().out
+    assert all(name in shown for name in found), shown
 
 
 def test_cli_killed_job_resumes_bit_exactly(prepared_set):
