@@ -331,8 +331,16 @@ def causal_loss(params, spec: TowerSpec, ids, w, pad_id):
     row).  Position i's target is id_{i+1} where that is not ``PAD``.
     Returns (loss, aux)."""
     h, found = trunk(params, spec, ids)
+    return packed_loss(params, h, found, ids, w, pad_id, spec.rms_norm_eps,
+                       _key_blocks(spec, ids.shape[1]))
+
+
+def packed_loss(params, h, found, ids, w, pad_id, eps, key_blocks):
+    """:func:`causal_loss` from the trunk's output ``h`` and its MoE layers'
+    counters ``found`` on: the final norm, the head, the loss and ``aux``
+    (``key_blocks``: a sequence's visited and full-sweep key blocks)."""
     with jax.named_scope("tower/head"):
-        logits = (_rms(h, params["norm_f"], spec.rms_norm_eps) @ params["head"]).astype(jnp.float32)
+        logits = (_rms(h, params["norm_f"], eps) @ params["head"]).astype(jnp.float32)
         targets = jnp.roll(ids, -1, axis=1)                 # the last position's counts for nothing
         ce = jax.nn.logsumexp(logits, axis=-1) - \
             jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
@@ -340,7 +348,7 @@ def causal_loss(params, spec: TowerSpec, ids, w, pad_id):
         loss_sum, count = jnp.sum(ce * tw), jnp.sum(tw)
         counts = jnp.any(w > 0, axis=1, keepdims=True)                      # sequences that count
         live = jnp.sum(counts).astype(jnp.float32)
-        visited, dense = _key_blocks(spec, ids.shape[1])
+        visited, dense = key_blocks
         aux = {"loss_sum": loss_sum, "positions": count,
                "attn_key_blocks": live * visited, "attn_key_blocks_dense": live * dense,
                "pad_positions": jnp.sum((ids == pad_id) & counts).astype(jnp.float32),
@@ -364,16 +372,17 @@ def _bias_absmax(params):
 
 
 def after_step(params, aux, spec: TowerSpec):
-    """The selection bias's rule, once an optimizer step: every MoE layer's
-    ``b <- b + load_balance_coeff x sign(mean(n) - n_e)`` from the step's
-    counts.  Returns (params, aux): ``router_bias_absmax`` is how far max |b|
-    moved, so that the steps' sum is the largest |b| since a zero start."""
+    """The selection bias's rule (``ops/moe.bias_step``), once an optimizer
+    step: every MoE layer's ``b <- b + load_balance_coeff x sign(mean(n) -
+    n_e)`` from the step's counts.  Returns (params, aux):
+    ``router_bias_absmax`` is how far max |b| moved, so that the steps' sum is
+    the largest |b| since a zero start."""
     before = _bias_absmax(params)
     blocks = dict(params["blocks"])
     moe_names = [name for name in sorted(blocks) if "bias" in blocks[name]]
     for name, n_e in zip(moe_names, aux["tokens"]):
-        move = spec.load_balance_coeff * jnp.sign(jnp.mean(n_e) - n_e)
-        blocks[name] = {**blocks[name], "bias": blocks[name]["bias"] + move}
+        blocks[name] = {**blocks[name],
+                        "bias": moe.bias_step(blocks[name]["bias"], n_e, spec.load_balance_coeff)}
     params = {**params, "blocks": blocks}
     return params, {**aux, "router_bias_absmax": _bias_absmax(params) - before}
 
@@ -388,10 +397,15 @@ def counter_shapes(spec: TowerSpec) -> Dict[str, tuple]:
 def tag_logits(params, spec: TowerSpec, feature_ids, tag0_id, mask_id):
     """One causal forward over one row a sequence.  feature_ids [n, C] ->
     [n, 2] logits of (TAG0, TAG1) at the last feature token."""
+    return row_tag_logits(trunk, params, spec, feature_ids, tag0_id, spec.rms_norm_eps)
+
+
+def row_tag_logits(trunk_of, params, spec, feature_ids, tag0_id, eps):
+    """:func:`tag_logits` through the trunk ``trunk_of(params, spec, ids)``."""
     c = feature_ids.shape[1]
     pad_id = tag0_id + (SPECIALS.index("PAD") - SPECIALS.index("TAG0"))
-    h, _ = trunk(params, spec, pad_to_block(feature_ids, spec.attention_block, pad_id))
+    h, _ = trunk_of(params, spec, pad_to_block(feature_ids, spec.attention_block, pad_id))
     with jax.named_scope("tower/head"):
-        last = _rms(h[:, c - 1], params["norm_f"], spec.rms_norm_eps)
+        last = _rms(h[:, c - 1], params["norm_f"], eps)
         two = jax.lax.dynamic_slice_in_dim(params["head"], tag0_id, 2, axis=1)
         return (last @ two).astype(jnp.float32)

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from ..config.errors import ErrorCode, ShifuError
 from ..ops import moe
-from .towers import RowTokens, nest_names
+from .towers import RowTokens, causal_conv, nest_names
 
 MTP_LOSS_SCALE = 0.1                # Megatron-Core's default scaling of the MTP loss
 # the step's named scopes, most specific first: device ops carry them (the
@@ -314,13 +314,12 @@ def ssd_chunked(x, dt, a, b, c, chunk: int):
 def _mamba(p, x, spec: TowerSpec):
     n, t, _ = x.shape
     h, pd, g, ns = spec.mamba_num_heads, spec.mamba_head_dim, spec.n_groups, spec.ssm_state_size
-    di, k = h * pd, spec.conv_kernel
+    di = h * pd
     with jax.named_scope("tower/ssm/proj"):
         zxbcdt = x @ p["w_in"]
     with jax.named_scope("tower/ssm/scan"):
         z, xbc, dt = zxbcdt[..., :di], zxbcdt[..., di:2 * di + 2 * g * ns], zxbcdt[..., 2 * di + 2 * g * ns:]
-        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-        xbc = jax.nn.silu(sum(padded[:, j:j + t] * p["conv_w"][j] for j in range(k)) + p["conv_b"])
+        xbc = jax.nn.silu(causal_conv(xbc, p["conv_w"]) + p["conv_b"])
         xs = xbc[..., :di].reshape(n, t, h, pd)
         b = xbc[..., di:di + g * ns].reshape(n, t, g, ns)
         c = xbc[..., di + g * ns:].reshape(n, t, g, ns)

@@ -25,6 +25,7 @@ trainer, ``eval`` and the entry points' refusals find it through
 
 Shared here: the tokeniser (a row of the binned plane is a sequence: one
 token a column, id = the column's offset + its bin, then the specials), the
+packing, the causal depthwise convolution (``nemotron_h``, ``lfm2_moe``), the
 ``.tower`` file and ``eval``'s scorer.
 """
 
@@ -44,7 +45,8 @@ import jax.numpy as jnp
 from .. import obs
 from ..config.errors import ErrorCode, ShifuError
 
-TOWERS = {"sdar_moe": "tower_sdar", "nemotron_h": "tower_nemotron_h", "afmoe": "tower_afmoe"}
+TOWERS = {"sdar_moe": "tower_sdar", "nemotron_h": "tower_nemotron_h", "afmoe": "tower_afmoe",
+          "lfm2_moe": "tower_lfm2"}
 SPECIALS = ("TAG0", "TAG1", "MASK", "PAD")
 
 
@@ -128,6 +130,16 @@ def tokenize(spec, bins: np.ndarray, y: np.ndarray) -> np.ndarray:
 def pad_to_block(ids, block: int, pad_id):
     """[n, L] -> [n, L rounded up to whole blocks], filled with ``pad_id``."""
     return jnp.pad(ids, ((0, 0), (0, -ids.shape[1] % block)), constant_values=pad_id)
+
+
+def causal_conv(x, w):
+    """The causal depthwise convolution along a sequence: x [n, T, C], w [K, C]
+    -> [n, T, C], ``out_t = sum_j w_j x_{t-K+1+j}`` with x before the first
+    position 0 — the last tap is the current position (a ``Conv1d(groups=C,
+    padding=K-1)`` cut to T).  A bias and an activation are the caller's."""
+    k, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + t] * w[j] for j in range(k))
 
 
 def pack_rows(ids, row_w, rows_per_sequence: int, block: int, pad_id):

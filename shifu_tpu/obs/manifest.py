@@ -96,17 +96,18 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
     "tower.ssm_chunks": ("counter", "chunks the Mamba-2 layers' scans ran: rows x layers "
                          "x ceil(positions / chunk_size) (nemotron_h)"),
     "tower.attn_key_blocks": ("counter", "key blocks the attention kernels' forward visits: "
-                              "sequences x heads x layers' visits (afmoe, sdar_moe)"),
+                              "sequences x heads x layers' visits (afmoe, lfm2_moe, sdar_moe)"),
     "tower.attn_key_blocks_dense": ("counter", "what a full sweep of every layer would visit "
-                                    "(afmoe: causal; sdar_moe: every block pair)"),
+                                    "(afmoe, lfm2_moe: causal; sdar_moe: every block pair)"),
     "tower.attn_pad_positions": ("counter", "positions the attention pads a half of [x_t ; x_0] "
                                  "with to whole blocks, over the rows trained on (sdar_moe; "
                                  "not PAD tokens: tower.pad_positions)"),
-    "tower.pad_positions": ("counter", "PAD positions of the packed training sequences (afmoe)"),
+    "tower.pad_positions": ("counter", "PAD positions of the packed training sequences "
+                            "(afmoe, lfm2_moe)"),
     "tower.sequence_positions": ("counter", "positions of the packed training sequences, "
-                                 "PAD included (afmoe)"),
+                                 "PAD included (afmoe, lfm2_moe)"),
     "tower.router_bias_absmax": ("counter", "how far the largest |selection bias| moved: "
-                                 "summed since a zero start, the largest |b| (afmoe)"),
+                                 "summed since a zero start, the largest |b| (afmoe, lfm2_moe)"),
     "train.host_syncs": ("counter", "device->host value-forcing fetches"),
     "train.tail_sweeps": ("counter", "disk-tail re-streams paid"),
     "train.tail_repairs": ("counter", "c2f speculation repairs"),

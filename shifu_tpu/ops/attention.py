@@ -35,8 +35,10 @@ walks a key block's query blocks, over the R query heads that share it, for
 ``dk`` and ``dv`` with the scores transposed, so that the row statistics
 broadcast along lanes.  Heads are addressed in place: the arrays stay ``[n, S,
 heads x hd]`` and a block is ``[block, hd]`` at the head's lane offset, so
-nothing is transposed on the way in or out (on the chip ``hd`` is a multiple
-of 128).
+nothing is transposed on the way in or out.  Mosaic takes such a block only
+when ``hd`` is whole lanes (:data:`LANES`): on the chip a narrower head
+(``lfm2_moe``'s 64) is padded with zero channels on the way in, which add
+nothing to a score, and they are cut off the output on the way out.
 
 Precision: f32 in and out.  The MXU's operands (q, k, v, the probabilities,
 the output's cotangent) are rounded once to :func:`..ops.moe.mxu_operand_dtype`
@@ -60,6 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .moe import mxu_operand_dtype
 
 BLOCK = 512                     # positions of a query / key block on the chip
+LANES = 128                     # a head's block is [block, hd] at its lane offset: whole lanes
 _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)       # a ruled-out score
 _NT = (((1,), (1,)), ((), ()))  # [a, d] x [b, d] -> [a, b]
 
@@ -413,6 +416,13 @@ def _backward(q, kv, o, lse, do, plan: Schedule, heads, interpret):
     return dq, dkv[:, 0], dkv[:, 1]
 
 
+def _lane_width(hd: int, interpret: bool) -> int:
+    """A head's channels as the kernels take them: Mosaic takes a ``[block,
+    hd]`` block of a ``[.., heads x hd]`` array only at whole lanes, the
+    interpreter at any width."""
+    return hd if interpret else -(-hd // LANES) * LANES
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _attend(q, k, v, plan, heads, interpret):
     return _attend_fwd(q, k, v, plan, heads, interpret)[0]
@@ -453,11 +463,15 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, window=Non
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     attend = partial(_attend, plan=schedule(mask, block), heads=kv * r, interpret=bool(interpret))
-    q, k, v = (q.reshape(n, seq, kv * r * hd) * (1.0 / math.sqrt(hd)),
-               k.reshape(n, seq, kv * hd), v.reshape(n, seq, kv * hd))
+    width = _lane_width(hd, interpret)
+    if width > hd:              # zero channels: they add 0 to every score, the output's are cut off
+        q, k, v = (jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - hd),)) for x in (q, k, v))
+    q, k, v = (q.reshape(n, seq, kv * r * width) * (1.0 / math.sqrt(hd)),
+               k.reshape(n, seq, kv * width), v.reshape(n, seq, kv * width))
     if interpret and n > 1:
         # the interpreter copies every operand whole at each grid step: a sequence a call
         o = jax.lax.map(lambda one: attend(*(a[None] for a in one))[0], (q, k, v))
     else:
         o = attend(q, k, v)
-    return o.reshape(n, seq, kv, r, hd)
+    o = o.reshape(n, seq, kv, r, width)
+    return o[..., :hd] if width > hd else o

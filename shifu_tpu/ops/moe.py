@@ -89,6 +89,14 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int, norm_topk: bool = T
     return top_p, top_e.astype(jnp.int32)
 
 
+def bias_step(bias: jnp.ndarray, tokens: jnp.ndarray, coeff: float) -> jnp.ndarray:
+    """The selection bias after an optimizer step whose top-k counts were
+    ``tokens`` [E] (every expert, held or not): ``b + coeff x sign(mean(n) -
+    n_e)`` — DeepSeek-V3's auxiliary-loss-free rule (arXiv:2412.19437), not
+    centred.  No gradient reaches ``b``; this is all that moves it."""
+    return bias + coeff * jnp.sign(jnp.mean(tokens) - tokens)
+
+
 def _grouped(lhs, rhs, group_sizes):
     return jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32)
 

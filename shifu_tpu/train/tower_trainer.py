@@ -183,10 +183,11 @@ def train_tower(bins: np.ndarray, y: np.ndarray, w: np.ndarray, spec, settings,
         raise ValueError(f"a tower trains under shifu.train.precision=f32; got {precision!r}")
     packs = hasattr(tower, "sequence_block")
     if rows_per_sequence != 1 and not packs:
+        packers = [n for n in sorted(towers.TOWERS) if hasattr(towers.module(n), "sequence_block")]
         raise ShifuError(ErrorCode.ERROR_MODELCONFIG_NOT_VALIDATION,
                          f"train#params.RowsPerSequence {rows_per_sequence}: the {spec.tower} "
                          "tower takes one row a sequence (its mask and recurrence end with the "
-                         "row); `afmoe` packs rows")
+                         f"row); {', '.join(f'`{n}`' for n in packers)} pack rows")
     with obs.span("tower.tokenize", rows=len(y), ids=spec.n_ids):
         ids = towers.tokenize(spec, bins, y)
         train_rows, valid_rows = split_rows(len(y), valid_rate, settings.seed)

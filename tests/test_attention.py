@@ -161,3 +161,21 @@ def test_shapes_the_blocks_do_not_divide_are_refused():
     q, k, v, _ = _qkv(64, 1, 1)
     with pytest.raises(ValueError, match="window of 24 keys is not whole blocks"):
         attention.blocked_attention(q, k, v, 24, BLOCK)
+
+
+def test_a_head_narrower_than_whole_lanes_is_padded_and_cut_off(monkeypatch):
+    """The chip's path for a head of fewer than 128 channels, interpreted:
+    zero channels on the way in, cut off the output on the way out, and the
+    same attention and gradients as the dense reference."""
+    monkeypatch.setattr(attention, "_lane_width", lambda hd, interpret: -(-hd // attention.LANES) *
+                        attention.LANES)
+    q, k, v, c = _qkv(2 * BLOCK, 2, 4, hd=64)
+    attend = partial(attention.blocked_attention, block=BLOCK)
+    both = lambda fn: jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * c), argnums=(0, 1, 2))
+    got, got_grads = jax.jit(both(attend))(q, k, v)
+    want, want_grads = both(dense_attention)(q, k, v)
+    assert attend(q, k, v).shape == q.shape
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(got_grads, want_grads):
+        assert a.shape == b.shape and a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, atol=2e-5)

@@ -18,6 +18,7 @@ import pytest
 import jax
 
 import test_tower_afmoe as afmoe_t
+import test_tower_lfm2 as lfm2_t
 import test_tower_nemotron_h as nemotron_t
 import test_tower_sdar as sdar_t
 from benchmark.run import _CompileCounter
@@ -30,6 +31,7 @@ TOWERS = {                              # name -> (toy spec, its columns' bins, 
     "sdar_moe": (sdar_t._spec, sdar_t.COL_BINS, 1),
     "nemotron_h": (nemotron_t._spec, nemotron_t.COL_BINS, 1),
     "afmoe": (afmoe_t._spec, afmoe_t.COL_BINS, afmoe_t.R),
+    "lfm2_moe": (lfm2_t._spec, lfm2_t.COL_BINS, lfm2_t.R),
 }
 COMPILES = _CompileCounter()            # the benchmark's own count: what `*_job_rebuilds` reads
 

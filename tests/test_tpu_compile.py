@@ -236,3 +236,38 @@ def test_trinity_train_step_fits_one_v5e_chip(one_chip, no_cache_no_x64, monkeyp
     assert len(calls("tower/attn/window")) == 4 * 4 and len(calls("tower/attn/full")) == 4, \
         {k: len(v) for k, v in scopes.items()}
     _assert_the_scopes_reach_what_a_scope_can(text, tw.SCOPES)
+
+
+def test_lfm2_train_step_fits_one_v5e_chip(one_chip, no_cache_no_x64, monkeypatch):
+    """The cell ``lfm2-train``'s step program — 18 rows packed into one
+    sequence of 8,192 positions (two sequences, 36 rows, count 14.56 GB live:
+    over the 14.5 GB the cell allows itself), the 486 M-parameter share with
+    its Adam state donated, the attention kernels compiled by Mosaic at
+    ``head_dim`` 64 (each head padded to 128 lanes) — fits, holds no ``[heads,
+    S, S]`` scores, keeps its four attention kernel calls under
+    ``tower/attn/full`` and the convolution's gates and taps under
+    ``tower/conv/mix``."""
+    from benchmark.drivers.train_afmoe import tower_params
+    from shifu_tpu.models import tower_lfm2 as tw
+    from shifu_tpu.obs.costs import op_scopes
+    _on_the_chips_branch(monkeypatch)
+    doc = _doc("configs", "lfm2-24b-a2b-ep8.json")
+    spec = _spec(tw, tower_params(doc), doc)
+    assert (doc["train"]["params"]["MiniBatchs"], doc["train"]["params"]["RowsPerSequence"],
+            spec.seq_len, spec.n_ids, spec.head_dim) == (18, 18, 433, 7402, 64)
+    compiled, n_params = _compiled_step(
+        tw, spec, doc, _doc("traffic", "retrain-540x433-pack18-2epochs.json")["rows"], one_chip)
+    assert n_params == 486_062_464
+    assert compiled.memory_analysis().alias_size_in_bytes > 5.8e9   # 12 bytes a parameter updated in place
+    live = _live_bytes(compiled)
+    print(f"lfm2-train step: {live / 1e9:.2f} GB live", compiled.memory_analysis())
+    assert live < 11.6e9, compiled.memory_analysis()       # 11.47 GB
+    text = compiled.as_text()
+    # [8192, 8192]: the head's logits over the 8,192-row vocabulary slice; never one a head
+    lead = {m for m in re.findall(r"\[((?:\d+,)*)8192,8192\]", text)}
+    assert all(np.prod([int(x) for x in m.split(",") if x]) == 1 for m in lead), lead
+    scopes = op_scopes(text, tw.SCOPES)
+    calls = sorted(n.split(".")[0] for n in scopes["tower/attn/full"] if n.startswith("blocked_attention"))
+    assert calls == ["blocked_attention_dkv", "blocked_attention_dq"] + ["blocked_attention_fwd"] * 2, calls
+    assert scopes["tower/conv/mix"] and scopes["tower/conv/proj"]
+    _assert_the_scopes_reach_what_a_scope_can(text, tw.SCOPES)
