@@ -345,7 +345,10 @@ SPANS: Dict[str, str] = {
     "setup.config": "ModelConfig.load and the PathFinder",
     "setup.probe": "the step's validation of ModelConfig (config.validator.probe)",
     "setup.columns": ("load_column_configs: ColumnConfig.json parsed into its "
-                      "objects, bins included (columns, bytes of the file)"),
+                      "objects, bins included (columns, bytes of the file; "
+                      "plans_built: jsonbean's conversion plans built by "
+                      "this load, 3 the first time a process loads "
+                      "columns, 0 after)"),
     "setup.journal": "ensure_dirs and the step's StepJournal read",
     "setup.precheck": ("_check_step_preconditions: the inputs of the step "
                        "exist; `train` stats every journaled norm shard "

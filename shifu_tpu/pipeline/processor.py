@@ -16,7 +16,7 @@ import time
 from typing import List, Optional
 
 from .. import faults, ioutil, obs
-from ..config import (ColumnConfig, ModelConfig, PathFinder,
+from ..config import (ColumnConfig, ModelConfig, PathFinder, jsonbean,
                       load_column_configs, save_column_configs)
 from ..config.validator import ModelStep, probe
 from .journal import StepJournal
@@ -62,9 +62,11 @@ class BasicProcessor:
         cc_path = self.paths.column_config_path
         if os.path.isfile(cc_path):
             with obs.span("setup.columns") as sp:
+                plans = jsonbean.plans_built()
                 self.column_configs = load_column_configs(cc_path)
                 sp.set(columns=len(self.column_configs),
-                       bytes=os.path.getsize(cc_path))
+                       bytes=os.path.getsize(cc_path),
+                       plans_built=jsonbean.plans_built() - plans)
         elif require_columns:
             raise FileNotFoundError(
                 f"{cc_path} not found — run `shifu-tpu init` first")

@@ -2,13 +2,21 @@
 
 import json
 import os
+import sys
+from dataclasses import dataclass
+from typing import List, Optional
 
 import pytest
 
-from shifu_tpu.config import (Algorithm, ColumnConfig, ColumnFlag, ColumnType,
-                              ModelConfig, NormType,
-                              build_initial_column_configs,
-                              load_column_configs, save_column_configs)
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "helpers"))
+import jsonbean_reference as reference  # noqa: E402
+
+from shifu_tpu.config import (Algorithm, ColumnBinning, ColumnConfig,
+                              ColumnFlag, ColumnStats, ColumnType,
+                              ModelBasicConf, ModelConfig, ModelTrainConf,
+                              NormType, build_initial_column_configs,
+                              jsonbean, load_column_configs,
+                              save_column_configs)
 from shifu_tpu.config.jsonbean import parse_enum
 from shifu_tpu.config.validator import ModelStep, ValidationError, probe
 
@@ -93,6 +101,103 @@ def test_column_config_init_and_round_trip(tmp_path):
     assert back[1].columnStats.mean == 3.5
     assert back[1].columnBinning.binBoundary[1] == 1.0
     assert back[3].columnFlag == ColumnFlag.Target
+
+
+@dataclass
+class _NoExtra:
+    rate: float = 0.0
+    counts: Optional[List[int]] = None
+
+
+def _columns_of(kind):
+    def columns(request):
+        mdir = request.getfixturevalue("_prepared_template")
+        with open(os.path.join(mdir, "ColumnConfig.json")) as f:
+            cols = [c for c in json.load(f) if c["columnType"] == kind]
+        key = "binCategory" if kind == "C" else "binBoundary"
+        assert any(c["columnBinning"][key] for c in cols)
+        return ColumnConfig, cols
+    return columns
+
+
+# name -> (request -> (class, the JSON dicts to convert))
+FROM_DICT_CASES = {
+    "columns_numeric": _columns_of("N"),
+    "columns_categorical": _columns_of("C"),
+    "model_config": lambda r: (ModelConfig, [REFERENCE_STYLE_MODEL_CONFIG]),
+    "bool_into_float": lambda r: (ColumnStats, [
+        {"mean": True, "max": False, "min": 3, "ks": "0.5"}]),
+    "integral_float_into_int": lambda r: (ColumnStats, [
+        {"totalCount": 3.0, "missingCount": 2.5, "distinctCount": 7}]),
+    "string_into_bool": lambda r: (ColumnConfig, [
+        {"finalSelect": "yes"}, {"finalSelect": " TRUE "},
+        {"finalSelect": "no"}, {"finalSelect": 1}]),
+    "enum_by_lower_case": lambda r: (ModelBasicConf, [
+        {"runMode": "mapred"}, {"runMode": " Dist "}]),
+    "enum_member": lambda r: (ColumnConfig, [
+        {"columnType": ColumnType.H, "columnFlag": ColumnFlag.Meta}]),
+    "none_optional_list": lambda r: (ColumnBinning, [
+        {"length": 0, "binBoundary": None, "binCategory": None}]),
+    "none_nested": lambda r: (ColumnConfig, [
+        {"columnStats": None, "columnBinning": None}]),
+    "unknown_keys": lambda r: (ColumnConfig, [
+        {"columnNum": 1, "futureKey": {"a": [1]},
+         "columnStats": {"newStat": 1.5, "mean": 2}}]),
+    "unknown_keys_dropped": lambda r: (_NoExtra, [
+        {"rate": 1, "counts": [1.0, 2.5, None], "futureKey": 3}]),
+    "key_named_extra": lambda r: (ColumnConfig, [
+        {"extra": {"x": 1}, "columnNum": 2}]),
+    "non_list_under_list": lambda r: (ColumnBinning, [
+        {"binCategory": "abc", "binCountPos": {"1": 2}}]),
+}
+
+
+@pytest.mark.parametrize("case", FROM_DICT_CASES)
+def test_from_dict_matches_per_value_reference(case, request):
+    """The planned ``from_dict`` builds the objects the per-value one did:
+    equal, equal as dicts, and with the same types inside (``repr`` tells
+    1 from 1.0 from True)."""
+    cls, dicts = FROM_DICT_CASES[case](request)
+    got = [jsonbean.from_dict(cls, d) for d in dicts]
+    want = [reference.from_dict(cls, d) for d in dicts]
+    assert got == want
+    assert [jsonbean.to_dict(o) for o in got] == \
+        [jsonbean.to_dict(o) for o in want]
+    assert repr(got) == repr(want)
+
+
+def test_from_dict_copies_lists_and_dicts():
+    """An object shares no list or dict with the parsed JSON it came from."""
+    d = {"sampleValues": ["a", "b"],
+         "columnBinning": {"binBoundary": [1.0, 2.0], "binCountPos": [3, 4],
+                           "binCategory": ["x"]}}
+    cc = jsonbean.from_dict(ColumnConfig, d)
+    train = jsonbean.from_dict(ModelTrainConf, {"params": {"a": 1}})
+    d["sampleValues"].append("c")
+    for v in d["columnBinning"].values():
+        v.clear()
+    assert cc.sampleValues == ["a", "b"]
+    assert cc.columnBinning.binBoundary == [1.0, 2.0]
+    assert cc.columnBinning.binCountPos == [3, 4]
+    assert cc.columnBinning.binCategory == ["x"]
+    assert train.params == {"a": 1}
+
+
+def test_column_configs_second_load_builds_no_plan(tmp_path, monkeypatch):
+    """The first load of a process builds the three classes' plans; a
+    second builds none and gives equal objects."""
+    monkeypatch.setattr(jsonbean, "_PLANS", {})
+    ccs = build_initial_column_configs(["a", "tag"], target="tag")
+    ccs[0].columnStats.mean = 0.5
+    ccs[0].columnBinning.binBoundary = [float("-inf"), 1.0]
+    ccs[0].columnBinning.binCountPos = [1, 2, 0]
+    p = str(tmp_path / "ColumnConfig.json")
+    save_column_configs(ccs, p)
+    first = load_column_configs(p)
+    built = jsonbean.plans_built()
+    second = load_column_configs(p)
+    assert (built, jsonbean.plans_built()) == (3, 3)
+    assert first == second == ccs
 
 
 def test_validator_catches_problems():

@@ -198,8 +198,10 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
     assert sum(s["dur_s"] for s in kids) <= setup["dur_s"]
     by_name = {s["name"]: s["attrs"] for s in kids}
     cc_path = os.path.join(prepared_set, "ColumnConfig.json")
+    columns = by_name["setup.columns"]
+    assert isinstance(columns.pop("plans_built"), int)
     with open(cc_path) as f:
-        assert by_name["setup.columns"] == {
+        assert columns == {
             "columns": len(json.load(f)), "bytes": os.path.getsize(cc_path)}
     with open(os.path.join(prepared_set, "tmp", "journal",
                            "NORMALIZE.json")) as f:
@@ -257,6 +259,14 @@ def test_train_job_is_spanned_from_shard_to_epoch(telemetry, prepared_set):
     built = {s["attrs"]["program"] for s in spans
              if s["name"] == "xla.build" and s["parent"] == d0["id"]}
     assert {"epoch_steps", "eval_errors"} <= built
+
+    # a second job in the process converts the columns on the plans the
+    # first one left: it builds none
+    assert main(["-Dshifu.train.streaming=off", "--dir", prepared_set,
+                 "train", "--telemetry"]) == 0
+    spans, _ = _span_tree(prepared_set)
+    last = [s for s in spans if s["name"] == "setup.columns"][-1]
+    assert last["attrs"]["plans_built"] == 0
 
 
 # ------------------------------------------------------- (d) the manifest
