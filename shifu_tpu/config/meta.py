@@ -144,11 +144,12 @@ TRAIN_PARAM_RULES: Dict[str, Rule] = {
     # trains itself (train/tower_trainer.py); ``TowerParams`` holds the
     # tower's published config.json keys, checked by the tower's own module
     # (models/towers.py TOWERS: tower_sdar.py, tower_nemotron_h.py,
-    # tower_afmoe.py, tower_lfm2.py); ``RowsPerSequence`` lays that many
-    # consecutive rows of a microbatch end to end as one sequence (default 1;
-    # the towers with a ``sequence_block``: ``afmoe``, ``lfm2_moe``)
-    "Tower": Rule("str", allowed=("sdar_moe", "nemotron_h", "afmoe", "lfm2_moe"), algs=("TENSORFLOW",),
-                  native=True),
+    # tower_afmoe.py, tower_lfm2.py, tower_deepseek_v3.py); ``RowsPerSequence``
+    # lays that many consecutive rows of a microbatch end to end as one
+    # sequence (default 1; the towers with a ``sequence_block``: ``afmoe``,
+    # ``lfm2_moe``, ``deepseek_v3``)
+    "Tower": Rule("str", allowed=("sdar_moe", "nemotron_h", "afmoe", "lfm2_moe", "deepseek_v3"),
+                  algs=("TENSORFLOW",), native=True),
     "TowerParams": Rule("dict", algs=("TENSORFLOW",), native=True),
     "RowsPerSequence": Rule("int", lo=1, algs=("TENSORFLOW",), native=True),
     # WDL family

@@ -46,7 +46,7 @@ from .. import obs
 from ..config.errors import ErrorCode, ShifuError
 
 TOWERS = {"sdar_moe": "tower_sdar", "nemotron_h": "tower_nemotron_h", "afmoe": "tower_afmoe",
-          "lfm2_moe": "tower_lfm2"}
+          "lfm2_moe": "tower_lfm2", "deepseek_v3": "tower_deepseek_v3"}
 SPECIALS = ("TAG0", "TAG1", "MASK", "PAD")
 
 

@@ -107,7 +107,11 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
     "tower.sequence_positions": ("counter", "positions of the packed training sequences, "
                                  "PAD included (afmoe, lfm2_moe)"),
     "tower.router_bias_absmax": ("counter", "how far the largest |selection bias| moved: "
-                                 "summed since a zero start, the largest |b| (afmoe, lfm2_moe)"),
+                                 "summed since a zero start, the largest |b| (afmoe, lfm2_moe, "
+                                 "deepseek_v3)"),
+    "tower.moe_balance_loss_sum": ("counter", "the sequence-wise balance loss's sum_e f_e P_e, "
+                                   "summed over the MoE layers and the sequences, unscaled "
+                                   "(deepseek_v3)"),
     "train.host_syncs": ("counter", "device->host value-forcing fetches"),
     "train.tail_sweeps": ("counter", "disk-tail re-streams paid"),
     "train.tail_repairs": ("counter", "c2f speculation repairs"),

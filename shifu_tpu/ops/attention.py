@@ -2,7 +2,9 @@
 scores alive, and only the key blocks the mask allows are visited.
 
 :func:`blocked_attention` takes grouped queries ``[n, S, KV, R, hd]`` (R query
-heads share a key-value head) and keys / values ``[n, S, KV, hd]``.  **The mask
+heads share a key-value head), keys ``[n, S, KV, hd]`` and values ``[n, S, KV,
+dv]`` of a width of their own (latent attention scores over 192 channels and
+carries 128; ``dv == hd`` elsewhere).  **The mask
 is data**: a :class:`Mask` gives every query position the keys it may see as
 at most two half-open intervals ``[lo0, hi0) u [lo1, hi1)`` of key positions —
 causal ``[0, i + 1)``, a window ``[max(i - w + 1, 0), i + 1)``
@@ -34,11 +36,17 @@ log-sum-exp — one kernel walks a query block's key blocks for ``dq``, one
 walks a key block's query blocks, over the R query heads that share it, for
 ``dk`` and ``dv`` with the scores transposed, so that the row statistics
 broadcast along lanes.  Heads are addressed in place: the arrays stay ``[n, S,
-heads x hd]`` and a block is ``[block, hd]`` at the head's lane offset, so
-nothing is transposed on the way in or out.  Mosaic takes such a block only
-when ``hd`` is whole lanes (:data:`LANES`): on the chip a narrower head
-(``lfm2_moe``'s 64) is padded with zero channels on the way in, which add
-nothing to a score, and they are cut off the output on the way out.
+heads x hd]`` (v, the output and its cotangent ``[n, S, heads x dv]``) and a
+block is ``[block, hd]`` (``[block, dv]``) at the head's lane offset, so
+nothing is transposed on the way in or out.  Keys and values of one width go
+in as one stacked ``[n, 2, S, KV x hd]`` operand, a block pair fetched as one;
+of two widths as two operands, ``dk`` and ``dv`` written at their own widths.
+Mosaic takes a block only when its width is whole lanes (:data:`LANES`): on
+the chip a narrower q / k head (``lfm2_moe``'s 64, latent attention's 192) is
+padded with zero channels on the way in, which add nothing to a score, and v
+is padded to its own whole lanes alone (128 stays 128); the output's zero
+channels are cut off on the way out.  A caller that builds q and k at
+:func:`lane_width` hands them over with ``qk_dim``, and nothing pads them again.
 
 Precision: f32 in and out.  The MXU's operands (q, k, v, the probabilities,
 the output's cotangent) are rounded once to :func:`..ops.moe.mxu_operand_dtype`
@@ -252,8 +260,19 @@ _WALK = ("parallel", "parallel", "parallel", "arbitrary")  # the last grid axis 
 
 
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, o_ref, lse_ref,
-                m_s, l_s, acc_s, *, block, steps, kinds):
+def _kv_refs(refs, split):
+    """(loaders of a visit's key and value blocks, the kernel's other refs):
+    one stacked ``[2, block, hd]`` operand, or (``split``) two of their own
+    widths."""
+    if split:
+        k_ref, v_ref, *rest = refs
+        return (lambda: k_ref[...]), (lambda: v_ref[...]), rest
+    kv_ref, *rest = refs
+    return (lambda: kv_ref[0]), (lambda: kv_ref[1]), rest
+
+
+def _fwd_kernel(blocks_ref, kinds_ref, q_ref, *refs, block, steps, kinds, split):
+    k, v, (bounds_ref, o_ref, lse_ref, m_s, l_s, acc_s) = _kv_refs(refs, split)
     i, t = pl.program_id(1), pl.program_id(3)
     at = i * steps + t
 
@@ -264,7 +283,7 @@ def _fwd_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, o_ref, lse_ref
         acc_s[...] = jnp.zeros_like(acc_s)
 
     def visit(cuts):
-        s = jax.lax.dot_general(q_ref[...], kv_ref[0], _NT, preferred_element_type=jnp.float32)
+        s = jax.lax.dot_general(q_ref[...], k(), _NT, preferred_element_type=jnp.float32)
         if cuts:
             s = jnp.where(_allowed(bounds_ref, blocks_ref[at], block, cuts), s, _MASK)
         m_prev = m_s[...]
@@ -274,7 +293,7 @@ def _fwd_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, o_ref, lse_ref
         l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=-1, keepdims=True)
         m_s[...] = m_next
         acc_s[...] = _lanes(alpha, acc_s.shape[1]) * acc_s[...] + jnp.dot(
-            p.astype(kv_ref.dtype), kv_ref[1], preferred_element_type=jnp.float32)
+            p.astype(q_ref.dtype), v(), preferred_element_type=jnp.float32)
 
     _visit_if(kinds_ref[at], kinds, visit)
 
@@ -293,41 +312,60 @@ def _fwd_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, o_ref, lse_ref
         lse_ref[...] = jnp.sum(jnp.where(eye, lse, 0.0), axis=0, keepdims=True)
 
 
-def _specs(plan: Schedule, seq, hd, r):
-    """(a query block, the visit's key and value block, the query block's
-    intervals and those as an operand) for grid point (sequence, query block,
-    query head, step): the heads of a query block share its intervals, which
-    are fetched once for them all."""
+def _specs(plan: Schedule, seq, hd, dv, r, split):
+    """(a query block, the visit's key and value blocks, the query block's
+    intervals and those as an operand, a query block's output) for grid point
+    (sequence, query block, query head, step): the heads of a query block
+    share its intervals, which are fetched once for them all.  The key and
+    value blocks are one ``[2, block, hd]`` block of the stacked operand, or
+    (``split``) a ``[block, hd]`` and a ``[block, dv]`` one."""
     block, steps, lanes = plan.block, plan.q_steps, min(128, plan.block)
     bounds = plan.bounds[:max(1, (max(plan.kinds) - PLAIN).bit_length())]    # up to the last that cuts a pair
-    return (pl.BlockSpec((None, block, hd), lambda b, i, h, t, *_: (b, i, h)),
-            pl.BlockSpec((None, 2, block, hd),
-                         lambda b, i, h, t, blocks, kinds: (b, 0, blocks[i * steps + t], h // r)),
+    visit = lambda b, i, h, t, blocks, kinds: (b, blocks[i * steps + t], h // r)
+    at_q = pl.BlockSpec((None, block, hd), lambda b, i, h, t, *_: (b, i, h))
+    if split:
+        at_kv = [pl.BlockSpec((None, block, hd), visit), pl.BlockSpec((None, block, dv), visit)]
+        at_o = pl.BlockSpec((None, block, dv), lambda b, i, h, t, *_: (b, i, h))
+    else:
+        at_kv = [pl.BlockSpec((None, 2, block, hd),
+                              lambda b, i, h, t, blocks, kinds: (b, 0, blocks[i * steps + t], h // r))]
+        at_o = at_q
+    return (at_q, at_kv,
             pl.BlockSpec((len(bounds), block, lanes), lambda b, i, h, t, *_: (0, i, 0)),
-            jnp.broadcast_to(jnp.asarray(bounds)[:, :, None], (len(bounds), seq, lanes)))
+            jnp.broadcast_to(jnp.asarray(bounds)[:, :, None], (len(bounds), seq, lanes)), at_o)
+
+
+def _widths(q, kv, heads):
+    """(q/k channels a head, v channels a head, key-value heads) of the
+    kernels' operands: ``kv`` is (the stacked ``[n, 2, S, KV x hd]``,) or
+    (k ``[n, S, KV x hd]``, v ``[n, S, KV x dv]``)."""
+    hd = q.shape[2] // heads
+    groups = kv[0].shape[-1] // hd
+    return hd, (hd if len(kv) == 1 else kv[1].shape[2] // groups), groups
 
 
 def _forward(q, kv, plan: Schedule, heads, interpret):
     n, seq, width = q.shape
-    hd, block = width // heads, plan.block
+    (hd, dv, groups), block = _widths(q, kv, heads), plan.block
     nq, lanes = seq // block, min(128, block)
-    at_q, at_kv, at_bounds, bounds = _specs(plan, seq, hd, heads // (kv.shape[3] // hd))
+    split = len(kv) == 2
+    at_q, at_kv, at_bounds, bounds, at_o = _specs(plan, seq, hd, dv, heads // groups, split)
     o, lse = _call(
-        partial(_fwd_kernel, block=block, steps=plan.q_steps, kinds=plan.kinds),
+        partial(_fwd_kernel, block=block, steps=plan.q_steps, kinds=plan.kinds, split=split),
         "blocked_attention_fwd", _WALK, (n, nq, heads, plan.q_steps),
-        [at_q, at_kv, at_bounds],
-        [at_q, pl.BlockSpec((None, None, 1, block), lambda b, i, h, t, *_: (b, h, 0, i))],
-        [jax.ShapeDtypeStruct((n, seq, width), jnp.float32),
+        [at_q, *at_kv, at_bounds],
+        [at_o, pl.BlockSpec((None, None, 1, block), lambda b, i, h, t, *_: (b, h, 0, i))],
+        [jax.ShapeDtypeStruct((n, seq, heads * dv), jnp.float32),
          jax.ShapeDtypeStruct((n, heads, 1, seq), jnp.float32)],
         [pltpu.VMEM((block, lanes), jnp.float32), pltpu.VMEM((block, lanes), jnp.float32),
-         pltpu.VMEM((block, hd), jnp.float32)], interpret,
-    )(*plan.q_visits, q, kv, bounds)
+         pltpu.VMEM((block, dv), jnp.float32)], interpret,
+    )(*plan.q_visits, q, *kv, bounds)
     return o, lse[:, :, 0]
 
 
 # ----------------------------------------------------------------- backward
-def _dq_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, do_ref, rows_ref, dq_ref, acc_s, *,
-               block, steps, kinds):
+def _dq_kernel(blocks_ref, kinds_ref, q_ref, *refs, block, steps, kinds, split):
+    k_of, v_of, (bounds_ref, do_ref, rows_ref, dq_ref, acc_s) = _kv_refs(refs, split)
     i, t = pl.program_id(1), pl.program_id(3)
     at = i * steps + t
 
@@ -336,12 +374,12 @@ def _dq_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, do_ref, rows_re
         acc_s[...] = jnp.zeros_like(acc_s)
 
     def visit(cuts):
-        k = kv_ref[0]
+        k = k_of()
         s = jax.lax.dot_general(q_ref[...], k, _NT, preferred_element_type=jnp.float32)
         if cuts:
             s = jnp.where(_allowed(bounds_ref, blocks_ref[at], block, cuts), s, _MASK)
         p = jnp.exp(s - jnp.expand_dims(rows_ref[0], -1))   # the rows' log-sum-exp
-        dp = jax.lax.dot_general(do_ref[...], kv_ref[1], _NT, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do_ref[...], v_of(), _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - jnp.expand_dims(rows_ref[1], -1))    # their delta
         acc_s[...] += jnp.dot(ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
@@ -352,68 +390,79 @@ def _dq_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, do_ref, rows_re
         dq_ref[...] = acc_s[...]
 
 
-def _dkv_kernel(blocks_ref, kinds_ref, q_ref, kv_ref, bounds_ref, do_ref, rows_ref, dkv_ref, acc_s, *,
-                block, steps, kinds):
+def _dkv_kernel(blocks_ref, kinds_ref, q_ref, *refs, block, steps, kinds, split):
+    k, v, (bounds_ref, do_ref, rows_ref, *out) = _kv_refs(refs, split)
+    outs, accs = out[:len(out) // 2], out[len(out) // 2:]
+    # where dk and dv add up: the two halves of one [2, block, hd] block, or two blocks
+    (dk_s, dk_at), (dv_s, dv_at) = ((accs[0], ...), (accs[1], ...)) if split else \
+        ((accs[0], 0), (accs[0], 1))
     j, head, t = pl.program_id(2), pl.program_id(3), pl.program_id(4)
 
     @pl.when((head == 0) & (t == 0))
     def _():
-        acc_s[...] = jnp.zeros_like(acc_s)
+        for acc_s in accs:
+            acc_s[...] = jnp.zeros_like(acc_s)
 
     def visit(cuts):
-        s = jax.lax.dot_general(kv_ref[0], q_ref[...], _NT, preferred_element_type=jnp.float32)
+        s = jax.lax.dot_general(k(), q_ref[...], _NT, preferred_element_type=jnp.float32)
         if cuts:
             s = jnp.where(_allowed(bounds_ref, j, block, cuts, transposed=True), s, _MASK)
         p = jnp.exp(s - rows_ref[0:1, :])                   # [keys, queries] - [1, queries]: the log-sum-exp
-        acc_s[1] += jnp.dot(p.astype(do_ref.dtype), do_ref[...], preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(kv_ref[1], do_ref[...], _NT, preferred_element_type=jnp.float32)
+        dv_s[dv_at] += jnp.dot(p.astype(do_ref.dtype), do_ref[...], preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v(), do_ref[...], _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - rows_ref[1:2, :])                    # the queries' delta
-        acc_s[0] += jnp.dot(ds.astype(q_ref.dtype), q_ref[...], preferred_element_type=jnp.float32)
+        dk_s[dk_at] += jnp.dot(ds.astype(q_ref.dtype), q_ref[...], preferred_element_type=jnp.float32)
 
     _visit_if(kinds_ref[j * steps + t], kinds, visit)
 
     @pl.when((head == pl.num_programs(3) - 1) & (t == steps - 1))
     def _():
-        dkv_ref[...] = acc_s[...]
+        for out_ref, acc_s in zip(outs, accs):
+            out_ref[...] = acc_s[...]
 
 
 def _backward(q, kv, o, lse, do, plan: Schedule, heads, interpret):
     n, seq, width = q.shape
-    hd, block = width // heads, plan.block
-    groups = kv.shape[3] // hd
+    (hd, dv, groups), block = _widths(q, kv, heads), plan.block
     r = heads // groups
     nq = seq // block
-    delta = jnp.sum((do * o).reshape(n, seq, heads, hd), axis=-1).transpose(0, 2, 1)
+    split = len(kv) == 2
+    delta = jnp.sum((do * o).reshape(n, seq, heads, dv), axis=-1).transpose(0, 2, 1)
     rows, do = jnp.stack([lse, delta], axis=2), do.astype(q.dtype)    # [n, heads, 2, S]: two rows a block
 
-    at_q, at_kv, at_bounds, bounds = _specs(plan, seq, hd, r)
+    at_q, at_kv, at_bounds, bounds, at_o = _specs(plan, seq, hd, dv, r, split)
     rows_q = pl.BlockSpec((None, None, 2, block), lambda b, i, h, t, *_: (b, h, 0, i))
     dq = _call(
-        partial(_dq_kernel, block=block, steps=plan.q_steps, kinds=plan.kinds),
+        partial(_dq_kernel, block=block, steps=plan.q_steps, kinds=plan.kinds, split=split),
         "blocked_attention_dq", _WALK, (n, nq, heads, plan.q_steps),
-        [at_q, at_kv, at_bounds, at_q, rows_q], at_q,
+        [at_q, *at_kv, at_bounds, at_o, rows_q], at_q,
         jax.ShapeDtypeStruct((n, seq, width), jnp.float32),
         [pltpu.VMEM((block, hd), jnp.float32)], interpret,
-    )(*plan.q_visits, q, kv, bounds, do, rows)
+    )(*plan.q_visits, q, *kv, bounds, do, rows)
 
     # grid point (sequence, key-value head, key block, query head of the r
     # that share it, step): a key block's query blocks, a head after the other
     steps = plan.k_steps
-    q_of = pl.BlockSpec((None, block, hd), lambda b, g, j, h, t, blocks, kinds:
-                        (b, blocks[j * steps + t], g * r + h))
+    of_head = lambda b, g, j, h, t, blocks, kinds: (b, blocks[j * steps + t], g * r + h)
+    q_of = pl.BlockSpec((None, block, hd), of_head)
     rows_of = pl.BlockSpec((None, None, 2, block), lambda b, g, j, h, t, blocks, kinds:
                            (b, g * r + h, 0, blocks[j * steps + t]))
     bounds_of = pl.BlockSpec((4, block), lambda b, g, j, h, t, blocks, kinds:
                              (0, blocks[j * steps + t]))
-    at_kv = pl.BlockSpec((None, 2, block, hd), lambda b, g, j, h, t, *_: (b, 0, j, g))
-    dkv = _call(
-        partial(_dkv_kernel, block=block, steps=steps, kinds=plan.kinds),
+    if split:
+        at_kv = [pl.BlockSpec((None, block, c), lambda b, g, j, h, t, *_: (b, j, g)) for c in (hd, dv)]
+        do_of, scratch = pl.BlockSpec((None, block, dv), of_head), [(block, hd), (block, dv)]
+    else:
+        at_kv = [pl.BlockSpec((None, 2, block, hd), lambda b, g, j, h, t, *_: (b, 0, j, g))]
+        do_of, scratch = q_of, [(2, block, hd)]
+    grads = _call(
+        partial(_dkv_kernel, block=block, steps=steps, kinds=plan.kinds, split=split),
         "blocked_attention_dkv", _WALK + ("arbitrary",), (n, groups, nq, r, steps),
-        [q_of, at_kv, bounds_of, q_of, rows_of], at_kv,
-        jax.ShapeDtypeStruct(kv.shape, jnp.float32),
-        [pltpu.VMEM((2, block, hd), jnp.float32)], interpret,
-    )(*plan.k_visits, q, kv, plan.bounds, do, rows)
-    return dq, dkv[:, 0], dkv[:, 1]
+        [q_of, *at_kv, bounds_of, do_of, rows_of], at_kv,
+        [jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in kv],
+        [pltpu.VMEM(shape, jnp.float32) for shape in scratch], interpret,
+    )(*plan.k_visits, q, *kv, plan.bounds, do, rows)
+    return (dq, *grads) if split else (dq, grads[0][:, 0], grads[0][:, 1])
 
 
 def _lane_width(hd: int, interpret: bool) -> int:
@@ -423,6 +472,14 @@ def _lane_width(hd: int, interpret: bool) -> int:
     return hd if interpret else -(-hd // LANES) * LANES
 
 
+def lane_width(channels: int) -> int:
+    """The channels a head of ``channels`` is given in the kernels on this
+    backend (:func:`blocked_attention` without ``interpret``): a caller that
+    builds q and k at this width, zeros after its ``channels``, hands them
+    over with ``qk_dim=channels`` and nothing pads them again."""
+    return _lane_width(channels, jax.default_backend() != "tpu")
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _attend(q, k, v, plan, heads, interpret):
     return _attend_fwd(q, k, v, plan, heads, interpret)[0]
@@ -430,7 +487,10 @@ def _attend(q, k, v, plan, heads, interpret):
 
 def _attend_fwd(q, k, v, plan, heads, interpret):
     dt = mxu_operand_dtype(q)
-    q, kv = q.astype(dt), jnp.stack([k, v], axis=1).astype(dt)     # once, not a block; [n, 2, S, KV x hd]
+    if k.shape == v.shape:          # once, not a block; [n, 2, S, KV x hd]
+        q, kv = q.astype(dt), (jnp.stack([k, v], axis=1).astype(dt),)
+    else:                           # v at its own width
+        q, kv = q.astype(dt), (k.astype(dt), v.astype(dt))
     o, lse = _forward(q, kv, plan, heads, interpret)
     return o, (q, kv, o, lse)
 
@@ -444,17 +504,21 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, window=None,
-                      block: int = BLOCK, interpret=None, mask: Mask = None) -> jnp.ndarray:
+                      block: int = BLOCK, interpret=None, mask: Mask = None,
+                      qk_dim: int = None) -> jnp.ndarray:
     """Grouped-query attention under ``mask``; without one causal, ``window``
     keys back when given.
 
-    q [n, S, KV, R, hd], k / v [n, S, KV, hd], f32 -> [n, S, KV, R, hd] f32:
-    ``softmax_j(q_i . k_j / sqrt(hd)) v_j`` over the keys j that ``mask``
-    gives query i (0 where it gives none) — without a mask the keys ``j <= i``
-    (and ``i - j < window``).  S is a multiple of ``block``, and so is a
+    q [n, S, KV, R, hd], k [n, S, KV, hd], v [n, S, KV, dv], f32 -> [n, S, KV,
+    R, dv] f32: ``softmax_j(q_i . k_j / sqrt(hd)) v_j`` over the keys j that
+    ``mask`` gives query i (0 where it gives none) — without a mask the keys
+    ``j <= i`` (and ``i - j < window``).  ``qk_dim``: q and k hold that many
+    real channels a head and zeros after them (:func:`lane_width`), and the
+    scale is ``1 / sqrt(qk_dim)``.  S is a multiple of ``block``, and so is a
     ``window`` shorter than S (a longer one is a full layer).  ``interpret``
     None: the Pallas interpreter anywhere but on a TPU."""
     n, seq, kv, r, hd = q.shape
+    dv = v.shape[-1]
     if mask is None:
         mask = causal_mask(seq, _whole_window(seq, block, window))
     elif window is not None or mask.seq != seq:
@@ -463,15 +527,19 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, window=Non
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     attend = partial(_attend, plan=schedule(mask, block), heads=kv * r, interpret=bool(interpret))
-    width = _lane_width(hd, interpret)
-    if width > hd:              # zero channels: they add 0 to every score, the output's are cut off
-        q, k, v = (jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - hd),)) for x in (q, k, v))
-    q, k, v = (q.reshape(n, seq, kv * r * width) * (1.0 / math.sqrt(hd)),
-               k.reshape(n, seq, kv * width), v.reshape(n, seq, kv * width))
+    width, v_width = _lane_width(hd, interpret), _lane_width(dv, interpret)
+    # zero channels: they add 0 to every score, the output's are cut off
+    pad = lambda x, to: jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, to - x.shape[-1]),))
+    if width > hd:
+        q, k = pad(q, width), pad(k, width)
+    if v_width > dv:
+        v = pad(v, v_width)
+    q, k, v = (q.reshape(n, seq, kv * r * width) * (1.0 / math.sqrt(qk_dim or hd)),
+               k.reshape(n, seq, kv * width), v.reshape(n, seq, kv * v_width))
     if interpret and n > 1:
         # the interpreter copies every operand whole at each grid step: a sequence a call
         o = jax.lax.map(lambda one: attend(*(a[None] for a in one))[0], (q, k, v))
     else:
         o = attend(q, k, v)
-    o = o.reshape(n, seq, kv, r, width)
-    return o[..., :hd] if width > hd else o
+    o = o.reshape(n, seq, kv, r, v_width)
+    return o[..., :dv] if v_width > dv else o

@@ -2,7 +2,8 @@
 
 The rank is told which experts it holds (``lo .. lo + held``), routes every
 token over ALL experts (:func:`route`: a softmax router, or sigmoid scores
-with a selection bias; top-k, renormalised), and computes its own experts'
+with a selection bias; top-k, renormalised; :func:`route_scores` hands out the
+scores too, for a balance loss), and computes its own experts'
 part of the result (:func:`held_experts_ffn`: SwiGLU experts, or two matrices
 around relu^2 — one sort, one walk, one backward).  What the absent experts
 would add is left out — there is no stand-in for the other ranks or their
@@ -74,10 +75,20 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int, norm_topk: bool = T
     (the selection bias; zeros count): sigmoid scores, chosen = the top-k of
     score + bias, weights = the chosen *scores*; the bias takes no gradient.
     ``scale`` multiplies the weights after the renormalisation."""
+    return route_scores(x, w_router, top_k, norm_topk, bias, scale)[:2]
+
+
+def route_scores(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int, norm_topk: bool = True,
+                 bias: Optional[jnp.ndarray] = None, scale: float = 1.0
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """:func:`route`'s (weights, experts) and the scores [T, E] f32 it chose
+    from (softmax, or sigmoid without the bias), from the same router product:
+    what a balance loss over all experts reads."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if bias is None:
-        top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        scores = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(scores, top_k)
     else:
         scores = jax.nn.sigmoid(logits)
         _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
@@ -86,7 +97,7 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int, norm_topk: bool = T
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     if scale != 1.0:
         top_p = top_p * scale
-    return top_p, top_e.astype(jnp.int32)
+    return top_p, top_e.astype(jnp.int32), scores
 
 
 def bias_step(bias: jnp.ndarray, tokens: jnp.ndarray, coeff: float) -> jnp.ndarray:

@@ -179,3 +179,38 @@ def test_a_head_narrower_than_whole_lanes_is_padded_and_cut_off(monkeypatch):
     for a, b in zip(got_grads, want_grads):
         assert a.shape == b.shape and a.dtype == jnp.float32
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("lanes", [False, True], ids=["any-width", "whole-lanes"])
+@pytest.mark.parametrize("seq", [BLOCK, 4 * BLOCK])
+@pytest.mark.parametrize("hd,dv", [(192, 128), (64, 64)])
+def test_a_value_width_of_its_own_matches_dense_attention(hd, dv, seq, lanes, monkeypatch):
+    """v of ``dv`` channels beside q / k of ``hd`` (latent attention's 128
+    against 192; equal widths stack k and v as one operand): forward and the
+    gradients of q, k and v against the dense reference, at one block and at
+    several.  ``whole-lanes`` runs the chip's layout, interpreted: q / k
+    padded to 256 lanes, v left at its own 128 — and q / k handed over
+    already padded with ``qk_dim`` give the same."""
+    if lanes:
+        monkeypatch.setattr(attention, "_lane_width", lambda c, interpret: -(-c // attention.LANES) *
+                            attention.LANES)
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (2, seq, 2, 1, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (2, seq, 2, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (2, seq, 2, dv), jnp.float32)
+    c = jax.random.normal(ks[3], (2, seq, 2, 1, dv), jnp.float32)
+    attend = partial(attention.blocked_attention, block=BLOCK)
+    both = lambda fn: jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * c), argnums=(0, 1, 2))
+    got, got_grads = jax.jit(both(attend))(q, k, v)
+    want, want_grads = both(dense_attention)(q, k, v)
+    out = attend(q, k, v)
+    assert out.shape == (2, seq, 2, 1, dv)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    np.testing.assert_allclose(out, dense_attention(q, k, v), atol=2e-5)
+    for a, b in zip(got_grads, want_grads):
+        assert a.shape == b.shape and a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, atol=5e-5)
+    if lanes and hd % attention.LANES:
+        width = attention._lane_width(hd, True)
+        pad = lambda x: jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - hd),))
+        np.testing.assert_allclose(attend(pad(q), pad(k), v, qk_dim=hd), out, atol=1e-6)

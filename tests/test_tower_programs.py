@@ -18,6 +18,7 @@ import pytest
 import jax
 
 import test_tower_afmoe as afmoe_t
+import test_tower_deepseek_v3 as deepseek_t
 import test_tower_lfm2 as lfm2_t
 import test_tower_nemotron_h as nemotron_t
 import test_tower_sdar as sdar_t
@@ -32,6 +33,7 @@ TOWERS = {                              # name -> (toy spec, its columns' bins, 
     "nemotron_h": (nemotron_t._spec, nemotron_t.COL_BINS, 1),
     "afmoe": (afmoe_t._spec, afmoe_t.COL_BINS, afmoe_t.R),
     "lfm2_moe": (lfm2_t._spec, lfm2_t.COL_BINS, lfm2_t.R),
+    "deepseek_v3": (deepseek_t._spec, deepseek_t.COL_BINS, deepseek_t.R),
 }
 COMPILES = _CompileCounter()            # the benchmark's own count: what `*_job_rebuilds` reads
 

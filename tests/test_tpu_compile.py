@@ -271,3 +271,43 @@ def test_lfm2_train_step_fits_one_v5e_chip(one_chip, no_cache_no_x64, monkeypatc
     assert calls == ["blocked_attention_dkv", "blocked_attention_dq"] + ["blocked_attention_fwd"] * 2, calls
     assert scopes["tower/conv/mix"] and scopes["tower/conv/proj"]
     _assert_the_scopes_reach_what_a_scope_can(text, tw.SCOPES)
+
+
+def test_moonlight_train_step_fits_one_v5e_chip(one_chip, no_cache_no_x64, monkeypatch):
+    """The cell ``moonlight-train``'s step program — 18 rows packed into one
+    sequence of 8,192 positions, the 568 M-parameter share with its Adam
+    state donated, latent attention on the kernels compiled by Mosaic with q
+    and k at 256 lanes (192 real channels) and v at its own 128 — fits with
+    room under the 14.5 GB the cell allows itself, holds no ``[heads, S, S]``
+    scores, keeps its twenty attention kernel calls (forward, forward again,
+    ``dq``, ``dk``/``dv`` a layer) under ``tower/attn/full`` with v's and the
+    output's lanes at 16 x 128, and every instruction with an ``op_name`` lies
+    in a scope."""
+    from benchmark.drivers.train_afmoe import tower_params
+    from shifu_tpu.models import tower_deepseek_v3 as tw
+    from shifu_tpu.obs.costs import op_scopes
+    _on_the_chips_branch(monkeypatch)
+    doc = _doc("configs", "moonlight-16b-a3b-ep8.json")
+    spec = _spec(tw, tower_params(doc), doc)
+    assert (doc["train"]["params"]["MiniBatchs"], doc["train"]["params"]["RowsPerSequence"],
+            spec.seq_len, spec.n_ids, spec.qk_head_dim, spec.v_head_dim) == (18, 18, 433, 17360, 192, 128)
+    compiled, n_params = _compiled_step(
+        tw, spec, doc, _doc("traffic", "retrain-540x433-pack18-2epochs.json")["rows"], one_chip)
+    assert n_params == 568_484_608
+    assert compiled.memory_analysis().alias_size_in_bytes > 6.8e9   # 12 bytes a parameter updated in place
+    live = _live_bytes(compiled)
+    print(f"moonlight-train step: {live / 1e9:.2f} GB live", compiled.memory_analysis())
+    assert live < 10.6e9, compiled.memory_analysis()       # 10.39 GB
+    text = compiled.as_text()
+    assert "8192,8192]" not in text
+    scopes = op_scopes(text, tw.SCOPES)
+    calls = sorted(n.split(".")[0] for n in scopes["tower/attn/full"] if n.startswith("blocked_attention"))
+    assert calls == (["blocked_attention_dkv"] * 5 + ["blocked_attention_dq"] * 5 +
+                     ["blocked_attention_fwd"] * 10), calls
+    outputs = re.findall(r"%(blocked_attention_(?:fwd|dkv)\.\d+) = \((f32\[[\d,]+\])", text)
+    assert outputs and all(shape == "f32[1,8192,2048]" for name, shape in outputs
+                           if name.startswith("blocked_attention_fwd"))
+    assert all(re.search(rf"%{name} = \(f32\[1,8192,4096\]\{{[^}}]*\}}, f32\[1,8192,2048\]", text)
+               for name, _ in outputs if name.startswith("blocked_attention_dkv"))
+    assert scopes["tower/attn/latent"] and scopes["tower/attn/proj"] and scopes["tower/moe/shared"]
+    _assert_the_scopes_reach_what_a_scope_can(text, tw.SCOPES)
