@@ -86,6 +86,9 @@ MANIFEST: Dict[str, Tuple[str, str]] = {
                                 "the walk's occupancy"),
     "tower.programs_reused": ("counter", "jobs that found their three programs held by the "
                               "process (compile_cache.PROGRAMS) and built none"),
+    "nn.programs_reused": ("counter", "resident NN jobs that found step, epoch_steps and "
+                           "eval_errors held by the process (compile_cache.PROGRAMS) and "
+                           "made none"),
     "tower.dropped_pairs": ("counter", "pairs routed to a held expert that no "
                             "grouped product covered (must stay 0)"),
     "tower.masked_positions": ("counter", "masked non-PAD positions trained on (sdar_moe)"),
@@ -398,7 +401,10 @@ SPANS: Dict[str, str] = {
                  "the fill's thread)"),
     "train.split": ("member_masks and the weight products: the "
                     "train/validation row weights of every member (rows)"),
-    "nn.init": "mesh, params and optimizer state, their device_put",
+    "nn.init": ("mesh, params and optimizer state, their device_put; the "
+                "three programs found in compile_cache.PROGRAMS or made anew "
+                "(members, programs_built: 3 when made, 0 when the "
+                "process's last job left them)"),
     "nn.h2d": ("the trainer's uploads: rows zero-padded on the host to "
                "their final multiple (the minibatch when MiniBatchs is "
                "set, else the mesh's data extent), then one device_put "
@@ -410,7 +416,9 @@ SPANS: Dict[str, str] = {
                "it.  Nothing of the plane comes back to the host"),
     "nn.epoch": "one epoch of the in-RAM NN trainer (epoch)",
     "nn.epoch.dispatch": ("rng split and the step / epoch_steps and "
-                          "eval_errors calls (builds them in epoch 0)"),
+                          "eval_errors calls (epoch 0 builds them, unless "
+                          "they were held and the shapes are the last "
+                          "job's)"),
     "nn.epoch.fetch": "the packed train/validation error fetch that waits",
     "nn.epoch.best_copy": "device->host copy of improved members' params",
     "nn.epoch.progress": "the progress callback (progress file line)",

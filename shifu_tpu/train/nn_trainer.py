@@ -18,9 +18,11 @@ Reference mapping:
 
 from __future__ import annotations
 
+import copy
+import json
 import logging
 import time
-from dataclasses import dataclass, field
+from dataclasses import asdict, dataclass, field
 from functools import lru_cache, partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -29,7 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import obs
+from .. import compile_cache, obs
 from ..data.staging import RowLayout
 from ..models import nn as nn_model
 from ..parallel import mesh as meshlib
@@ -261,6 +263,135 @@ def plane_layout(settings: TrainSettings, bags: int, mesh=None):
                                bs or data_size)
 
 
+def _make_nn_programs(spec: nn_model.NNModelSpec, settings: TrainSettings,
+                      precision: str, uniform: bool, dropout: bool,
+                      y_axis: Optional[int]):
+    """(step, epoch_steps, eval_errors), made anew: what
+    :func:`_train_ensemble_impl` holds for the process's next job.  A
+    function of its own, so that the closures capture the values read here
+    (a copy of the spec, the update rule, the frozen layers, the uniform
+    regularisers) and nothing of a job: its planes, parameters and hyper
+    rows are arguments."""
+    spec = copy.deepcopy(spec)
+    opt = make_optimizer(settings.optimizer, settings.learning_rate,
+                         **settings.opt_kwargs)
+    l2, l1, rate = settings.l2, settings.l1, settings.dropout_rate
+    fixed, fixed_bias = set(settings.fixed_layers), settings.fixed_bias
+
+    def _freeze(delta):
+        """Zero deltas of fixed layers (reference FIXED_LAYERS /
+        FIXED_BIAS: frozen weights during continuous training; 1-based
+        layer ids)."""
+        if not fixed:
+            return delta
+        return [dl if (li + 1) not in fixed else
+                {"w": jnp.zeros_like(dl["w"]),
+                 "b": jnp.zeros_like(dl["b"]) if fixed_bias
+                 else dl["b"]}
+                for li, dl in enumerate(delta)]
+
+    def member_update(params, opt_state, xb, yb, mw, rng, h, lr_scale):
+        loss, grads = jax.value_and_grad(nn_model.weighted_loss)(
+            params, spec, xb, yb[:, None], mw,
+            l2=l2 if uniform else h[1],
+            l1=l1 if uniform else h[2],
+            dropout_rate=rate if uniform else h[3],
+            rng=rng if dropout else None)
+        if precision == "mixed":
+            # bf16 grads widen once; the rule steps the f32 master and
+            # the bf16 training copy is one rounding of it
+            params, opt_state = mixed_apply(opt, grads, opt_state,
+                                            scale=lr_scale * h[0],
+                                            freeze=_freeze)
+            return params, opt_state, loss
+        delta, opt_state = opt.update(grads, opt_state, params)
+        # apply in the PARAM dtype: the f32-strong lr_scale tracer would
+        # otherwise silently widen a bf16 ladder back to f32 (no-op for
+        # f32 params)
+        params = jax.tree_util.tree_map(
+            lambda p, d: p + (d * (lr_scale * h[0])).astype(p.dtype),
+            params, _freeze(delta))
+        return params, opt_state, loss
+
+    # cost-attributed entry points: the full-batch step, the scanned
+    # epoch sweep and the eval pass are THE nn-plane executables the
+    # utilization report joins against the TRAIN span (obs/costs).  Data
+    # arrays and the [B, 4] hyper rows enter as ARGUMENTS: closing over a
+    # multi-host-sharded array is an error under multiple controllers, and
+    # a held program must keep no job's array alive
+    @partial(obs.costed_jit, "nn.step")
+    def step(stacked, opt_state, xb, yb, tw, rngs, hd, lr_scale):
+        return jax.vmap(member_update,
+                        in_axes=(0, 0, None, y_axis, 0, 0, 0, None))(
+            stacked, opt_state, xb, yb, tw, rngs, hd, lr_scale)
+
+    @partial(obs.costed_jit, "nn.eval_errors")
+    def eval_errors(stacked, tw, vw, xe, ys):
+        def one(params, mw, ym):
+            pred = nn_model.forward(params, spec, xe)
+            per_row = nn_model.per_row_loss(pred, ym[:, None], spec)
+            return (per_row * mw).sum() / jnp.maximum(mw.sum(), 1e-9)
+        ev = jax.vmap(one, in_axes=(0, 0, y_axis))
+        return ev(stacked, tw, ys), ev(stacked, vw, ys)
+
+    # batch slicing happens INSIDE jit (dynamic_slice of sharded arrays
+    # compiles into the SPMD program); an EAGER lax.slice on sharded inputs
+    # does ad-hoc device-to-device copies the XLA:CPU runtime has been seen
+    # to SIGABRT on
+    def step_batch(stacked, opt_state, start, rngs, hd, lr_scale, blen: int,
+                   xe, ye, twe):
+        xb = jax.lax.dynamic_slice_in_dim(xe, start, blen, axis=0)
+        yb = jax.lax.dynamic_slice_in_dim(ye, start, blen, axis=0) \
+            if y_axis is None else \
+            jax.lax.dynamic_slice_in_dim(ye, start, blen, axis=1)
+        twb = jax.lax.dynamic_slice_in_dim(twe, start, blen, axis=1)
+        return jax.vmap(member_update,
+                        in_axes=(0, 0, None, y_axis, 0, 0, 0, None))(
+            stacked, opt_state, xb, yb, twb, rngs, hd, lr_scale)
+
+    @partial(obs.costed_jit, "nn.epoch_steps",
+             static_argnames=("blen", "n_b"))
+    def epoch_steps(stacked, opt_state, rngs, hd, lr_scale, xe, ye, twe,
+                    blen: int, n_b: int):
+        """A whole epoch's minibatch sweep as ONE executable (lax.scan over
+        batches) — the per-batch dispatch loop costs one program execution
+        per batch, which dominates wall-clock on a remote-device link."""
+        def body(carry, bi):
+            st, os_ = carry
+            rngs_b = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
+                rngs, bi) if dropout else rngs
+            st, os_, _ = step_batch(st, os_, bi * blen, rngs_b, hd, lr_scale,
+                                    blen, xe, ye, twe)
+            return (st, os_), None
+        (st, os_), _ = jax.lax.scan(body, (stacked, opt_state),
+                                    jnp.arange(n_b, dtype=jnp.int32))
+        return st, os_
+
+    return step, epoch_steps, eval_errors
+
+
+def nn_programs_key(spec: nn_model.NNModelSpec, settings: TrainSettings,
+                    precision: str, uniform: bool, dropout: bool,
+                    y_axis: Optional[int], mesh) -> tuple:
+    """Everything :func:`_make_nn_programs`' closures read or are
+    specialised to.  The spec holds lists, so its canonical JSON stands for
+    it; the regularisers are constants of the programs on the uniform path
+    only (stacked grid trials carry theirs in the hyper rows); the mesh
+    names the shardings the programs see; telemetry is in it because
+    ``obs.costed_jit`` wraps differently with it on.  The row count and the
+    minibatch are shapes and static arguments, which jax keys per
+    callable."""
+    return ("nn", json.dumps(asdict(spec), sort_keys=True, default=repr),
+            str(settings.optimizer or "R").upper(),
+            float(settings.learning_rate),
+            json.dumps(settings.opt_kwargs, sort_keys=True, default=repr),
+            (float(settings.l2), float(settings.l1),
+             float(settings.dropout_rate)) if uniform else None,
+            precision, tuple(sorted(set(settings.fixed_layers))),
+            bool(settings.fixed_bias), uniform, dropout, y_axis,
+            settings.matmul_precision, mesh, obs.enabled())
+
+
 def train_ensemble(x: np.ndarray, y: np.ndarray,
                    train_w: np.ndarray, valid_w: np.ndarray,
                    spec: nn_model.NNModelSpec,
@@ -316,7 +447,7 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
     bags = train_w.shape[0]
     n = y.shape[0]
     from jax.sharding import NamedSharding, PartitionSpec as P
-    with obs.span("nn.init", members=bags):
+    with obs.span("nn.init", members=bags) as sp:
         mesh, bs, x_layout = plane_layout(settings, bags, mesh)
         key = jax.random.PRNGKey(settings.seed)
         if init_params_list is None:
@@ -343,6 +474,40 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
         stacked = jax.device_put(stacked, sh_ens)
         opt_state = jax.device_put(opt_state, sh_ens)
 
+        # per-member hyper rows [B, 4]: lr_scale, l2, l1, dropout — uniform
+        # from settings unless stacked grid trials supplied their own
+        if member_hypers is None:
+            hyp = np.tile(np.asarray(
+                [[1.0, settings.l2, settings.l1, settings.dropout_rate]],
+                np.float32), (bags, 1))
+        else:
+            hyp = np.stack([
+                np.asarray(member_hypers.get("lr_scale", np.ones(bags)),
+                           np.float32),
+                np.asarray(member_hypers.get("l2",
+                                             np.full(bags, settings.l2)),
+                           np.float32),
+                np.asarray(member_hypers.get("l1",
+                                             np.full(bags, settings.l1)),
+                           np.float32),
+                np.asarray(member_hypers.get(
+                    "dropout", np.full(bags, settings.dropout_rate)),
+                    np.float32)], axis=1)
+        dropout = float(hyp[:, 3].max()) > 0   # static gate: any member drops?
+        uniform = member_hypers is None
+        y_axis = None if y_members is None else 0  # per-member targets vmap over B
+        # the programs outlive the job: an equal key finds them held
+        # (compile_cache.PROGRAMS), built by the process's last job
+        programs, reused = compile_cache.PROGRAMS.programs(
+            nn_programs_key(spec, settings, precision, uniform, dropout,
+                            y_axis, mesh),
+            partial(_make_nn_programs, spec, settings, precision, uniform,
+                    dropout, y_axis))
+        step, epoch_steps, eval_errors = programs
+        if reused:
+            obs.counter("nn.programs_reused").inc()
+        sp.set(programs_built=0 if reused else len(programs))
+
     on_device = isinstance(x, jax.Array)
     if on_device and x.shape[0] != n + meshlib.pad_rows(n, x_layout.multiple):
         # laid out for another job than this one: the host path
@@ -364,86 +529,7 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
                                             else plane)),
                pad_rows=plane[0].shape[0] - n)
     xd, yd, twd, vwd, ymd = (*plane, None)[:5]
-
-    # per-member hyper rows [B, 4]: lr_scale, l2, l1, dropout — uniform from
-    # settings unless stacked grid trials supplied their own
-    if member_hypers is None:
-        hyp = np.tile(np.asarray(
-            [[1.0, settings.l2, settings.l1, settings.dropout_rate]],
-            np.float32), (bags, 1))
-    else:
-        hyp = np.stack([
-            np.asarray(member_hypers.get("lr_scale", np.ones(bags)),
-                       np.float32),
-            np.asarray(member_hypers.get("l2", np.full(bags, settings.l2)),
-                       np.float32),
-            np.asarray(member_hypers.get("l1", np.full(bags, settings.l1)),
-                       np.float32),
-            np.asarray(member_hypers.get(
-                "dropout", np.full(bags, settings.dropout_rate)),
-                np.float32)], axis=1)
-    dropout = float(hyp[:, 3].max())       # static gate: any member drops?
-    uniform = member_hypers is None
     hd = jax.device_put(hyp, sh_ens)
-
-    fixed = set(settings.fixed_layers)
-
-    def _freeze(delta):
-        """Zero deltas of fixed layers (reference FIXED_LAYERS /
-        FIXED_BIAS: frozen weights during continuous training; 1-based
-        layer ids)."""
-        if not fixed:
-            return delta
-        return [dl if (li + 1) not in fixed else
-                {"w": jnp.zeros_like(dl["w"]),
-                 "b": jnp.zeros_like(dl["b"]) if settings.fixed_bias
-                 else dl["b"]}
-                for li, dl in enumerate(delta)]
-
-    def member_update(params, opt_state, xb, yb, mw, rng, h, lr_scale):
-        loss, grads = jax.value_and_grad(nn_model.weighted_loss)(
-            params, spec, xb, yb[:, None], mw,
-            l2=settings.l2 if uniform else h[1],
-            l1=settings.l1 if uniform else h[2],
-            dropout_rate=settings.dropout_rate if uniform else h[3],
-            rng=rng if dropout > 0 else None)
-        if precision == "mixed":
-            # bf16 grads widen once; the rule steps the f32 master and
-            # the bf16 training copy is one rounding of it
-            params, opt_state = mixed_apply(opt, grads, opt_state,
-                                            scale=lr_scale * h[0],
-                                            freeze=_freeze)
-            return params, opt_state, loss
-        delta, opt_state = opt.update(grads, opt_state, params)
-        # apply in the PARAM dtype: the f32-strong lr_scale tracer would
-        # otherwise silently widen a bf16 ladder back to f32 (no-op for
-        # f32 params)
-        params = jax.tree_util.tree_map(
-            lambda p, d: p + (d * (lr_scale * h[0])).astype(p.dtype),
-            params, _freeze(delta))
-        return params, opt_state, loss
-
-    y_axis = None if ymd is None else 0    # per-member targets vmap over B
-
-    # cost-attributed entry points: the full-batch step, the scanned
-    # epoch sweep and the eval pass are THE nn-plane executables the
-    # utilization report joins against the TRAIN span (obs/costs)
-    @partial(obs.costed_jit, "nn.step")
-    def step(stacked, opt_state, xb, yb, tw, rngs, lr_scale):
-        return jax.vmap(member_update,
-                        in_axes=(0, 0, None, y_axis, 0, 0, 0, None))(
-            stacked, opt_state, xb, yb, tw, rngs, hd, lr_scale)
-
-    @partial(obs.costed_jit, "nn.eval_errors")
-    def eval_errors(stacked, tw, vw, xe, ys):
-        # data arrays enter as ARGUMENTS: closing over a multi-host-sharded
-        # array is an error under multiple controllers
-        def one(params, mw, ym):
-            pred = nn_model.forward(params, spec, xe)
-            per_row = nn_model.per_row_loss(pred, ym[:, None], spec)
-            return (per_row * mw).sum() / jnp.maximum(mw.sum(), 1e-9)
-        ev = jax.vmap(one, in_axes=(0, 0, y_axis))
-        return ev(stacked, tw, ys), ev(stacked, vw, ys)
 
     stops = [WindowEarlyStop(settings.early_stop_window) for _ in range(bags)]
     best_valid = np.full(bags, np.inf)
@@ -483,39 +569,6 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
 
     n_padded = xd.shape[0]
 
-    # batch slicing happens INSIDE jit (dynamic_slice of sharded arrays
-    # compiles into the SPMD program); an EAGER lax.slice on sharded inputs
-    # does ad-hoc device-to-device copies the XLA:CPU runtime has been seen
-    # to SIGABRT on
-    def step_batch(stacked, opt_state, start, rngs, lr_scale, blen: int,
-                   xe, ye, twe):
-        xb = jax.lax.dynamic_slice_in_dim(xe, start, blen, axis=0)
-        yb = jax.lax.dynamic_slice_in_dim(ye, start, blen, axis=0) \
-            if ymd is None else \
-            jax.lax.dynamic_slice_in_dim(ye, start, blen, axis=1)
-        twb = jax.lax.dynamic_slice_in_dim(twe, start, blen, axis=1)
-        return jax.vmap(member_update,
-                        in_axes=(0, 0, None, y_axis, 0, 0, 0, None))(
-            stacked, opt_state, xb, yb, twb, rngs, hd, lr_scale)
-
-    @partial(obs.costed_jit, "nn.epoch_steps",
-             static_argnames=("blen", "n_b"))
-    def epoch_steps(stacked, opt_state, rngs, lr_scale, xe, ye, twe,
-                    blen: int, n_b: int):
-        """A whole epoch's minibatch sweep as ONE executable (lax.scan over
-        batches) — the per-batch dispatch loop costs one program execution
-        per batch, which dominates wall-clock on a remote-device link."""
-        def body(carry, bi):
-            st, os_ = carry
-            rngs_b = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
-                rngs, bi) if dropout > 0 else rngs
-            st, os_, _ = step_batch(st, os_, bi * blen, rngs_b, lr_scale,
-                                    blen, xe, ye, twe)
-            return (st, os_), None
-        (st, os_), _ = jax.lax.scan(body, (stacked, opt_state),
-                                    jnp.arange(n_b, dtype=jnp.int32))
-        return st, os_
-
     obs_on = obs.enabled()
     for epoch in range(start_epoch, epochs_target):
         with obs.span("nn.epoch", epoch=epoch):
@@ -525,13 +578,14 @@ def _train_ensemble_impl(x: np.ndarray, y: np.ndarray,
                 rngs = jax.random.split(sub, bags)
                 if bs and bs < n_padded:
                     stacked, opt_state = epoch_steps(
-                        stacked, opt_state, rngs, lr_scale, xd,
+                        stacked, opt_state, rngs, hd, lr_scale, xd,
                         yd if ymd is None else ymd, twd, bs,
                         (n_padded - bs) // bs + 1)
                 else:
                     stacked, opt_state, _ = step(
                         stacked, opt_state, xd,
-                        yd if ymd is None else ymd, twd, rngs, lr_scale)
+                        yd if ymd is None else ymd, twd, rngs, hd,
+                        lr_scale)
                 tr, va = eval_errors(stacked, twd, vwd, xd,
                                      yd if ymd is None else ymd)
                 packed = jnp.stack([tr, va])

@@ -9,7 +9,7 @@ files' ``TowerParams``.
 import gc
 import os
 import shutil
-import types
+import sys
 import weakref
 
 import numpy as np
@@ -26,6 +26,9 @@ from benchmark.run import _CompileCounter
 from shifu_tpu import compile_cache, obs
 from shifu_tpu.train import tower_trainer as tt
 from shifu_tpu.train.nn_trainer import TrainSettings
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "helpers"))
+from reachable import reachable_arrays  # noqa: E402
 
 ROWS, MB, VALID = 32, 8, 0.25           # 24 training rows: 3 steps and 1 validation step an epoch
 TOWERS = {                              # name -> (toy spec, its columns' bins, RowsPerSequence)
@@ -77,24 +80,6 @@ def _init_spans():
             if r.get("kind") == "span" and r.get("name") == "tower.init"]
 
 
-def _reachable_arrays(root):
-    """jax Arrays reachable from ``root`` through what the garbage collector
-    can see, a function's module globals and classes left out (they are the
-    interpreter's, not the programs')."""
-    seen, stack, found = set(), [root], []
-    while stack:
-        o = stack.pop()
-        if id(o) in seen or isinstance(o, (types.ModuleType, type, str, bytes, int, float)):
-            continue
-        seen.add(id(o))
-        if isinstance(o, jax.Array):
-            found.append(o)
-            continue
-        skip = getattr(o, "__globals__", None) if isinstance(o, types.FunctionType) else None
-        stack.extend(r for r in gc.get_referents(o) if r is not skip)
-    return found
-
-
 # ------------------------------------------------- the second job builds nothing
 @pytest.mark.parametrize("telemetry", [False, True], ids=["plain", "telemetry"])
 @pytest.mark.parametrize("name", sorted(TOWERS))
@@ -117,7 +102,7 @@ def test_second_job_builds_nothing_and_trains_to_the_bit(name, telemetry):
     assert built_fresh >= 3
     assert first.history == second.history == fresh.history and len(first.history) == 2
     assert _bits(first) == _bits(second) == _bits(fresh)
-    assert not _reachable_arrays(compile_cache.PROGRAMS._held)
+    assert not reachable_arrays(compile_cache.PROGRAMS._held)
 
 
 # ------------------------------------------------------- the key and the bound
